@@ -13,40 +13,42 @@ keep importing and running, not a measurement.
 from __future__ import annotations
 
 import argparse
+import importlib
 import time
 
-from benchmarks import (accuracy, agg_schemes, bias_curves, comm_path, eur,
-                        heterogeneity, kernels_bench, lag_tolerance,
-                        roofline_table, round_engine, round_length,
-                        selection_ablation, sr_futility)
+
+def _mod(name: str):
+    """Sections import lazily: this process touches JAX only inside the
+    sections it runs, so a section's own child processes (the scale
+    sweep's per-cell subprocesses) can take the accelerator.  fleet_sweep
+    forces one XLA host device per core at import, which only takes
+    effect before jax initializes — run it standalone
+    (python -m benchmarks.fleet_sweep) for the sharded-fleet numbers."""
+    return importlib.import_module(f'benchmarks.{name}')
+
 
 SECTIONS = {
-    'round_length': lambda full: (round_length.run(), round_length.summarize()),
-    'round_engine': lambda full: round_engine.run(),
-    'comm_path': lambda full: comm_path.run(),
-    'sr_futility': lambda full: sr_futility.run(),
-    'accuracy': lambda full: accuracy.run(full=full),
-    'lag_tolerance': lambda full: lag_tolerance.run(),
-    'bias': lambda full: bias_curves.run(),
-    'eur': lambda full: eur.run(),
-    'selection_ablation': lambda full: selection_ablation.run(),
-    'agg_schemes': lambda full: agg_schemes.run(
+    'round_length': lambda full: (_mod('round_length').run(),
+                                  _mod('round_length').summarize()),
+    'round_engine': lambda full: _mod('round_engine').run(),
+    'comm_path': lambda full: _mod('comm_path').run(),
+    'sr_futility': lambda full: _mod('sr_futility').run(),
+    'accuracy': lambda full: _mod('accuracy').run(full=full),
+    'lag_tolerance': lambda full: _mod('lag_tolerance').run(),
+    'bias': lambda full: _mod('bias_curves').run(),
+    'eur': lambda full: _mod('eur').run(),
+    'selection_ablation': lambda full: _mod('selection_ablation').run(),
+    'agg_schemes': lambda full: _mod('agg_schemes').run(
         json_path='BENCH_agg_schemes.json'),
-    'heterogeneity': lambda full: heterogeneity.run(
+    'heterogeneity': lambda full: _mod('heterogeneity').run(
         json_path='BENCH_heterogeneity.json'),
-    'kernels': lambda full: kernels_bench.run(),
-    'roofline': lambda full: roofline_table.run(),
-    # imported lazily: fleet_sweep forces one XLA host device per core at
-    # import, which must happen before jax initializes to take effect —
-    # run it standalone (python -m benchmarks.fleet_sweep) for the
-    # sharded-fleet numbers; here it runs unsharded on one device
-    'fleet_sweep': lambda full: __import__(
-        'benchmarks.fleet_sweep', fromlist=['run']).run(),
-    # lazy too: the full sweep spawns one subprocess per cell for honest
-    # per-cell peak-RSS (see benchmarks/scale.py)
-    'scale': lambda full: __import__(
-        'benchmarks.scale', fromlist=['run']).run(
-            smoke=not full, json_path=_JSON_PATH['path']),
+    'kernels': lambda full: _mod('kernels_bench').run(),
+    'roofline': lambda full: _mod('roofline_table').run(),
+    'fleet_sweep': lambda full: _mod('fleet_sweep').run(),
+    # the full sweep spawns one subprocess per cell for honest per-cell
+    # peak-RSS (see benchmarks/scale.py)
+    'scale': lambda full: _mod('scale').run(
+        smoke=not full, json_path=_JSON_PATH['path']),
 }
 
 #: ``--json FILE`` routes the scale section's cell measurements
@@ -57,27 +59,24 @@ _JSON_PATH = {'path': None}
 # script executes end to end in seconds, so CI catches bitrot in the
 # benchmark layer without paying for a measurement
 SMOKE_SECTIONS = {
-    'round_length': lambda: (round_length.run(rounds=3),
-                             round_length.summarize(rounds=3)),
-    'round_engine': lambda: round_engine.run(rounds=6, reps=1),
+    'round_length': lambda: (_mod('round_length').run(rounds=3),
+                             _mod('round_length').summarize(rounds=3)),
+    'round_engine': lambda: _mod('round_engine').run(rounds=6, reps=1),
     # comm_path asserts the 2-dispatch invariant of the compressed wire
     # path on every run, so the smoke pass is also a regression guard
-    'comm_path': lambda: comm_path.run(rounds=4, reps=1),
-    'eur': lambda: eur.run(rounds=3),
+    'comm_path': lambda: _mod('comm_path').run(rounds=4, reps=1),
+    'eur': lambda: _mod('eur').run(rounds=3),
     # one fleet dispatch over the whole aggregation family; the JSON is
     # the BENCH_agg_schemes.json CI artifact
-    'agg_schemes': lambda: agg_schemes.run(
+    'agg_schemes': lambda: _mod('agg_schemes').run(
         rounds=6, reps=1, json_path='BENCH_agg_schemes.json'),
     # the trace-scenario grid (scenario x protocol x wire); the JSON is
     # the BENCH_heterogeneity.json CI artifact
-    'heterogeneity': lambda: heterogeneity.run(
+    'heterogeneity': lambda: _mod('heterogeneity').run(
         rounds=6, reps=1, json_path='BENCH_heterogeneity.json'),
-    'fleet_sweep': lambda: __import__(
-        'benchmarks.fleet_sweep', fromlist=['run']).run(rounds=6, s=4,
-                                                        reps=1),
-    'scale': lambda: __import__(
-        'benchmarks.scale', fromlist=['run']).run(
-            smoke=True, json_path=_JSON_PATH['path']),
+    'fleet_sweep': lambda: _mod('fleet_sweep').run(rounds=6, s=4, reps=1),
+    'scale': lambda: _mod('scale').run(
+        smoke=True, json_path=_JSON_PATH['path']),
 }
 
 
@@ -95,6 +94,9 @@ def main(argv=None) -> None:
     if args.full and args.smoke:
         ap.error('--full and --smoke are mutually exclusive')
     _JSON_PATH['path'] = args.json
+    # a path only: importing jax starts no backend
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.json and args.only not in (None, 'scale'):
         ap.error('--json applies to the scale section')
     sections = SMOKE_SECTIONS if args.smoke else SECTIONS

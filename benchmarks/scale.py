@@ -256,12 +256,16 @@ def run_cell(protocol: str, schedule: str, m: int, *, quota: int = QUOTA,
     }
 
 
-def _cell_subprocess(protocol, schedule, m, quota, rounds) -> dict:
-    """Run one cell in a child interpreter so VmHWM is a per-cell peak."""
+def _cell_subprocess(protocol, schedule, m, quota, rounds, *,
+                     platform: str | None = None) -> dict:
+    """Run one cell in a child interpreter so VmHWM is a per-cell peak.
+    ``platform`` pins the child's JAX platform (JAX_PLATFORMS)."""
     cmd = [sys.executable, '-m', 'benchmarks.scale', '--cell',
            f'{protocol}:{schedule}:{m}', '--quota', str(quota),
            '--rounds', str(rounds)]
     env = dict(os.environ)
+    if platform is not None:
+        env['JAX_PLATFORMS'] = platform
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env['PYTHONPATH'] = os.pathsep.join(
         p for p in (os.path.join(root, 'src'), root,
@@ -315,8 +319,10 @@ def guard(*, budget_mb: float = TIER_HWM_BUDGET_MB, quota: int = QUOTA,
           rounds: int = ROUNDS) -> dict:
     """CI memory-regression gate: the m=1e5 SAFA ``sparse_tier`` cell in
     its own subprocess (honest per-cell VmHWM) against the committed
-    budget.  Raises ``SystemExit`` on regression."""
-    r = _cell_subprocess('safa', 'sparse_tier', 100_000, quota, rounds)
+    budget.  Raises ``SystemExit`` on regression.  VmHWM is host memory,
+    so the child runs on the CPU and never touches an accelerator."""
+    r = _cell_subprocess('safa', 'sparse_tier', 100_000, quota, rounds,
+                         platform='cpu')
     hwm = r['vm_hwm_mb']
     print(f'scale-guard/safa/sparse_tier/m=100000,{hwm:.0f},'
           f'vm_hwm_mb (budget {budget_mb:.0f}MB)', flush=True)
@@ -347,6 +353,8 @@ def main(argv=None) -> None:
     ap.add_argument('--cell', default=None, metavar='P:S:M',
                     help='internal: run one cell, print its JSON')
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.cell:
         p, s, m = args.cell.split(':')
         print(json.dumps(run_cell(p, s, int(m), quota=args.quota,

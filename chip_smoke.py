@@ -1,0 +1,446 @@
+#!/usr/bin/env python3
+"""Run SAFA's paper-scale round on a TPU through ``api.Experiment``.
+
+    python chip_smoke.py             # phases (a)-(c) on one chip
+    python chip_smoke.py --chips 4   # the sharded-fleet phase on four chips
+
+The workload is ``PAPER_TASKS['task2_cnn']`` at its full width: m=100
+clients, 70,000 images from ``make_images`` (seed 0), batch 40, 5 local
+epochs, and the paper CNN (about 340k parameters, packed to N=342,016).
+SAFA runs with ``SafaSpec(fraction=0.3, lag_tolerance=5)`` and a crash
+probability of 0.3.  The one cut is the number of rounds: 3, not 50.
+
+One chip, one process:
+
+  (a) ``ExecSpec(use_kernel='packed')`` against ``use_kernel=False`` (the
+      XLA tree path);
+  (b) ``wire='int8', use_kernel='packed'`` (the two-dispatch compressed
+      round) against (a), and one round's kernel operands against the
+      pure-jnp oracles ``quantize_packed_ref``/``safa_aggregate_q8_ref``;
+  (c) ``schedule='sparse_tier', use_kernel='packed'`` (the gather and
+      tier-rows kernels) against (a).
+
+``--chips 4`` runs only the multi-chip phase: an S=8 fleet of these SAFA
+members under ``ExecSpec(engine='fleet', use_kernel='packed')``, sharded
+over 4 chips, against the same fleet unsharded on one chip.  The
+unsharded reference is the sweep's ``engine='sequential'`` form (each
+member's run in turn, same schedules): the S=8 fleet as one vmapped
+program needs 22.15 GB of HBM by the v5e compiler's count, more than one
+chip's 15.75 GB.
+
+Each phase prints one line; the last line of the output is
+``{"ok": true, "device": {...}}``.  Every phase checks that its program
+lowered real Pallas kernels (``tpu_custom_call`` in the compiled text,
+no kernel traced in interpret mode).  Without a TPU the script exits
+non-zero before any phase runs.
+"""
+from __future__ import annotations
+
+import argparse
+import glob
+import json
+import os
+import shutil
+import sys
+
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                'src'))
+
+ROUNDS = 3                      # the paper runs 50; the one cut
+CRASH_PROB = 0.3
+FRACTION, LAG_TOLERANCE = 0.3, 5
+DATA_SEED = 0
+FLEET_SIZE = 8
+#: images per fleet member's federation in the 4-chip phase (see four_chips)
+FLEET_DATASET = 8_000
+# Tolerances bound the largest |difference| by rtol times the largest
+# |reference| value.
+#: One round's kernel outputs against their reference on the same
+#: operands: f32 paths differ only in how the Eq. 7 sum associates (the
+#: repo's f32 tolerance; verify recipe, "Tier drive").
+RTOL_F32 = 2e-5
+#: The int8 wire moves each upload by up to half a quantisation step
+#: (1/254 of its block's absolute maximum) every round (the repo's int8
+#: tolerance).
+RTOL_INT8 = 2e-2
+#: Two f32 paths over a whole 3-round run: each round's 1-ulp differences
+#: in the Eq. 7 sum pass through 90 SGD steps of local training before
+#: the next; on the v5e the packed and tree paths ended 3.3e-5 apart
+#: (relative), so the run is held to ten times the one-round bound.
+RUN_RTOL_F32 = 2e-4
+#: XLA writes the optimised HLO of every program it compiles here; each
+#: phase reads its programs' text from it.  The engine programs carry the
+#: client data as constants (~750 MB), above the persistent cache's entry
+#: limit, so every run compiles them and their text is always written.
+DUMP_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        '.xla_dump')
+
+
+class SmokeError(RuntimeError):
+    """A phase broke its contract."""
+
+
+def _require(cond: bool, msg: str):
+    if not cond:
+        raise SmokeError(msg)
+
+
+def _max_diff(got, ref):
+    """(largest |got - ref|, largest |ref|) over every leaf."""
+    import jax
+    import numpy as np
+    diffs = [float(np.max(np.abs(np.asarray(a, np.float64)
+                                 - np.asarray(b, np.float64))))
+             for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref))]
+    scale = max(float(np.max(np.abs(np.asarray(b))))
+                for b in jax.tree.leaves(ref))
+    return max(diffs), scale
+
+
+class Workload:
+    """The task2_cnn federation: declarative env plus the CNN task."""
+
+    def __init__(self, *, m: int, dataset_size: int, batch_size: int,
+                 epochs: int, lr: float, t_lim: float):
+        from repro.data import make_images, partition
+        from repro.data.tasks import cnn_task
+        from repro.fedsim import EnvSpec
+        self.env = EnvSpec(m=m, crash_prob=CRASH_PROB,
+                           dataset_size=dataset_size, batch_size=batch_size,
+                           epochs=epochs, t_lim=t_lim, seed=DATA_SEED)
+        x, y = make_images(n=dataset_size, seed=DATA_SEED)
+        data = partition(x, y, self.env.build().partition_sizes, batch_size,
+                         seed=DATA_SEED)
+        self.task = cnn_task(data, lr=lr, epochs=epochs)
+
+    @classmethod
+    def paper(cls) -> 'Workload':
+        from repro.configs import PAPER_TASKS
+        t = PAPER_TASKS['task2_cnn']
+        return cls(m=t['m'], dataset_size=t['dataset_size'],
+                   batch_size=t['batch_size'], epochs=t['epochs'],
+                   lr=t['lr'], t_lim=t['t_lim'])
+
+    def spec(self):
+        from repro import api
+        return api.SafaSpec(fraction=FRACTION, lag_tolerance=LAG_TOLERANCE)
+
+    def experiment(self, **exec_kw):
+        from repro import api
+        return api.Experiment(self.task, self.env, self.spec(),
+                              api.ExecSpec(eval_every=ROUNDS, **exec_kw),
+                              rounds=ROUNDS, seed=0)
+
+
+class Compiles:
+    """What the phases compile: seconds spent tracing, lowering and
+    compiling (JAX's monitoring events), and the optimised HLO text of
+    the programs compiled (XLA's dump)."""
+
+    def __init__(self):
+        import jax
+        self.secs = 0.0
+        self.seen = set()
+        jax.monitoring.register_event_duration_secs_listener(self._on_event)
+
+    def _on_event(self, name, secs, **_):
+        if name.startswith('/jax/core/compile/'):
+            self.secs += secs
+
+    def mark(self):
+        self.secs = 0.0
+        self.seen = set(glob.glob(os.path.join(DUMP_DIR, '*.txt')))
+
+    def since_mark(self):
+        """(compile seconds, texts of the programs compiled) since mark."""
+        new = set(glob.glob(os.path.join(
+            DUMP_DIR, '*after_optimizations.txt'))) - self.seen
+        texts = []
+        for path in sorted(new):
+            with open(path) as f:
+                texts.append(f.read())
+        return self.secs, texts
+
+
+def traced_for_tpu(wl: Workload, **exec_kw):
+    """The phase's segment program, built as the runner builds it, traced
+    every kernel for compilation (no kernel in interpret mode)."""
+    from repro import api
+    from repro.analysis import jaxpr_checks
+    from repro.kernels import backend
+    _require(not backend.interpret(), 'kernels would run in interpret mode')
+    exp = wl.experiment(engine='scan', **exec_kw)
+    cell = jaxpr_checks.Cell(api.check_compat(exp.protocol, exp.exec),
+                             exp.protocol, exp.exec)
+    jaxpr = jaxpr_checks.lower_cell(cell, task=wl.task, env=wl.env,
+                                    rounds=ROUNDS).jaxpr
+    modes = [eqn.params['interpret']
+             for eqn, _ in jaxpr_checks._walk_eqns(jaxpr.jaxpr)
+             if eqn.primitive.name == 'pallas_call']
+    _require(modes, f'{exec_kw}: no Pallas kernel in the program')
+    _require(not any(modes),
+             f'{exec_kw}: a kernel was traced in interpret mode')
+
+
+def run_phase(wl: Workload, compiles: Compiles, *, kernels: bool,
+              **exec_kw):
+    """One run through the normal entry point.  Returns (history, compile
+    seconds); with ``kernels``, the run must have compiled a program that
+    holds a Mosaic kernel (``tpu_custom_call``)."""
+    if kernels:
+        traced_for_tpu(wl, **exec_kw)
+    compiles.mark()
+    hist = wl.experiment(**exec_kw).compile().run()
+    secs, texts = compiles.since_mark()
+    if kernels:
+        _require(any('tpu_custom_call' in t for t in texts),
+                 f'{exec_kw}: no compiled program holds tpu_custom_call')
+    return hist, secs
+
+
+def report(phase: str, device: dict, checks: dict, **info):
+    """Print the phase's line, then fail on any check out of bounds.
+    ``checks``: name -> (largest |diff|, bound)."""
+    line = {'phase': phase, 'platform': device['platform'],
+            'device_kind': device['kind'], 'devices': device['count'],
+            'rounds': ROUNDS, 'cut': 'rounds: 3 of the paper\'s 50', **info}
+    for name, (diff, bound) in checks.items():
+        line[name] = {'max_abs_diff': diff, 'tolerance': bound}
+    print(json.dumps(line), flush=True)
+    for name, (diff, bound) in checks.items():
+        _require(diff <= bound, f'{phase}: {name} max |diff| {diff} '
+                                f'exceeds its tolerance {bound}')
+
+
+def _bounded(got, ref, rtol):
+    diff, scale = _max_diff(got, ref)
+    return diff, rtol * scale
+
+
+class Operands:
+    """Full-width round operands built around a trained global model: the
+    packed global, clients' rows near it, role masks and weights."""
+
+    def __init__(self, wl: Workload, global_w, *, wire: bool):
+        import jax.numpy as jnp
+        import numpy as np
+
+        from repro.kernels import ops
+        self.rng = np.random.default_rng(DATA_SEED)
+        self.spec = (ops.wire_spec if wire else ops.pack_spec)(global_w)
+        self.g = ops.pack_global(global_w, self.spec)
+        self.m = wl.env.m
+        self.weights = jnp.asarray(wl.env.build().weights, jnp.float32)
+
+    def rows(self, k):
+        import jax.numpy as jnp
+        return self.g[None] + jnp.asarray(
+            0.01 * self.rng.standard_normal((k, self.spec.n_padded)),
+            jnp.float32)
+
+    def masks(self, k):
+        import jax.numpy as jnp
+        picked = self.rng.random(k) < FRACTION
+        return (jnp.asarray(picked),
+                jnp.asarray(~picked & (self.rng.random(k) < 0.3)),
+                jnp.asarray(self.rng.random(k) < 0.1),
+                jnp.asarray(self.rng.random(k) < 1 - CRASH_PROB))
+
+
+def dense_round(ops_: Operands):
+    """The dense packed kernel against the pure-jnp Eq. 6-8 oracle."""
+    from repro.kernels import ops, ref
+    picked, undrafted, deprecated, _ = ops_.masks(ops_.m)
+    args = (ops_.rows(ops_.m), ops_.rows(ops_.m), ops_.g, picked, undrafted,
+            deprecated, ops_.weights)
+    return _bounded(ops.safa_aggregate_packed(*args),
+                    ref.safa_aggregate_ref(*args), RTOL_F32)
+
+
+def wire_round(ops_: Operands):
+    """quantize_packed and the fused int8 kernel against their oracles."""
+    import jax.numpy as jnp
+
+    from repro.kernels import ops, ref
+    trained = ops_.rows(ops_.m)
+    q, s = ops.quantize_packed(trained)
+    rq, rs = ref.quantize_packed_ref(trained)
+    args = (q, s, ops_.rows(ops_.m), ops_.rows(ops_.m), ops_.g,
+            *ops_.masks(ops_.m), ops_.weights)
+    return {
+        # a value on a rounding tie may land one int8 step apart
+        'round_int8_steps': (
+            int(jnp.max(jnp.abs(q.astype(jnp.int32) - rq))), 1),
+        'round_scales': _bounded(s, rs, RTOL_F32),
+        'round_q8_aggregate': _bounded(ops.safa_aggregate_packed_q8(*args),
+                                       ref.safa_aggregate_q8_ref(*args),
+                                       RTOL_F32),
+    }
+
+
+def tier_round(ops_: Operands, k: int = 24):
+    """gather_rows, scatter_rows and the tier-rows kernel against plain
+    indexing and the Eq. 6-8 delta oracle, on a value buffer of 64 rows
+    whose K read slots and K write slots are distinct and disjoint (the
+    tier schedule's invariant)."""
+    import jax.numpy as jnp
+
+    from repro.kernels import backend, ops
+    r = backend.row_pad(2 * k + 1)
+    buf = ops_.rows(r)
+    order = ops_.rng.permutation(r - 1)
+    srcs = jnp.asarray(order[:k], jnp.int32)
+    dsts = jnp.asarray(order[k:2 * k], jnp.int32)
+    trained = ops_.rows(k)
+    p, u, d, _ = ops_.masks(k)
+    w = jnp.asarray(ops_.rng.dirichlet(jnp.ones(k)) * 0.5, jnp.float32)
+    agg = ops_.g * 0.9
+
+    c0 = buf[srcs]
+    c1 = jnp.where((d & ~p)[:, None], ops_.g[None], c0)
+    c1 = jnp.where(p[:, None], trained, c1)
+    c2 = jnp.where(u[:, None], trained, c1)
+    want = (agg + jnp.sum(w[:, None] * (c1 - c0), axis=0),
+            agg + jnp.sum(w[:, None] * (c2 - c0), axis=0),
+            buf.at[dsts].set(c2))
+    got = ops.safa_aggregate_packed_tier_rows(buf, trained, ops_.g, agg,
+                                              srcs, dsts, p, u, d, w)
+    return {
+        'round_gather_rows': _bounded(ops.gather_rows(buf, srcs), c0, 0.0),
+        'round_scatter_rows': _bounded(ops.scatter_rows(buf + 0.0, dsts, c2),
+                                       want[2], 0.0),
+        'round_tier_rows': _bounded(got, want, RTOL_F32),
+    }
+
+
+def one_chip(wl: Workload, device: dict, compiles: Compiles):
+    packed, c_packed = run_phase(wl, compiles, kernels=True,
+                                 use_kernel='packed')
+    tree, c_tree = run_phase(wl, compiles, kernels=False, use_kernel=False)
+    report('a_dense_packed', device, {
+        'run_vs_tree_path': _bounded(packed.final_global, tree.final_global,
+                                     RUN_RTOL_F32),
+        'round_dense_kernel': dense_round(
+            Operands(wl, packed.final_global, wire=False)),
+    }, compile_s=c_packed, eval_loss=packed.best_eval['loss'],
+        reference='use_kernel=False (the XLA tree path)',
+        reference_compile_s=c_tree,
+        reference_eval_loss=tree.best_eval['loss'])
+
+    q8, c_q8 = run_phase(wl, compiles, kernels=True, use_kernel='packed',
+                         wire='int8')
+    report('b_int8_packed', device, {
+        'run_vs_phase_a': _bounded(q8.final_global, packed.final_global,
+                                   RTOL_INT8),
+        **wire_round(Operands(wl, q8.final_global, wire=True)),
+    }, compile_s=c_q8, eval_loss=q8.best_eval['loss'],
+        reference='phase a (f32 wire); oracles quantize_packed_ref, '
+                  'safa_aggregate_q8_ref')
+
+    tier, c_tier = run_phase(wl, compiles, kernels=True,
+                             use_kernel='packed', schedule='sparse_tier')
+    report('c_sparse_tier_packed', device, {
+        'run_vs_phase_a': _bounded(tier.final_global, packed.final_global,
+                                   RUN_RTOL_F32),
+        **tier_round(Operands(wl, tier.final_global, wire=False)),
+    }, compile_s=c_tier, eval_loss=tier.best_eval['loss'],
+        reference='phase a (dense); indexing and the Eq. 6-8 delta oracle')
+
+
+def fleet_members(wl: Workload):
+    from repro import api
+    return [api.SweepMember(env=wl.env.replace(draw_seed=s),
+                            fraction=FRACTION, lag_tolerance=LAG_TOLERANCE,
+                            seed=s)
+            for s in range(FLEET_SIZE)]
+
+
+def four_chips(device: dict, compiles: Compiles):
+    """The S=8 fleet sharded over 4 chips against the same members run
+    one after another on one chip (``engine='sequential'``, the sweep's
+    per-member reference; the fleet as one unsharded vmapped program needs
+    22.15 GB of HBM by the v5e compiler's count, above one chip's 15.75).
+    Each member keeps the paper's m=100 clients, batch 40, 5 epochs and
+    CNN; its federation holds 8,000 images rather than 70,000, because
+    the client data is compiled into every program as constants and this
+    phase compiles two programs on four chips."""
+    import jax
+
+    from repro import api
+    from repro.configs import PAPER_TASKS
+    t = PAPER_TASKS['task2_cnn']
+    wl = Workload(m=t['m'], dataset_size=FLEET_DATASET,
+                  batch_size=t['batch_size'], epochs=t['epochs'], lr=t['lr'],
+                  t_lim=t['t_lim'])
+
+    def sweep(engine: str):
+        runner = api.Experiment(
+            wl.task, wl.env, wl.spec(),
+            api.ExecSpec(engine=engine, use_kernel='packed',
+                         eval_every=ROUNDS),
+            rounds=ROUNDS, seed=0).compile()
+        return runner, runner.run_sweep(fleet_members(wl))
+
+    compiles.mark()
+    runner, sharded = sweep('fleet')
+    secs, texts = compiles.since_mark()
+    _require(any('tpu_custom_call' in t for t in texts),
+             'no compiled fleet program holds tpu_custom_call')
+    where = {sh.device for leaf in jax.tree.leaves(runner.fleet_global)
+             for sh in leaf.addressable_shards}
+    _require(len(where) == 4,
+             f'the fleet sits on {len(where)} devices, not 4')
+    _, single = sweep('sequential')
+    worst = max((_bounded(a.final_global, b.final_global, RUN_RTOL_F32)
+                 for a, b in zip(sharded, single)),
+                key=lambda t: t[0] / max(t[1], 1e-30))
+    report('fleet_sharded_4', device, {'run_vs_sequential': worst},
+           compile_s=secs, members=FLEET_SIZE, shard_devices=len(where),
+           eval_loss=max(h.best_eval['loss'] for h in sharded),
+           dataset_size=FLEET_DATASET,
+           reference='the same members run one by one on one device')
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument('--chips', type=int, choices=(1, 4), default=1,
+                    help='4 runs only the sharded-fleet phase')
+    args = ap.parse_args(argv)
+
+    # fails here, before touching JAX, outside a checkout of the repo
+    from repro.launch.compile_cache import enable_compile_cache
+
+    # XLA reads its flags when JAX first starts the backend, below
+    if os.path.isdir(DUMP_DIR):
+        shutil.rmtree(DUMP_DIR)
+    os.environ['XLA_FLAGS'] = (
+        os.environ.get('XLA_FLAGS', '')
+        + f' --xla_dump_to={DUMP_DIR} --xla_dump_hlo_as_text').strip()
+    import jax
+    devices = jax.devices()
+    if devices[0].platform != 'tpu':
+        print(f'chip_smoke: no TPU — JAX sees {len(devices)} '
+              f'{devices[0].platform} device(s); this script runs only on '
+              f'the chip', file=sys.stderr)
+        return 2
+    if len(devices) < args.chips:
+        print(f'chip_smoke: --chips {args.chips} needs {args.chips} TPU '
+              f'devices, JAX sees {len(devices)}', file=sys.stderr)
+        return 2
+    enable_compile_cache()
+    compiles = Compiles()
+    device = {'platform': devices[0].platform,
+              'kind': devices[0].device_kind, 'count': args.chips}
+    # the phases are compared at f32 tolerance, so the local training
+    # they compare runs its matmuls and convolutions in f32 arithmetic
+    with jax.default_matmul_precision('float32'):
+        if args.chips == 4:
+            four_chips(device, compiles)
+        else:
+            one_chip(Workload.paper(), device, compiles)
+    print(json.dumps({'ok': True, 'device': device}), flush=True)
+    return 0
+
+
+if __name__ == '__main__':
+    sys.exit(main())

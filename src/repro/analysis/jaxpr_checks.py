@@ -13,7 +13,7 @@ admits, builds the exact segment program ``CompiledRunner`` dispatches
   the registration, not a one-off benchmark assert; cells with no
   declared budget (e.g. the leaf-wise path, whose count scales with the
   model's leaf count) report the measured count informationally.
-* **JAX002** — donations take effect: every ``pjit`` call that donates
+* **JAX002** — donations take effect: every ``jit`` call that donates
   arguments must expose, for each donated input buffer, a distinct
   output with the same shape/dtype — otherwise XLA silently drops the
   donation and the engine pays a hidden model-sized copy per segment.
@@ -72,7 +72,7 @@ ALIAS_CONTRACTS = {**safa_aggregate.ALIAS_CONTRACTS, **ops.ALIAS_CONTRACTS,
                    **comm_quant.ALIAS_CONTRACTS}
 
 _CALLBACK_PRIMS = frozenset(
-    ('pure_callback', 'io_callback', 'debug_callback', 'callback'))
+    ('pure_callback', 'io_callback', 'debug_callback', 'debug_print'))
 
 _TASK = None
 
@@ -192,15 +192,18 @@ def precompute_cell(cell: Cell, task=None):
     return fleet
 
 
-def lower_cell(cell: Cell, task=None) -> CellTrace:
+def lower_cell(cell: Cell, task=None, env=None,
+               rounds: int = ROUNDS) -> CellTrace:
     """Build the cell's segment program exactly as ``CompiledRunner``
-    does and trace it (no execution, no compile)."""
+    does and trace it (no execution, no compile).  ``task``/``env``
+    default to the analysis's tiny regression federation; a single run
+    (``engine='scan'``) takes any task, env and round count."""
     task = task if task is not None else _tiny_task()
     pdef, ex = cell.pdef, cell.ex
     stateless = _stateless(pdef, ex)
     if ex.engine == 'scan':
-        exp = api.Experiment(task, _tiny_env(), cell.spec, ex,
-                             rounds=ROUNDS, seed=0)
+        exp = api.Experiment(task, env if env is not None else _tiny_env(),
+                             cell.spec, ex, rounds=rounds, seed=0)
         sched = exp.precompute()
         st = _init_state(task, exp.env.m, exp.seed, pdef.uses_cache,
                          stateless)
@@ -302,14 +305,10 @@ def _walk_eqns(jaxpr, *, in_scan=False):
 
 
 def _kernel_name(eqn) -> str:
-    """Kernel body name of a pallas_call eqn; vmap's batching rule
-    appends ``_batched`` (the fleet engine vmaps the single-run kernels),
-    stripped here so names key into the modules' ALIAS_CONTRACTS."""
-    info = eqn.params.get('name_and_src_info')
-    name = str(info).split(' at ')[0] if info is not None else '<unknown>'
-    while name.endswith('_batched'):
-        name = name[:-len('_batched')]
-    return name
+    """Kernel body name of a pallas_call eqn, from the kernel jaxpr's
+    debug info (``'<body> at <file>:<line>'``); vmapped launches keep
+    the body's name, so names key into the modules' ALIAS_CONTRACTS."""
+    return eqn.params['jaxpr'].debug_info.func_src_info.split(' at ')[0]
 
 
 def _pallas_sites(jaxpr):
@@ -326,18 +325,18 @@ def _pallas_sites(jaxpr):
 
 
 def _check_donations(jaxpr):
-    """JAX002: for every pjit eqn with donated invars, each donated
+    """JAX002: for every jit eqn with donated invars, each donated
     buffer must be matchable 1:1 to an output aval (shape+dtype) —
     the necessary condition for XLA to honour the donation.  Returns
     (ok, detail)."""
     for eqn, _ in _walk_eqns(jaxpr):
-        if eqn.primitive.name != 'pjit':
+        if eqn.primitive.name != 'jit':
             continue
         donated = eqn.params.get('donated_invars', ())
         if not any(donated):
             continue
         outs = [(v.aval.shape, v.aval.dtype) for v in eqn.outvars]
-        name = eqn.params.get('name', '<pjit>')
+        name = eqn.params.get('name', '<jit>')
         for i, (inv, don) in enumerate(zip(eqn.invars, donated)):
             if not don:
                 continue
@@ -346,7 +345,7 @@ def _check_donations(jaxpr):
                 outs.remove(key)    # each output absorbs one donation
             else:
                 return False, (
-                    f'pjit {name!r}: donated input {i} '
+                    f'jit {name!r}: donated input {i} '
                     f'{inv.aval.str_short()} has no matching output '
                     f'buffer — XLA drops the donation (hidden copy)')
     return True, 'all donated buffers have matching outputs'

@@ -54,6 +54,7 @@ from repro import fedsim
 from repro.core import federation, protocol, schedules
 from repro.core.federation import Task
 from repro.core.schedules import History, RoundRecord, SweepMember
+from repro.kernels.backend import row_pad
 
 __all__ = [
     'CompiledRunner', 'ExecSpec', 'Experiment', 'FedAsyncSpec', 'FedAvgSpec',
@@ -644,7 +645,8 @@ def _pack_layout(global_w, wire):
 def _safa_prepare_state(st, weights, ex, fleet: bool, sched=None):
     """Sparse-delta carries: the running aggregate tree, or — under
     ``use_kernel='packed'`` — the whole state as resident pack buffers
-    ([m+1, N] with a trailing scratch row for sentinel slots).
+    ([m+1, N] with a trailing scratch row for sentinel slots, padded to
+    whole 8-row groups for the in-place row kernels).
 
     Lag-tier carries (``schedule='sparse_tier'``): the [m, ...] stacks are
     never materialised — the cache slot becomes the O(tau+quota) value
@@ -670,7 +672,8 @@ def _safa_prepare_state(st, weights, ex, fleet: bool, sched=None):
     pack_m = kops.pack_fleet if fleet else kops.pack_stacked
 
     def scratch(b):
-        pad = [(0, 0)] * (b.ndim - 2) + [(0, 1), (0, 0)]
+        m = b.shape[-2]
+        pad = [(0, 0)] * (b.ndim - 2) + [(0, row_pad(m + 1) - m), (0, 0)]
         return jnp.pad(b, pad)
 
     st.packed = (pack_g(st.global_w, spec),
@@ -683,8 +686,10 @@ def _safa_prepare_state(st, weights, ex, fleet: bool, sched=None):
 
 def _safa_prepare_tier_state(st, weights, ex, fleet: bool, sched):
     """Build the lag-tier carry from the global alone: value buffer
-    (capacity + 1 rows of the init global; trailing row is scratch) and
-    the running aggregate ``global * sum(weights)``."""
+    (capacity + 1 rows of the init global; row ``capacity`` is scratch,
+    and the packed buffer is padded to whole 8-row groups for the
+    in-place row kernels) and the running aggregate
+    ``global * sum(weights)``."""
     from repro.kernels import ops as kops
     cap = int(sched.capacity)
     wsum = jnp.sum(weights, axis=-1) if fleet else jnp.sum(weights)
@@ -707,6 +712,7 @@ def _safa_prepare_tier_state(st, weights, ex, fleet: bool, sched):
         _tree_member(st.global_w, 0) if fleet else st.global_w, ex.wire)
     pack_g = kops.pack_stacked if fleet else kops.pack_global
     gbuf = pack_g(st.global_w, spec)
+    cap = row_pad(cap + 1) - 1
     st.packed = (gbuf, rows(gbuf),
                  pack_g(jax.tree.map(scale, st.global_w), spec))
     st.spec = spec
@@ -1156,6 +1162,41 @@ class Experiment:
         return '|'.join(parts)
 
 
+def _sharded_segment(pdef, train_fn, ex, ctx, spec, sharding):
+    """``pdef.fleet_segment`` on every device of ``sharding``'s mesh at
+    once, each device running the unsharded segment program on the
+    members it holds; the outputs are reassembled into fleet arrays
+    sharded like the inputs.
+
+    Not one partitioned program: Mosaic kernels cannot be partitioned
+    automatically, and a fleet traced over a mesh (jit, shard_map or pmap
+    alike) carries the mesh into its types, which JAX's batching rule for
+    grouped convolutions — a CNN's weight gradient, vmapped over clients
+    and then over members — refuses.  JAX dispatches asynchronously, so
+    the devices run concurrently; each compiles its own copy."""
+    devices = list(sharding.mesh.devices.flat)
+
+    def run(st, seg, weights):
+        leaves, treedef = jax.tree.flatten((st.tree(), seg, weights, ctx))
+        by_device = [{sh.device: sh.data for sh in leaf.addressable_shards}
+                     for leaf in leaves]
+        outs = []
+        for d in devices:
+            tree, seg_d, w_d, ctx_d = jax.tree.unflatten(
+                treedef, [shards[d] for shards in by_device])
+            local = _RunState()
+            local.set_tree(tree)
+            local.spec = spec
+            pdef.fleet_segment(local, seg_d, w_d, train_fn, ex, ctx_d)
+            outs.append(local.tree())
+        st.set_tree(jax.tree.map(
+            lambda *parts: jax.make_array_from_single_device_arrays(
+                (sum(p.shape[0] for p in parts),) + parts[0].shape[1:],
+                sharding, list(parts)),
+            *outs))
+    return run
+
+
 class CompiledRunner:
     """Executes an ``Experiment``.  ``run()`` drives the single
     simulation; ``run_sweep(members)`` drives S member configurations as
@@ -1166,6 +1207,11 @@ class CompiledRunner:
         self.exp = exp
         self._pdef = exp._pdef
         self._dev = None            # cached device-resident schedule
+        #: after a fleet-engine ``run_sweep``: the members' final global
+        #: models stacked [S', ...], device-resident with the placement
+        #: the sweep ran on (sharded over the fleet axis across devices;
+        #: S' > S when the fleet was padded, rows past S copy member S-1)
+        self.fleet_global = None
 
     # -- single run ---------------------------------------------------------
 
@@ -1274,7 +1320,8 @@ class CompiledRunner:
         experiment's own env/seed are not used here; each member carries
         its own.  ``engine='fleet'`` (default) executes all members in a
         single vmapped-scan dispatch per eval segment (sharded over JAX
-        devices when several are visible and S divides evenly);
+        devices when several are visible, the fleet padded with copies
+        of its last member to a multiple of the device count);
         ``engine='sequential'`` drives the same precomputed schedules
         through S per-member scan runs — bit-identical per member."""
         exp = self.exp
@@ -1399,14 +1446,28 @@ class CompiledRunner:
                 _apply_saved_history(hist, d)
 
         dev = fleet.to_device()
+        size = len(members)
         ndev = len(jax.devices())
-        if ex.shard and ndev > 1 and len(members) % ndev == 0:
+        pdef = self._pdef
+        segment = lambda st_, seg_, w_: pdef.fleet_segment(
+            st_, seg_, w_, train_fn, ex, ctx)
+        if ex.shard and ndev > 1:
+            # every device holds an equal share of the fleet: pad it with
+            # copies of the last member up to a multiple of the device
+            # count (their results are never read or saved)
             from jax.sharding import Mesh, NamedSharding, PartitionSpec
+            pad = -size % ndev
+            tree, dev, weights, ctx = jax.tree.map(
+                lambda a: jnp.concatenate(
+                    [a, jnp.repeat(a[-1:], pad, axis=0)]) if pad else a,
+                (st.tree(), dev, weights, ctx))
             mesh = Mesh(np.asarray(jax.devices()), ('fleet',))
             sharding = NamedSharding(mesh, PartitionSpec('fleet'))
             tree, dev, weights, ctx = jax.device_put(
-                (st.tree(), dev, weights, ctx), sharding)
+                (tree, dev, weights, ctx), sharding)
             st.set_tree(tree)
+            segment = _sharded_segment(self._pdef, train_fn, ex, ctx,
+                                       st.spec, sharding)
 
         start = evals[start_seg - 1] if start_seg else 0
         done = 0
@@ -1415,7 +1476,7 @@ class CompiledRunner:
             stop = evals[k]
             seg = jax.tree.map(
                 lambda a, s=start, e=stop: a[:, s:e], dev)
-            self._pdef.fleet_segment(st, seg, weights, train_fn, ex, ctx)
+            segment(st, seg, weights)
             if self._pdef.finish_segment is not None:
                 self._pdef.finish_segment(st, weights, True)
             # one host gather per leaf: slicing members out of a (possibly
@@ -1429,11 +1490,14 @@ class CompiledRunner:
             start = stop
             done += 1
             if checkpoint is not None:
-                ckpt.save_run(checkpoint, st.tree(), seg_done=k + 1,
-                              histories=hists, fingerprint=fingerprint)
+                ckpt.save_run(checkpoint,
+                              jax.tree.map(lambda a: a[:size], st.tree()),
+                              seg_done=k + 1, histories=hists,
+                              fingerprint=fingerprint)
             if max_segments is not None and done >= max_segments \
                     and k + 1 < len(evals):
                 break
         for s, hist in enumerate(hists):
             hist.final_global = _tree_member(g_host, s)
+        self.fleet_global = st.global_w
         return hists

@@ -974,7 +974,7 @@ def safa_run_fleet_sparse_delta_packed(gbuf, lbuf, cbuf, abuf,
                                        wire='f32'):
     """Fleet counterpart of ``safa_run_scan_sparse_delta_packed`` (one
     vmapped scan over [S, ...] pack buffers; the rows kernels batch under
-    vmap into the same launches as their explicit ``*_fleet`` forms)."""
+    vmap into launches over a grid with a leading fleet dimension)."""
     run = lambda g, l, c, a, s, w: _safa_sparse_delta_packed_scan(
         g, l, c, a, s, w, local_train_fn, spec, wire)
     return jax.vmap(run)(gbuf, lbuf, cbuf, abuf, schedule, weights)

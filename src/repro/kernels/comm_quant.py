@@ -11,9 +11,9 @@ Two granularities are exposed:
 
 * ``quantize`` / ``dequantize`` — one flat [N] vector per call (the
   per-leaf reference path: 2 dispatches per pytree leaf);
-* ``quantize_packed`` / ``dequantize_packed`` (+ ``quantize_packed_fleet``)
-  — a whole packed [m, N] (or [S, m, N]) upload buffer in ONE grid
-  dispatch, each client row block-quantised independently.  This is the
+* ``quantize_packed`` / ``dequantize_packed`` — a whole packed [m, N]
+  upload buffer in ONE grid dispatch, each client row block-quantised
+  independently (fleets batch it under ``jax.vmap``).  This is the
   wire format of the compressed fast path: the simulated uplink carries
   the int8 buffer plus the [m, N/QBLOCK] f32 scale rows.
 """
@@ -25,7 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.backend import INTERPRET
+from repro.kernels import backend
+from repro.kernels.backend import fit_tile, padded_rows
 
 QBLOCK = 128
 DEFAULT_TILE = 2048  # values per program instance; must be multiple of QBLOCK
@@ -40,8 +41,34 @@ ALIAS_CONTRACTS = {
     '_dequant_kernel': ((),),
     '_quant_packed_kernel': ((),),
     '_dequant_packed_kernel': ((),),
-    '_quant_fleet_kernel': ((),),
 }
+
+
+# Scale layout inside the kernels.  A grid step over a ``tile``-wide
+# column block owns ``tile // QBLOCK`` scales per row, fewer than the 128
+# lanes a TPU block must span, so the kernels see the scales as
+# ``[N // tile, rows, tile // QBLOCK]`` with the leading axis squeezed out
+# of each block (the last two block dims are then the array's own).  The
+# public layout stays ``[rows, N // QBLOCK]``; these two helpers convert.
+
+def scales_to_blocks(scales, tile: int):
+    """[rows, N/QBLOCK] -> [N/tile, rows, tile/QBLOCK]."""
+    rows, nb = scales.shape
+    sb = tile // QBLOCK
+    return scales.reshape(rows, nb // sb, sb).transpose(1, 0, 2)
+
+
+def scales_from_blocks(blocks):
+    """Inverse of ``scales_to_blocks``."""
+    nt, rows, sb = blocks.shape
+    return blocks.transpose(1, 0, 2).reshape(rows, nt * sb)
+
+
+def scale_spec(rows: int, tile: int, index_map):
+    """BlockSpec of the blocked scale layout: ``index_map`` returns the
+    (tile, row-block) indices of the step."""
+    return pl.BlockSpec((None, rows, tile // QBLOCK),
+                        lambda *g: (*index_map(*g), 0))
 
 
 def _quant_kernel(x_ref, q_ref, scale_ref):
@@ -68,19 +95,20 @@ def quantize(x, *, tile: int = DEFAULT_TILE):
     pad = (-n) % tile
     xp = jnp.pad(x, (0, pad)).reshape(1, -1)
     np_ = xp.shape[1]
-    grid = (np_ // tile,)
+    nt = np_ // tile
     q, s = pl.pallas_call(
         _quant_kernel,
-        grid=grid,
+        grid=(nt,),
         in_specs=[pl.BlockSpec((1, tile), lambda i: (0, i))],
         out_specs=[pl.BlockSpec((1, tile), lambda i: (0, i)),
-                   pl.BlockSpec((1, tile // QBLOCK), lambda i: (0, i))],
+                   scale_spec(1, tile, lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((1, np_), jnp.int8),
-                   jax.ShapeDtypeStruct((1, np_ // QBLOCK), jnp.float32)],
-        interpret=INTERPRET,
+                   jax.ShapeDtypeStruct((nt, 1, tile // QBLOCK),
+                                        jnp.float32)],
+        interpret=backend.interpret(),
     )(xp)
     n_scales = -(-n // QBLOCK)
-    return q[0, :n], s[0, :n_scales]
+    return q[0, :n], s.reshape(-1)[:n_scales]
 
 
 @functools.partial(jax.jit, static_argnames=('tile', 'n'))
@@ -91,16 +119,15 @@ def dequantize(q, scales, *, n: int, tile: int = DEFAULT_TILE):
     np_ = qp.shape[1]
     sp = jnp.pad(scales, (0, np_ // QBLOCK - scales.shape[0]),
                  constant_values=1.0).reshape(1, -1)
-    grid = (np_ // tile,)
     x = pl.pallas_call(
         _dequant_kernel,
-        grid=grid,
+        grid=(np_ // tile,),
         in_specs=[pl.BlockSpec((1, tile), lambda i: (0, i)),
-                  pl.BlockSpec((1, tile // QBLOCK), lambda i: (0, i))],
+                  scale_spec(1, tile, lambda i: (i, 0))],
         out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, np_), jnp.float32),
-        interpret=INTERPRET,
-    )(qp, sp)
+        interpret=backend.interpret(),
+    )(qp, scales_to_blocks(sp, tile))
     return x[0, :n]
 
 
@@ -125,17 +152,11 @@ def _dequant_packed_kernel(q_ref, scale_ref, x_ref):
     x_ref[...] = (q * scale_ref[...][:, :, None]).reshape(m, t)
 
 
-def _quant_fleet_kernel(x_ref, q_ref, scale_ref):
-    """Fleet body: squeeze the leading [1, m, T] fleet-block dim so the
-    math is exactly the single-buffer kernel's."""
-    x = x_ref[...][0].astype(jnp.float32)           # [m, T]
-    m, t = x.shape
-    xb = x.reshape(m, t // QBLOCK, QBLOCK)
-    amax = jnp.max(jnp.abs(xb), axis=2, keepdims=True)
-    scale = jnp.maximum(amax, 1e-30) / 127.0
-    q = jnp.clip(jnp.round(xb / scale), -127, 127).astype(jnp.int8)
-    q_ref[...] = q.reshape(m, t)[None]
-    scale_ref[...] = scale.reshape(m, -1)[None]
+
+def _col_bytes(m: int) -> int:
+    """VMEM bytes per lane column of the packed (de)quantise blocks: the
+    f32 and int8 [m, tile] blocks, rows padded to their tiles."""
+    return 4 * padded_rows(m, 4) + padded_rows(m, 1)
 
 
 def _check_packed(n: int, tile: int):
@@ -159,17 +180,20 @@ def quantize_packed(x, *, tile: int = DEFAULT_TILE):
     """
     m, n = x.shape
     _check_packed(n, tile)
-    grid = (n // tile,)
-    return pl.pallas_call(
+    tile, params = fit_tile(tile, _col_bytes(m))
+    q, s = pl.pallas_call(
         _quant_packed_kernel,
-        grid=grid,
+        grid=(n // tile,),
         in_specs=[pl.BlockSpec((m, tile), lambda i: (0, i))],
         out_specs=[pl.BlockSpec((m, tile), lambda i: (0, i)),
-                   pl.BlockSpec((m, tile // QBLOCK), lambda i: (0, i))],
+                   scale_spec(m, tile, lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((m, n), jnp.int8),
-                   jax.ShapeDtypeStruct((m, n // QBLOCK), jnp.float32)],
-        interpret=INTERPRET,
+                   jax.ShapeDtypeStruct((n // tile, m, tile // QBLOCK),
+                                        jnp.float32)],
+        compiler_params=params,
+        interpret=backend.interpret(),
     )(x)
+    return q, scales_from_blocks(s)
 
 
 @functools.partial(jax.jit, static_argnames=('tile',))
@@ -178,34 +202,15 @@ def dequantize_packed(q, scales, *, tile: int = DEFAULT_TILE):
     x [m, N] f32, one grid dispatch."""
     m, n = q.shape
     _check_packed(n, tile)
-    grid = (n // tile,)
+    tile, params = fit_tile(tile, _col_bytes(m))
     return pl.pallas_call(
         _dequant_packed_kernel,
-        grid=grid,
+        grid=(n // tile,),
         in_specs=[pl.BlockSpec((m, tile), lambda i: (0, i)),
-                  pl.BlockSpec((m, tile // QBLOCK), lambda i: (0, i))],
+                  scale_spec(m, tile, lambda i: (i, 0))],
         out_specs=pl.BlockSpec((m, tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=INTERPRET,
-    )(q, scales)
+        compiler_params=params,
+        interpret=backend.interpret(),
+    )(q, scales_to_blocks(scales, tile))
 
-
-@functools.partial(jax.jit, static_argnames=('tile',))
-def quantize_packed_fleet(x, *, tile: int = DEFAULT_TILE):
-    """Fleet variant of ``quantize_packed``: x [S, m, N] -> (q [S, m, N],
-    scales [S, m, N/QBLOCK]) over an explicit (S, N // tile) grid — all S
-    servers' upload buffers quantised in one dispatch."""
-    s, m, n = x.shape
-    _check_packed(n, tile)
-    grid = (s, n // tile)
-    return pl.pallas_call(
-        _quant_fleet_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((1, m, tile), lambda s, i: (s, 0, i))],
-        out_specs=[pl.BlockSpec((1, m, tile), lambda s, i: (s, 0, i)),
-                   pl.BlockSpec((1, m, tile // QBLOCK),
-                                lambda s, i: (s, 0, i))],
-        out_shape=[jax.ShapeDtypeStruct((s, m, n), jnp.int8),
-                   jax.ShapeDtypeStruct((s, m, n // QBLOCK), jnp.float32)],
-        interpret=INTERPRET,
-    )(x)

@@ -9,15 +9,10 @@ Two tree-level aggregation paths are exposed:
   once at the end to a tile multiple), so Eq. 6-8 runs as exactly one
   ``pallas_call`` per round regardless of model depth.
 
-For fleet-major callers the pack gains a leading fleet axis:
-``safa_aggregate_tree_packed_fleet`` flattens [S, m, ...] stacked trees into
-one [S, m, N_total] buffer and aggregates all S independent servers in a
-single explicit fleet-grid dispatch (``safa_aggregate_packed_fleet``).
-Note the vmapped fleet *engine* does not call this entry point: inside
+Fleets of S servers batch these under ``jax.vmap``: inside
 ``protocol.safa_run_fleet`` the per-round ``safa_aggregate_packed`` call is
-batched by JAX's vmap rule into an equivalent batched-grid launch.  Both
-kernels share one Eq. 6-8 body (``safa_aggregate._agg_math``) and are
-regression-tested against each other.
+batched by JAX's vmap rule into one launch over a grid with a leading
+fleet dimension (``pack_fleet``/``unpack_fleet`` lay out [S, m, N]).
 """
 from __future__ import annotations
 
@@ -30,36 +25,30 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.protocol import AggregationResult
-from repro.kernels.backend import INTERPRET
+from repro.kernels import backend
+from repro.kernels.backend import SUBLANES, fit_tile, padded_rows
 from repro.kernels.comm_quant import (QBLOCK, dequantize, dequantize_packed,
-                                      quantize, quantize_packed,
-                                      quantize_packed_fleet)
-from repro.kernels.safa_aggregate import (DEFAULT_TILE, safa_aggregate,
+                                      quantize, quantize_packed)
+from repro.kernels.safa_aggregate import (DEFAULT_TILE, check_rows,
+                                          check_width, group_spec, row_of,
+                                          safa_aggregate,
                                           safa_aggregate_packed,
-                                          safa_aggregate_packed_fleet,
                                           safa_aggregate_packed_q8,
-                                          safa_aggregate_packed_q8_fleet,
                                           safa_aggregate_packed_q8_rows,
-                                          safa_aggregate_packed_q8_rows_fleet,
                                           safa_aggregate_packed_q8_tier_rows,
                                           safa_aggregate_packed_rows,
-                                          safa_aggregate_packed_rows_fleet,
-                                          safa_aggregate_packed_tier_rows)
+                                          safa_aggregate_packed_tier_rows,
+                                          set_row, slot_spec, write_row)
 from repro.kernels.swa_attention import swa_attention
 
-__all__ = ['safa_aggregate', 'safa_aggregate_packed',
-           'safa_aggregate_packed_fleet', 'safa_aggregate_tree',
-           'safa_aggregate_tree_packed', 'safa_aggregate_tree_packed_fleet',
-           'safa_aggregate_packed_q8', 'safa_aggregate_packed_q8_fleet',
-           'safa_aggregate_packed_rows', 'safa_aggregate_packed_rows_fleet',
-           'safa_aggregate_packed_q8_rows',
-           'safa_aggregate_packed_q8_rows_fleet',
+__all__ = ['safa_aggregate', 'safa_aggregate_packed', 'safa_aggregate_tree',
+           'safa_aggregate_tree_packed', 'safa_aggregate_packed_q8',
+           'safa_aggregate_packed_rows', 'safa_aggregate_packed_q8_rows',
            'safa_aggregate_packed_tier_rows',
            'safa_aggregate_packed_q8_tier_rows',
-           'gather_rows', 'scatter_rows', 'gather_rows_fleet',
-           'scatter_rows_fleet',
+           'gather_rows', 'scatter_rows',
            'quantize', 'dequantize', 'quantize_packed', 'dequantize_packed',
-           'quantize_packed_fleet', 'safa_compressed_update',
+           'safa_compressed_update',
            'weighted_merge_packed', 'weighted_merge_tree_packed',
            'wire_roundtrip_packed', 'wire_spec',
            'swa_attention', 'quantize_tree', 'dequantize_tree',
@@ -249,53 +238,50 @@ def unpack_fleet(buf, spec: PackSpec):
 # and move only the K = O(quota) active rows per round: ``gather_rows``
 # pulls them out for local training, ``scatter_rows`` writes results back
 # in place (the buffer is aliased to the output, so untouched rows are
-# never copied).  Both use the same scalar-prefetch indexing as the
-# rows-aggregation kernels in ``safa_aggregate``.
+# never copied).  Both use the same scalar-prefetch indexing and 8-row
+# group blocks as the rows-aggregation kernels in ``safa_aggregate``.
 
 
 #: Static alias inventory for this module's pallas kernels (see
 #: ``safa_aggregate.ALIAS_CONTRACTS`` for the format): the scatter
-#: kernels alias the row buffer to the output — untouched rows never
+#: kernel aliases the row buffer to the output — untouched rows never
 #: move — and everything else is copy-out.  ``repro.analysis`` checks
 #: this dict against the call sites (REP005) and lowered cells (JAX003).
 ALIAS_CONTRACTS = {
     '_copy_kernel': ((),),
     '_scatter_kernel': (((2, 0),),),        # buf -> out (rows prefetched)
-    '_copy_fleet_kernel': ((),),
-    '_scatter_fleet_kernel': (((2, 0),),),
     '_weighted_merge_kernel': ((),),
 }
 
 
 def _copy_kernel(rows_ref, src_ref, dst_ref):
-    del rows_ref  # consumed by the index maps
-    dst_ref[...] = src_ref[...]
+    j = pl.program_id(1)
+    set_row(dst_ref, j, row_of(src_ref, rows_ref[j]))
 
 
-def _scatter_kernel(rows_ref, vals_ref, buf_ref, out_ref):
-    del rows_ref, buf_ref  # buf only feeds the output via aliasing
-    out_ref[...] = vals_ref[...]
+def _scatter_kernel(rows_ref, vals_ref, buf_ref, out_ref, grp, sem):
+    del buf_ref  # aliased: read and written through out_ref
+    i, j = pl.program_id(0), pl.program_id(1)
+    write_row(out_ref, grp, sem, rows_ref[j], i, vals_ref.shape[1],
+              row_of(vals_ref, j))
 
 
 @functools.partial(jax.jit, static_argnames=('tile',))
 def gather_rows(buf, rows, *, tile: int = DEFAULT_TILE):
     """buf [R, N], rows [K] int32 < R -> [K, N] gathered rows (one
-    dispatch; only K·N elements stream through)."""
-    r, n = buf.shape
+    dispatch; only the K rows' 8-row groups stream through)."""
+    _, n = buf.shape
     k = rows.shape[0]
-    if n % tile:
-        raise ValueError(
-            f'packed buffer width {n} not a multiple of tile={tile}; '
-            f'pack with pad_to=tile')
+    check_width(n, tile)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(k, n // tile),
-        in_specs=[pl.BlockSpec((1, tile), lambda j, i, rows: (rows[j], i))],
-        out_specs=pl.BlockSpec((1, tile), lambda j, i, rows: (j, i)))
+        grid=(n // tile, k),        # k innermost: output groups stay put
+        in_specs=[group_spec(tile, lambda j, rows: rows[j])],
+        out_specs=slot_spec(tile))
     return pl.pallas_call(
         _copy_kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((k, n), buf.dtype),
-        interpret=INTERPRET)(rows.astype(jnp.int32), buf)
+        interpret=backend.interpret())(rows.astype(jnp.int32), buf)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,), static_argnames=('tile',))
@@ -303,89 +289,31 @@ def scatter_rows(buf, rows, vals, *, tile: int = DEFAULT_TILE):
     """Write vals [K, N] into buf [R, N] at ``rows`` and return the buffer
     (donated + aliased: untouched rows stay in place, no [R, N] copy).
 
-    Duplicate row indices write in slot order (last wins); sentinel slots
-    should point at a scratch row (R = m + 1, idx = m) so padding writes
-    land harmlessly."""
+    Each row is written by a read-modify-write of its 8-row group, so R
+    must be a multiple of 8 (``backend.row_pad``).  Duplicate row indices
+    write in slot order (last wins); sentinel slots should point at a
+    scratch row (idx = m of an ``row_pad(m + 1)``-row buffer) so padding
+    writes land harmlessly."""
     r, n = buf.shape
     k = rows.shape[0]
     if vals.shape != (k, n):
         raise ValueError(
             f'vals shape {vals.shape} does not match (K={k}, N={n})')
-    if n % tile:
-        raise ValueError(
-            f'packed buffer width {n} not a multiple of tile={tile}; '
-            f'pack with pad_to=tile')
+    check_width(n, tile)
+    check_rows(r)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(k, n // tile),
-        in_specs=[pl.BlockSpec((1, tile), lambda j, i, rows: (j, i)),
-                  pl.BlockSpec((1, tile), lambda j, i, rows: (rows[j], i))],
-        out_specs=pl.BlockSpec((1, tile), lambda j, i, rows: (rows[j], i)))
+        grid=(n // tile, k),
+        in_specs=[slot_spec(tile), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.VMEM((SUBLANES, tile), buf.dtype),
+                        pltpu.SemaphoreType.DMA])
     return pl.pallas_call(
         _scatter_kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, n), buf.dtype),
         # operand 0 is the prefetched rows, so buf is input index 2
         input_output_aliases={2: 0},
-        interpret=INTERPRET)(rows.astype(jnp.int32), vals, buf)
-
-
-def _copy_fleet_kernel(rows_ref, src_ref, dst_ref):
-    del rows_ref
-    dst_ref[...] = src_ref[...]
-
-
-def _scatter_fleet_kernel(rows_ref, vals_ref, buf_ref, out_ref):
-    del rows_ref, buf_ref
-    out_ref[...] = vals_ref[...]
-
-
-@functools.partial(jax.jit, static_argnames=('tile',))
-def gather_rows_fleet(buf, rows, *, tile: int = DEFAULT_TILE):
-    """Fleet variant: buf [S, R, N], rows [S, K] -> [S, K, N]."""
-    s, r, n = buf.shape
-    k = rows.shape[1]
-    if n % tile:
-        raise ValueError(
-            f'packed buffer width {n} not a multiple of tile={tile}; '
-            f'pack with pad_to=tile')
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(s, k, n // tile),
-        in_specs=[pl.BlockSpec((1, 1, tile),
-                               lambda b, j, i, rows: (b, rows[b, j], i))],
-        out_specs=pl.BlockSpec((1, 1, tile), lambda b, j, i, rows: (b, j, i)))
-    return pl.pallas_call(
-        _copy_fleet_kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, k, n), buf.dtype),
-        interpret=INTERPRET)(rows.astype(jnp.int32), buf)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,), static_argnames=('tile',))
-def scatter_rows_fleet(buf, rows, vals, *, tile: int = DEFAULT_TILE):
-    """Fleet variant: write vals [S, K, N] into buf [S, R, N] at per-member
-    ``rows`` [S, K] (donated + aliased, like ``scatter_rows``)."""
-    s, r, n = buf.shape
-    k = rows.shape[1]
-    if vals.shape != (s, k, n):
-        raise ValueError(
-            f'vals shape {vals.shape} does not match (S={s}, K={k}, N={n})')
-    if n % tile:
-        raise ValueError(
-            f'packed buffer width {n} not a multiple of tile={tile}; '
-            f'pack with pad_to=tile')
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(s, k, n // tile),
-        in_specs=[pl.BlockSpec((1, 1, tile), lambda b, j, i, rows: (b, j, i)),
-                  pl.BlockSpec((1, 1, tile),
-                               lambda b, j, i, rows: (b, rows[b, j], i))],
-        out_specs=pl.BlockSpec((1, 1, tile),
-                               lambda b, j, i, rows: (b, rows[b, j], i)))
-    return pl.pallas_call(
-        _scatter_fleet_kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, r, n), buf.dtype),
-        input_output_aliases={2: 0},
-        interpret=INTERPRET)(rows.astype(jnp.int32), vals, buf)
+        interpret=backend.interpret())(rows.astype(jnp.int32), vals, buf)
 
 
 def safa_aggregate_tree_packed(cache, trained, global_prev, *, picked,
@@ -422,29 +350,6 @@ def _require_f32(spec: PackSpec):
             'models')
 
 
-def safa_aggregate_tree_packed_fleet(cache, trained, global_prev, *, picked,
-                                     undrafted, deprecated, weights,
-                                     spec: PackSpec = None
-                                     ) -> AggregationResult:
-    """Fleet-batched single-dispatch Eq. 6-8 over fleet-stacked pytrees.
-
-    cache/trained: pytrees with [S, m, ...] leaves; global_prev: [S, ...]
-    leaves; picked/undrafted/deprecated/weights: [S, m].  All S independent
-    server aggregations run in ONE ``pallas_call`` over a (S, tiles) grid.
-    ``spec`` is the per-member layout (built from one member's global
-    tree); float32-only, like the single-run packed path.
-    """
-    if spec is None:
-        spec = pack_spec(jax.tree.map(lambda g: g[0], global_prev))
-    _require_f32(spec)
-    pc = pack_fleet(cache, spec)
-    pt = pack_fleet(trained, spec)
-    pg = pack_stacked(global_prev, spec)        # [S, n_padded]
-    ng, nc = safa_aggregate_packed_fleet(pc, pt, pg, picked, undrafted,
-                                         deprecated, weights)
-    return AggregationResult(unpack_stacked(ng, spec), unpack_fleet(nc, spec))
-
-
 # ---------------------------------------------------------------------------
 # Weighted-merge kernel: the staleness-adaptive aggregation family's
 # server step as one fused dispatch
@@ -478,10 +383,9 @@ def weighted_merge_packed(trained, global_prev, wrow, *,
     regardless of model depth; under the fleet engine's vmap the launch
     batches into an (S, tiles) grid.  Returns the new global row [N]."""
     m, np_ = trained.shape
-    if np_ % tile:
-        raise ValueError(
-            f'packed buffer width {np_} not a multiple of tile={tile}; '
-            f'pack with pad_to=tile')
+    check_width(np_, tile)
+    # trained block plus the global and output rows
+    tile, params = fit_tile(tile, 4 * padded_rows(m, 4) + 2 * 4 * SUBLANES)
     out = pl.pallas_call(
         _weighted_merge_kernel,
         grid=(np_ // tile,),
@@ -492,7 +396,8 @@ def weighted_merge_packed(trained, global_prev, wrow, *,
         ],
         out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, np_), trained.dtype),
-        interpret=INTERPRET,
+        compiler_params=params,
+        interpret=backend.interpret(),
     )(trained, global_prev.reshape(1, -1),
       wrow.astype(jnp.float32).reshape(m, 1))
     return out[0]

@@ -12,8 +12,9 @@ emits the new global tile + new cache tile.  HBM traffic drops from
 ``benchmarks/kernels_bench.py``).
 
 Layout: parameters are flattened to [m, N] (m = clients).  Grid is over N
-tiles; each program instance sees the full clients column for its tile —
-VMEM footprint = 2 * m * TILE * 4B (+ masks), e.g. m=32, TILE=2048 -> 512 KiB.
+tiles; each program instance sees the full clients column for its tile.
+The tile is narrowed from m and the dtypes (``backend.fit_tile``) so the
+double-buffered blocks fit the TPU's scoped VMEM.
 
 Two entry points share the kernel body:
 
@@ -24,13 +25,16 @@ Two entry points share the kernel body:
   ``input_output_aliases`` donating the cache buffer to the new-cache
   output, so the server never holds two full cache copies.
 
-The compressed-wire fast path adds ``safa_aggregate_packed_q8`` (+ fleet
-variant): the trained operand arrives as the int8 wire format
+The compressed-wire fast path adds ``safa_aggregate_packed_q8``: the
+trained operand arrives as the int8 wire format
 (q [m, N] + per-QBLOCK f32 scales, see ``comm_quant.quantize_packed``)
 and is dequantised *in-register* inside the same kernel body that applies
 Eq. 6-8 — the f32 [m, N] client-update matrix is never materialised in
 HBM on the aggregation input, and a fully compressed round is exactly two
 dispatches (quantize + this kernel).
+
+Fleets of S servers batch every entry point under ``jax.vmap``, which
+adds a leading fleet dimension to the grid.
 """
 from __future__ import annotations
 
@@ -41,9 +45,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# CPU containers run the kernel body in interpret mode; on TPU it compiles.
-from repro.kernels.backend import INTERPRET
-from repro.kernels.comm_quant import QBLOCK
+from repro.kernels import backend
+from repro.kernels.backend import SUBLANES, fit_tile, padded_rows
+from repro.kernels.comm_quant import QBLOCK, scale_spec, scales_to_blocks
 
 DEFAULT_TILE = 2048
 
@@ -59,13 +63,9 @@ DEFAULT_TILE = 2048
 #: (cache donated in place).
 ALIAS_CONTRACTS = {
     '_kernel': ((), ((0, 1),)),          # cache -> new_cache when packed
-    '_fleet_kernel': (((0, 1),),),       # cache -> new_cache
     '_q8_kernel': (((3, 1),),),          # cache -> new_cache
-    '_q8_fleet_kernel': (((3, 1),),),
     '_rows_kernel': ((),),               # rows paths scatter via ops.py
     '_q8_rows_kernel': ((),),
-    '_rows_fleet_kernel': ((),),
-    '_q8_rows_fleet_kernel': ((),),
     '_tier_rows_kernel': (((2, 2),),),   # value buffer updated in place
     '_q8_tier_rows_kernel': (((5, 2),),),
 }
@@ -95,33 +95,23 @@ def _kernel(cache_ref, trained_ref, global_ref, picked_ref, undrafted_ref,
         weights_ref[...])               # [m, 1] float32
 
 
-def _fleet_kernel(cache_ref, trained_ref, global_ref, picked_ref,
-                  undrafted_ref, deprecated_ref, weights_ref, new_global_ref,
-                  new_cache_ref):
-    """Fleet-batched body: each grid point (s, i) sees fleet member s's
-    [1, m, T] tile; the leading fleet-block dim is squeezed so the math is
-    exactly the single-run kernel's."""
-    ng, nc = _agg_math(
-        cache_ref[...][0],              # [m, T]
-        trained_ref[...][0],
-        global_ref[...][0],             # [1, T]
-        picked_ref[...][0] != 0,        # [m, 1]
-        undrafted_ref[...][0] != 0,
-        deprecated_ref[...][0] != 0,
-        weights_ref[...][0])
-    new_global_ref[...] = ng[None]
-    new_cache_ref[...] = nc[None]
+def check_width(np_: int, tile: int):
+    if np_ % tile:
+        raise ValueError(
+            f'packed buffer width {np_} not a multiple of tile={tile}; '
+            f'pack with pad_to=tile')
 
 
 def _launch(cache, trained, global_row, picked, undrafted, deprecated,
             weights, *, tile: int, alias_cache: bool):
     """Single fused dispatch over padded [m, N] operands (N % tile == 0)."""
     m, np_ = cache.shape
-    grid = (np_ // tile,)
+    # cache, trained and new_cache blocks, plus the global rows
+    tile, params = fit_tile(tile, 3 * 4 * padded_rows(m, 4) + 2 * 4 * SUBLANES)
     col = lambda arr: arr.reshape(m, 1)
     return pl.pallas_call(
         _kernel,
-        grid=grid,
+        grid=(np_ // tile,),
         in_specs=[
             pl.BlockSpec((m, tile), lambda i: (0, i)),      # cache
             pl.BlockSpec((m, tile), lambda i: (0, i)),      # trained
@@ -141,7 +131,8 @@ def _launch(cache, trained, global_row, picked, undrafted, deprecated,
         ],
         # the cache buffer is dead after the call: write new_cache in place
         input_output_aliases={0: 1} if alias_cache else {},
-        interpret=INTERPRET,
+        compiler_params=params,
+        interpret=backend.interpret(),
     )(cache, trained, global_row, col(picked.astype(jnp.int32)),
       col(undrafted.astype(jnp.int32)), col(deprecated.astype(jnp.int32)),
       col(weights.astype(jnp.float32)))
@@ -172,68 +163,23 @@ def safa_aggregate_packed(cache, trained, global_prev, picked, undrafted,
     ``ops.pack_stacked``).  One kernel dispatch regardless of how many
     pytree leaves the model has; the cache input is aliased to the
     new-cache output.  Returns (new_global [N], new_cache [m, N])."""
-    if cache.shape[1] % tile:
-        raise ValueError(
-            f'packed buffer width {cache.shape[1]} not a multiple of '
-            f'tile={tile}; pack with pad_to=tile')
+    check_width(cache.shape[1], tile)
     new_global, new_cache = _launch(
         cache, trained, global_prev.reshape(1, -1), picked, undrafted,
         deprecated, weights, tile=tile, alias_cache=True)
     return new_global[0], new_cache
 
 
-@functools.partial(jax.jit, static_argnames=('tile',))
-def safa_aggregate_packed_fleet(cache, trained, global_prev, picked,
-                                undrafted, deprecated, weights, *,
-                                tile: int = DEFAULT_TILE):
-    """Fleet variant of ``safa_aggregate_packed``: the pack gains a leading
-    fleet axis and the grid gains a fleet dimension.
-
-    cache/trained: [S, m, N] pre-padded pack buffers (N % tile == 0);
-    global_prev: [S, N]; masks/weights: [S, m].  One kernel dispatch runs
-    Eq. 6-8 for all S independent servers over a (S, N // tile) grid, with
-    the [S, m, N] cache buffer aliased to the new-cache output.  Returns
-    (new_global [S, N], new_cache [S, m, N]).
-    """
-    s, m, np_ = cache.shape
-    if np_ % tile:
-        raise ValueError(
-            f'packed buffer width {np_} not a multiple of tile={tile}; '
-            f'pack with pad_to=tile')
-    grid = (s, np_ // tile)
-    col = lambda arr: arr.reshape(s, m, 1)
-    new_global, new_cache = pl.pallas_call(
-        _fleet_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, m, tile), lambda s, i: (s, 0, i)),  # cache
-            pl.BlockSpec((1, m, tile), lambda s, i: (s, 0, i)),  # trained
-            pl.BlockSpec((1, 1, tile), lambda s, i: (s, 0, i)),  # global
-            pl.BlockSpec((1, m, 1), lambda s, i: (s, 0, 0)),     # picked
-            pl.BlockSpec((1, m, 1), lambda s, i: (s, 0, 0)),     # undrafted
-            pl.BlockSpec((1, m, 1), lambda s, i: (s, 0, 0)),     # deprecated
-            pl.BlockSpec((1, m, 1), lambda s, i: (s, 0, 0)),     # weights
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, tile), lambda s, i: (s, 0, i)),
-            pl.BlockSpec((1, m, tile), lambda s, i: (s, 0, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((s, 1, np_), cache.dtype),
-            jax.ShapeDtypeStruct((s, m, np_), cache.dtype),
-        ],
-        input_output_aliases={0: 1},
-        interpret=INTERPRET,
-    )(cache, trained, global_prev.reshape(s, 1, np_),
-      col(picked.astype(jnp.int32)), col(undrafted.astype(jnp.int32)),
-      col(deprecated.astype(jnp.int32)),
-      col(weights.astype(jnp.float32)))
-    return new_global[:, 0], new_cache
-
-
 # ---------------------------------------------------------------------------
 # Compressed-wire fast path: fused int8 dequant -> Eq. 6-8
 # ---------------------------------------------------------------------------
+
+def _dequant(q, scales):
+    """int8 rows [r, T] times their per-QBLOCK scales [r, T/QBLOCK]."""
+    r, t = q.shape
+    return (q.astype(jnp.float32).reshape(r, t // QBLOCK, QBLOCK)
+            * scales[:, :, None]).reshape(r, t)
+
 
 def _q8_math(q, scales, base, cache, global_row, picked, undrafted,
              deprecated, completed, weights):
@@ -243,10 +189,7 @@ def _q8_math(q, scales, base, cache, global_row, picked, undrafted,
     new_local is the post-wire trained matrix (base where crashed) — the
     clients' own view of the round, emitted so the caller never needs a
     separate dequantise dispatch."""
-    m, t = q.shape
-    deq = (q.astype(jnp.float32).reshape(m, t // QBLOCK, QBLOCK)
-           * scales[:, :, None]).reshape(m, t)
-    trained = jnp.where(completed, deq, base)
+    trained = jnp.where(completed, _dequant(q, scales), base)
     ng, nc = _agg_math(cache, trained, global_row, picked, undrafted,
                        deprecated, weights)
     return ng, nc, trained
@@ -266,20 +209,6 @@ def _q8_kernel(q_ref, scale_ref, base_ref, cache_ref, global_ref, picked_ref,
         deprecated_ref[...] != 0,
         completed_ref[...] != 0,
         weights_ref[...])               # [m, 1] float32
-
-
-def _q8_fleet_kernel(q_ref, scale_ref, base_ref, cache_ref, global_ref,
-                     picked_ref, undrafted_ref, deprecated_ref, completed_ref,
-                     weights_ref, new_global_ref, new_cache_ref,
-                     new_local_ref):
-    ng, nc, nl = _q8_math(
-        q_ref[...][0], scale_ref[...][0], base_ref[...][0], cache_ref[...][0],
-        global_ref[...][0], picked_ref[...][0] != 0,
-        undrafted_ref[...][0] != 0, deprecated_ref[...][0] != 0,
-        completed_ref[...][0] != 0, weights_ref[...][0])
-    new_global_ref[...] = ng[None]
-    new_cache_ref[...] = nc[None]
-    new_local_ref[...] = nl[None]
 
 
 @functools.partial(jax.jit, static_argnames=('tile',))
@@ -303,18 +232,17 @@ def safa_aggregate_packed_q8(q, scales, base, cache, global_prev, picked,
     after the round.
     """
     m, np_ = cache.shape
-    if np_ % tile:
-        raise ValueError(
-            f'packed buffer width {np_} not a multiple of tile={tile}; '
-            f'pack with pad_to=tile')
-    grid = (np_ // tile,)
+    check_width(np_, tile)
+    # int8 q block; base, cache, new_cache and new_local f32 blocks
+    tile, params = fit_tile(tile, padded_rows(m, 1)
+                            + 4 * 4 * padded_rows(m, 4) + 2 * 4 * SUBLANES)
     col = lambda arr: arr.reshape(m, 1)
     new_global, new_cache, new_local = pl.pallas_call(
         _q8_kernel,
-        grid=grid,
+        grid=(np_ // tile,),
         in_specs=[
             pl.BlockSpec((m, tile), lambda i: (0, i)),              # q
-            pl.BlockSpec((m, tile // QBLOCK), lambda i: (0, i)),    # scales
+            scale_spec(m, tile, lambda i: (i, 0)),                  # scales
             pl.BlockSpec((m, tile), lambda i: (0, i)),              # base
             pl.BlockSpec((m, tile), lambda i: (0, i)),              # cache
             pl.BlockSpec((1, tile), lambda i: (0, i)),              # global
@@ -336,64 +264,14 @@ def safa_aggregate_packed_q8(q, scales, base, cache, global_prev, picked,
         ],
         # the cache buffer is dead after the call: write new_cache in place
         input_output_aliases={3: 1},
-        interpret=INTERPRET,
-    )(q, scales, base, cache, global_prev.reshape(1, -1),
+        compiler_params=params,
+        interpret=backend.interpret(),
+    )(q, scales_to_blocks(scales, tile), base, cache,
+      global_prev.reshape(1, -1),
       col(picked.astype(jnp.int32)), col(undrafted.astype(jnp.int32)),
       col(deprecated.astype(jnp.int32)), col(completed.astype(jnp.int32)),
       col(weights.astype(jnp.float32)))
     return new_global[0], new_cache, new_local
-
-
-@functools.partial(jax.jit, static_argnames=('tile',))
-def safa_aggregate_packed_q8_fleet(q, scales, base, cache, global_prev,
-                                   picked, undrafted, deprecated, completed,
-                                   weights, *, tile: int = DEFAULT_TILE):
-    """Fleet variant of ``safa_aggregate_packed_q8``: every operand gains a
-    leading fleet axis (q/scales/base/cache [S, m, ...], global_prev
-    [S, N], masks/weights [S, m]) and the grid a fleet dimension — S
-    compressed server aggregations in one dispatch, cache aliased.
-    Returns (new_global [S, N], new_cache [S, m, N], new_local [S, m, N]).
-    """
-    s, m, np_ = cache.shape
-    if np_ % tile:
-        raise ValueError(
-            f'packed buffer width {np_} not a multiple of tile={tile}; '
-            f'pack with pad_to=tile')
-    grid = (s, np_ // tile)
-    col = lambda arr: arr.reshape(s, m, 1)
-    new_global, new_cache, new_local = pl.pallas_call(
-        _q8_fleet_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, m, tile), lambda s, i: (s, 0, i)),     # q
-            pl.BlockSpec((1, m, tile // QBLOCK),
-                         lambda s, i: (s, 0, i)),                   # scales
-            pl.BlockSpec((1, m, tile), lambda s, i: (s, 0, i)),     # base
-            pl.BlockSpec((1, m, tile), lambda s, i: (s, 0, i)),     # cache
-            pl.BlockSpec((1, 1, tile), lambda s, i: (s, 0, i)),     # global
-            pl.BlockSpec((1, m, 1), lambda s, i: (s, 0, 0)),        # picked
-            pl.BlockSpec((1, m, 1), lambda s, i: (s, 0, 0)),        # undrafted
-            pl.BlockSpec((1, m, 1), lambda s, i: (s, 0, 0)),        # deprecated
-            pl.BlockSpec((1, m, 1), lambda s, i: (s, 0, 0)),        # completed
-            pl.BlockSpec((1, m, 1), lambda s, i: (s, 0, 0)),        # weights
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, tile), lambda s, i: (s, 0, i)),
-            pl.BlockSpec((1, m, tile), lambda s, i: (s, 0, i)),
-            pl.BlockSpec((1, m, tile), lambda s, i: (s, 0, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((s, 1, np_), cache.dtype),
-            jax.ShapeDtypeStruct((s, m, np_), cache.dtype),
-            jax.ShapeDtypeStruct((s, m, np_), cache.dtype),
-        ],
-        input_output_aliases={3: 1},
-        interpret=INTERPRET,
-    )(q, scales, base, cache, global_prev.reshape(s, 1, np_),
-      col(picked.astype(jnp.int32)), col(undrafted.astype(jnp.int32)),
-      col(deprecated.astype(jnp.int32)), col(completed.astype(jnp.int32)),
-      col(weights.astype(jnp.float32)))
-    return new_global[:, 0], new_cache, new_local
 
 
 # ---------------------------------------------------------------------------
@@ -417,32 +295,92 @@ def safa_aggregate_packed_q8_fleet(q, scales, base, cache, global_prev,
 # the TPU-friendly consecutive-revisit pattern.  Sentinel slots point at
 # the scratch row of an [m+1, N] buffer (see ``ops.gather_rows``) and
 # carry zero weight, so padding is numerically inert.
+#
+# A TPU block spans whole 8-row tiles, so a row is moved as the 8-row
+# group that holds it (``group_spec``) and picked out of the block by a
+# dynamic sublane index (``row_of``); consecutive slots share their group
+# blocks, which stay resident across those steps.
 
 
-def _rows_kernel(rows_ref, cache_ref, trained_ref, global_ref, agg_ref,
-                 picked_ref, undrafted_ref, deprecated_ref, weights_ref,
-                 new_global_ref, new_agg_ref, c2_ref):
-    del rows_ref  # consumed by the index maps
-    k = pl.program_id(1)
-    c0 = cache_ref[...].astype(jnp.float32)     # [1, T] — gathered row
-    tr = trained_ref[...].astype(jnp.float32)
-    g = global_ref[...].astype(jnp.float32)
-    p = picked_ref[...] != 0                    # [1, 1]
-    u = undrafted_ref[...] != 0
-    d = deprecated_ref[...] != 0
-    w = weights_ref[...].astype(jnp.float32)
+def group_spec(tile: int, row):
+    """BlockSpec of the 8-row group holding row ``row(j, *prefetch)`` of
+    an [R, N] operand, at column tile i of a (N // tile, K) grid."""
+    return pl.BlockSpec((SUBLANES, tile),
+                        lambda i, j, *pf: (row(j, *pf) // SUBLANES, i))
+
+
+def slot_spec(tile: int):
+    """``group_spec`` of slot j's own row of a [K, N] operand."""
+    return group_spec(tile, lambda j, *pf: j)
+
+
+def row_of(ref, r):
+    """Row ``r`` of the buffer, read from its 8-row group block."""
+    return ref[pl.ds(r % SUBLANES, 1), :]
+
+
+def set_row(ref, r, value):
+    """Write row ``r`` into its 8-row group block."""
+    ref[pl.ds(r % SUBLANES, 1), :] = value
+
+
+_ROLE_SPEC = pl.BlockSpec((SUBLANES, 1),
+                          lambda i, j, *pf: (j // SUBLANES, 0))
+
+
+def _roles(j, picked_ref, undrafted_ref, deprecated_ref, weights_ref):
+    """Slot j's (picked, undrafted, deprecated, weight), each [1, 1]."""
+    return (row_of(picked_ref, j) != 0, row_of(undrafted_ref, j) != 0,
+            row_of(deprecated_ref, j) != 0,
+            row_of(weights_ref, j).astype(jnp.float32))
+
+
+def _row_math(c0, tr, g, p, u, d):
+    """Eq. 6 then Eq. 8 on one row; returns (c1, c2)."""
     c1 = jnp.where(d & ~p, g, c0)               # Eq. 6
     c1 = jnp.where(p, tr, c1)
-    c2 = jnp.where(u, tr, c1)                   # Eq. 8
-    c2_ref[...] = c2.astype(c2_ref.dtype)
+    return c1, jnp.where(u, tr, c1)             # Eq. 8
 
+
+def _accumulate(k, agg_ref, new_global_ref, new_agg_ref, w, c0, c1, c2):
+    """Eq. 7 as a delta on the running sum, over the inner slot steps."""
     @pl.when(k == 0)
     def _():
         new_global_ref[...] = agg_ref[...]
         new_agg_ref[...] = agg_ref[...]
 
-    new_global_ref[...] += w * (c1 - c0)        # Eq. 7 as a delta
+    new_global_ref[...] += w * (c1 - c0)
     new_agg_ref[...] += w * (c2 - c0)
+
+
+def _rows_kernel(rows_ref, cache_ref, trained_ref, global_ref, agg_ref,
+                 picked_ref, undrafted_ref, deprecated_ref, weights_ref,
+                 new_global_ref, new_agg_ref, c2_ref):
+    k = pl.program_id(1)
+    p, u, d, w = _roles(k, picked_ref, undrafted_ref, deprecated_ref,
+                        weights_ref)
+    c0 = row_of(cache_ref, rows_ref[k]).astype(jnp.float32)   # [1, T]
+    tr = row_of(trained_ref, k).astype(jnp.float32)
+    c1, c2 = _row_math(c0, tr, global_ref[...].astype(jnp.float32), p, u, d)
+    set_row(c2_ref, k, c2.astype(c2_ref.dtype))
+    _accumulate(k, agg_ref, new_global_ref, new_agg_ref, w, c0, c1, c2)
+
+
+def _row_args(agg, global_prev, masks, w_rows):
+    """The per-round operands of a rows call after its row operands: the
+    [1, N] global and agg rows, then one [K, 1] column per role mask and
+    the weights column."""
+    col = lambda arr, dt: arr.astype(dt).reshape(-1, 1)
+    return (global_prev.reshape(1, -1).astype(jnp.float32),
+            agg.reshape(1, -1).astype(jnp.float32),
+            *(col(mask, jnp.int32) for mask in masks),
+            col(w_rows, jnp.float32))
+
+
+def _row_specs(tile: int, n_masks: int):
+    """Specs of ``_row_args``."""
+    full = pl.BlockSpec((1, tile), lambda i, j, *pf: (0, i))
+    return [full, full] + [_ROLE_SPEC] * (n_masks + 1)
 
 
 @functools.partial(jax.jit, static_argnames=('tile',))
@@ -462,30 +400,21 @@ def safa_aggregate_packed_rows(cache, trained_rows, global_prev, agg, rows,
     caller scatters ``c2_rows`` back with ``ops.scatter_rows`` (the
     untouched cache rows are untouched by construction).
     """
-    r, np_ = cache.shape
+    _, np_ = cache.shape
     k, _ = trained_rows.shape
-    if np_ % tile:
-        raise ValueError(
-            f'packed buffer width {np_} not a multiple of tile={tile}; '
-            f'pack with pad_to=tile')
-    col = lambda arr: arr.reshape(k, 1)
+    check_width(np_, tile)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(np_ // tile, k),      # k innermost: agg blocks revisit
         in_specs=[
-            pl.BlockSpec((1, tile), lambda i, j, rows: (rows[j], i)),  # cache
-            pl.BlockSpec((1, tile), lambda i, j, rows: (j, i)),    # trained
-            pl.BlockSpec((1, tile), lambda i, j, rows: (0, i)),    # global
-            pl.BlockSpec((1, tile), lambda i, j, rows: (0, i)),    # agg
-            pl.BlockSpec((1, 1), lambda i, j, rows: (j, 0)),       # picked
-            pl.BlockSpec((1, 1), lambda i, j, rows: (j, 0)),       # undrafted
-            pl.BlockSpec((1, 1), lambda i, j, rows: (j, 0)),       # deprecated
-            pl.BlockSpec((1, 1), lambda i, j, rows: (j, 0)),       # weights
+            group_spec(tile, lambda j, rows: rows[j]),      # cache
+            slot_spec(tile),                                # trained
+            *_row_specs(tile, 3),
         ],
         out_specs=[
             pl.BlockSpec((1, tile), lambda i, j, rows: (0, i)),
             pl.BlockSpec((1, tile), lambda i, j, rows: (0, i)),
-            pl.BlockSpec((1, tile), lambda i, j, rows: (j, i)),
+            slot_spec(tile),
         ])
     new_global, new_agg, c2 = pl.pallas_call(
         _rows_kernel,
@@ -495,45 +424,52 @@ def safa_aggregate_packed_rows(cache, trained_rows, global_prev, agg, rows,
             jax.ShapeDtypeStruct((1, np_), jnp.float32),
             jax.ShapeDtypeStruct((k, np_), cache.dtype),
         ],
-        interpret=INTERPRET,
+        interpret=backend.interpret(),
     )(rows.astype(jnp.int32), cache, trained_rows,
-      global_prev.reshape(1, -1).astype(jnp.float32),
-      agg.reshape(1, -1).astype(jnp.float32),
-      col(picked_r.astype(jnp.int32)), col(undrafted_r.astype(jnp.int32)),
-      col(deprecated_r.astype(jnp.int32)), col(w_rows.astype(jnp.float32)))
+      *_row_args(agg, global_prev, (picked_r, undrafted_r, deprecated_r),
+                 w_rows))
     return new_global[0], new_agg[0], c2
+
+
+def _int8_row(ref, r):
+    """Row r of an int8 group block, as f32.  int8 rows pack four to a
+    sublane, so the row is selected arithmetically (exact: the values
+    are small integers) rather than by a sublane index."""
+    x = ref[...].astype(jnp.float32)
+    sub = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    return jnp.sum(jnp.where(sub == r % SUBLANES, x, 0.0), axis=0,
+                   keepdims=True)
+
+
+def _q8_row(k, q_ref, scale_ref, base_ref, completed_ref):
+    """Slot k's post-wire upload: its dequantised int8 row, or its base
+    row when it crashed."""
+    deq = _dequant(_int8_row(q_ref, k), row_of(scale_ref, k))
+    return jnp.where(row_of(completed_ref, k) != 0, deq,
+                     row_of(base_ref, k).astype(jnp.float32))
 
 
 def _q8_rows_kernel(rows_ref, q_ref, scale_ref, base_ref, cache_ref,
                     global_ref, agg_ref, picked_ref, undrafted_ref,
                     deprecated_ref, completed_ref, weights_ref,
                     new_global_ref, new_agg_ref, c2_ref, local_ref):
-    del rows_ref
     k = pl.program_id(1)
-    _, t = q_ref.shape
-    deq = (q_ref[...].astype(jnp.float32).reshape(1, t // QBLOCK, QBLOCK)
-           * scale_ref[...][:, :, None]).reshape(1, t)
-    tr = jnp.where(completed_ref[...] != 0, deq,
-                   base_ref[...].astype(jnp.float32))
-    local_ref[...] = tr.astype(local_ref.dtype)
-    c0 = cache_ref[...].astype(jnp.float32)
-    g = global_ref[...].astype(jnp.float32)
-    p = picked_ref[...] != 0
-    u = undrafted_ref[...] != 0
-    d = deprecated_ref[...] != 0
-    w = weights_ref[...].astype(jnp.float32)
-    c1 = jnp.where(d & ~p, g, c0)
-    c1 = jnp.where(p, tr, c1)
-    c2 = jnp.where(u, tr, c1)
-    c2_ref[...] = c2.astype(c2_ref.dtype)
+    tr = _q8_row(k, q_ref, scale_ref, base_ref, completed_ref)
+    set_row(local_ref, k, tr.astype(local_ref.dtype))
+    p, u, d, w = _roles(k, picked_ref, undrafted_ref, deprecated_ref,
+                        weights_ref)
+    c0 = row_of(cache_ref, rows_ref[k]).astype(jnp.float32)
+    c1, c2 = _row_math(c0, tr, global_ref[...].astype(jnp.float32), p, u, d)
+    set_row(c2_ref, k, c2.astype(c2_ref.dtype))
+    _accumulate(k, agg_ref, new_global_ref, new_agg_ref, w, c0, c1, c2)
 
-    @pl.when(k == 0)
-    def _():
-        new_global_ref[...] = agg_ref[...]
-        new_agg_ref[...] = agg_ref[...]
 
-    new_global_ref[...] += w * (c1 - c0)
-    new_agg_ref[...] += w * (c2 - c0)
+def _q8_slot_specs(tile: int):
+    """Specs of a rows call's wire operands: q, scales, base rows."""
+    return [slot_spec(tile),
+            scale_spec(SUBLANES, tile,
+                       lambda i, j, *pf: (i, j // SUBLANES)),
+            slot_spec(tile)]
 
 
 @functools.partial(jax.jit, static_argnames=('tile',))
@@ -548,35 +484,22 @@ def safa_aggregate_packed_q8_rows(q_rows, scales_rows, base_rows, cache,
     (new_global [N] f32, new_agg [N] f32, c2_rows [K, N], local_rows
     [K, N]) — local_rows is each active client's post-round local model,
     for the caller to scatter into the local stack."""
-    r, np_ = cache.shape
+    _, np_ = cache.shape
     k, _ = q_rows.shape
-    if np_ % tile:
-        raise ValueError(
-            f'packed buffer width {np_} not a multiple of tile={tile}; '
-            f'pack with pad_to=tile')
-    col = lambda arr: arr.reshape(k, 1)
+    check_width(np_, tile)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(np_ // tile, k),
         in_specs=[
-            pl.BlockSpec((1, tile), lambda i, j, rows: (j, i)),    # q
-            pl.BlockSpec((1, tile // QBLOCK),
-                         lambda i, j, rows: (j, i)),               # scales
-            pl.BlockSpec((1, tile), lambda i, j, rows: (j, i)),    # base
-            pl.BlockSpec((1, tile), lambda i, j, rows: (rows[j], i)),  # cache
-            pl.BlockSpec((1, tile), lambda i, j, rows: (0, i)),    # global
-            pl.BlockSpec((1, tile), lambda i, j, rows: (0, i)),    # agg
-            pl.BlockSpec((1, 1), lambda i, j, rows: (j, 0)),       # picked
-            pl.BlockSpec((1, 1), lambda i, j, rows: (j, 0)),       # undrafted
-            pl.BlockSpec((1, 1), lambda i, j, rows: (j, 0)),       # deprecated
-            pl.BlockSpec((1, 1), lambda i, j, rows: (j, 0)),       # completed
-            pl.BlockSpec((1, 1), lambda i, j, rows: (j, 0)),       # weights
+            *_q8_slot_specs(tile),
+            group_spec(tile, lambda j, rows: rows[j]),      # cache
+            *_row_specs(tile, 4),
         ],
         out_specs=[
             pl.BlockSpec((1, tile), lambda i, j, rows: (0, i)),
             pl.BlockSpec((1, tile), lambda i, j, rows: (0, i)),
-            pl.BlockSpec((1, tile), lambda i, j, rows: (j, i)),
-            pl.BlockSpec((1, tile), lambda i, j, rows: (j, i)),
+            slot_spec(tile),
+            slot_spec(tile),
         ])
     new_global, new_agg, c2, local = pl.pallas_call(
         _q8_rows_kernel,
@@ -587,184 +510,12 @@ def safa_aggregate_packed_q8_rows(q_rows, scales_rows, base_rows, cache,
             jax.ShapeDtypeStruct((k, np_), cache.dtype),
             jax.ShapeDtypeStruct((k, np_), cache.dtype),
         ],
-        interpret=INTERPRET,
-    )(rows.astype(jnp.int32), q_rows, scales_rows, base_rows, cache,
-      global_prev.reshape(1, -1).astype(jnp.float32),
-      agg.reshape(1, -1).astype(jnp.float32),
-      col(picked_r.astype(jnp.int32)), col(undrafted_r.astype(jnp.int32)),
-      col(deprecated_r.astype(jnp.int32)), col(completed_r.astype(jnp.int32)),
-      col(w_rows.astype(jnp.float32)))
+        interpret=backend.interpret(),
+    )(rows.astype(jnp.int32), q_rows, scales_to_blocks(scales_rows, tile),
+      base_rows, cache,
+      *_row_args(agg, global_prev,
+                 (picked_r, undrafted_r, deprecated_r, completed_r), w_rows))
     return new_global[0], new_agg[0], c2, local
-
-
-def _rows_fleet_kernel(rows_ref, cache_ref, trained_ref, global_ref, agg_ref,
-                       picked_ref, undrafted_ref, deprecated_ref, weights_ref,
-                       new_global_ref, new_agg_ref, c2_ref):
-    del rows_ref
-    k = pl.program_id(2)
-    c0 = cache_ref[...][0].astype(jnp.float32)
-    tr = trained_ref[...][0].astype(jnp.float32)
-    g = global_ref[...][0].astype(jnp.float32)
-    p = picked_ref[...][0] != 0
-    u = undrafted_ref[...][0] != 0
-    d = deprecated_ref[...][0] != 0
-    w = weights_ref[...][0].astype(jnp.float32)
-    c1 = jnp.where(d & ~p, g, c0)
-    c1 = jnp.where(p, tr, c1)
-    c2 = jnp.where(u, tr, c1)
-    c2_ref[...] = c2[None].astype(c2_ref.dtype)
-
-    @pl.when(k == 0)
-    def _():
-        new_global_ref[...] = agg_ref[...]
-        new_agg_ref[...] = agg_ref[...]
-
-    new_global_ref[...] += (w * (c1 - c0))[None]
-    new_agg_ref[...] += (w * (c2 - c0))[None]
-
-
-@functools.partial(jax.jit, static_argnames=('tile',))
-def safa_aggregate_packed_rows_fleet(cache, trained_rows, global_prev, agg,
-                                     rows, picked_r, undrafted_r,
-                                     deprecated_r, w_rows, *,
-                                     tile: int = DEFAULT_TILE):
-    """Fleet variant of ``safa_aggregate_packed_rows``: cache [S, R, N],
-    trained_rows [S, K, N], global_prev/agg [S, N], rows [S, K], roles/
-    weights [S, K]; grid (S, N // tile, K).  Returns (new_global [S, N]
-    f32, new_agg [S, N] f32, c2_rows [S, K, N])."""
-    s, r, np_ = cache.shape
-    _, k, _ = trained_rows.shape
-    if np_ % tile:
-        raise ValueError(
-            f'packed buffer width {np_} not a multiple of tile={tile}; '
-            f'pack with pad_to=tile')
-    col = lambda arr: arr.reshape(s, k, 1)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(s, np_ // tile, k),
-        in_specs=[
-            pl.BlockSpec((1, 1, tile),
-                         lambda b, i, j, rows: (b, rows[b, j], i)),  # cache
-            pl.BlockSpec((1, 1, tile), lambda b, i, j, rows: (b, j, i)),
-            pl.BlockSpec((1, 1, tile), lambda b, i, j, rows: (b, 0, i)),
-            pl.BlockSpec((1, 1, tile), lambda b, i, j, rows: (b, 0, i)),
-            pl.BlockSpec((1, 1, 1), lambda b, i, j, rows: (b, j, 0)),
-            pl.BlockSpec((1, 1, 1), lambda b, i, j, rows: (b, j, 0)),
-            pl.BlockSpec((1, 1, 1), lambda b, i, j, rows: (b, j, 0)),
-            pl.BlockSpec((1, 1, 1), lambda b, i, j, rows: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, tile), lambda b, i, j, rows: (b, 0, i)),
-            pl.BlockSpec((1, 1, tile), lambda b, i, j, rows: (b, 0, i)),
-            pl.BlockSpec((1, 1, tile), lambda b, i, j, rows: (b, j, i)),
-        ])
-    new_global, new_agg, c2 = pl.pallas_call(
-        _rows_fleet_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((s, 1, np_), jnp.float32),
-            jax.ShapeDtypeStruct((s, 1, np_), jnp.float32),
-            jax.ShapeDtypeStruct((s, k, np_), cache.dtype),
-        ],
-        interpret=INTERPRET,
-    )(rows.astype(jnp.int32), cache, trained_rows,
-      global_prev.reshape(s, 1, np_).astype(jnp.float32),
-      agg.reshape(s, 1, np_).astype(jnp.float32),
-      col(picked_r.astype(jnp.int32)), col(undrafted_r.astype(jnp.int32)),
-      col(deprecated_r.astype(jnp.int32)), col(w_rows.astype(jnp.float32)))
-    return new_global[:, 0], new_agg[:, 0], c2
-
-
-def _q8_rows_fleet_kernel(rows_ref, q_ref, scale_ref, base_ref, cache_ref,
-                          global_ref, agg_ref, picked_ref, undrafted_ref,
-                          deprecated_ref, completed_ref, weights_ref,
-                          new_global_ref, new_agg_ref, c2_ref, local_ref):
-    del rows_ref
-    k = pl.program_id(2)
-    _, _, t = q_ref.shape
-    deq = (q_ref[...][0].astype(jnp.float32).reshape(1, t // QBLOCK, QBLOCK)
-           * scale_ref[...][0][:, :, None]).reshape(1, t)
-    tr = jnp.where(completed_ref[...][0] != 0, deq,
-                   base_ref[...][0].astype(jnp.float32))
-    local_ref[...] = tr[None].astype(local_ref.dtype)
-    c0 = cache_ref[...][0].astype(jnp.float32)
-    g = global_ref[...][0].astype(jnp.float32)
-    p = picked_ref[...][0] != 0
-    u = undrafted_ref[...][0] != 0
-    d = deprecated_ref[...][0] != 0
-    w = weights_ref[...][0].astype(jnp.float32)
-    c1 = jnp.where(d & ~p, g, c0)
-    c1 = jnp.where(p, tr, c1)
-    c2 = jnp.where(u, tr, c1)
-    c2_ref[...] = c2[None].astype(c2_ref.dtype)
-
-    @pl.when(k == 0)
-    def _():
-        new_global_ref[...] = agg_ref[...]
-        new_agg_ref[...] = agg_ref[...]
-
-    new_global_ref[...] += (w * (c1 - c0))[None]
-    new_agg_ref[...] += (w * (c2 - c0))[None]
-
-
-@functools.partial(jax.jit, static_argnames=('tile',))
-def safa_aggregate_packed_q8_rows_fleet(q_rows, scales_rows, base_rows, cache,
-                                        global_prev, agg, rows, picked_r,
-                                        undrafted_r, deprecated_r,
-                                        completed_r, w_rows, *,
-                                        tile: int = DEFAULT_TILE):
-    """Fleet variant of ``safa_aggregate_packed_q8_rows`` (operands gain a
-    leading fleet axis, grid (S, N // tile, K)).  Returns (new_global
-    [S, N] f32, new_agg [S, N] f32, c2_rows [S, K, N], local_rows
-    [S, K, N])."""
-    s, r, np_ = cache.shape
-    _, k, _ = q_rows.shape
-    if np_ % tile:
-        raise ValueError(
-            f'packed buffer width {np_} not a multiple of tile={tile}; '
-            f'pack with pad_to=tile')
-    col = lambda arr: arr.reshape(s, k, 1)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(s, np_ // tile, k),
-        in_specs=[
-            pl.BlockSpec((1, 1, tile), lambda b, i, j, rows: (b, j, i)),
-            pl.BlockSpec((1, 1, tile // QBLOCK),
-                         lambda b, i, j, rows: (b, j, i)),
-            pl.BlockSpec((1, 1, tile), lambda b, i, j, rows: (b, j, i)),
-            pl.BlockSpec((1, 1, tile),
-                         lambda b, i, j, rows: (b, rows[b, j], i)),  # cache
-            pl.BlockSpec((1, 1, tile), lambda b, i, j, rows: (b, 0, i)),
-            pl.BlockSpec((1, 1, tile), lambda b, i, j, rows: (b, 0, i)),
-            pl.BlockSpec((1, 1, 1), lambda b, i, j, rows: (b, j, 0)),
-            pl.BlockSpec((1, 1, 1), lambda b, i, j, rows: (b, j, 0)),
-            pl.BlockSpec((1, 1, 1), lambda b, i, j, rows: (b, j, 0)),
-            pl.BlockSpec((1, 1, 1), lambda b, i, j, rows: (b, j, 0)),
-            pl.BlockSpec((1, 1, 1), lambda b, i, j, rows: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, tile), lambda b, i, j, rows: (b, 0, i)),
-            pl.BlockSpec((1, 1, tile), lambda b, i, j, rows: (b, 0, i)),
-            pl.BlockSpec((1, 1, tile), lambda b, i, j, rows: (b, j, i)),
-            pl.BlockSpec((1, 1, tile), lambda b, i, j, rows: (b, j, i)),
-        ])
-    new_global, new_agg, c2, local = pl.pallas_call(
-        _q8_rows_fleet_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((s, 1, np_), jnp.float32),
-            jax.ShapeDtypeStruct((s, 1, np_), jnp.float32),
-            jax.ShapeDtypeStruct((s, k, np_), cache.dtype),
-            jax.ShapeDtypeStruct((s, k, np_), cache.dtype),
-        ],
-        interpret=INTERPRET,
-    )(rows.astype(jnp.int32), q_rows, scales_rows, base_rows, cache,
-      global_prev.reshape(s, 1, np_).astype(jnp.float32),
-      agg.reshape(s, 1, np_).astype(jnp.float32),
-      col(picked_r.astype(jnp.int32)), col(undrafted_r.astype(jnp.int32)),
-      col(deprecated_r.astype(jnp.int32)), col(completed_r.astype(jnp.int32)),
-      col(w_rows.astype(jnp.float32)))
-    return new_global[:, 0], new_agg[:, 0], c2, local
 
 
 # ---------------------------------------------------------------------------
@@ -775,41 +526,104 @@ def safa_aggregate_packed_q8_rows_fleet(q_rows, scales_rows, base_rows, cache,
 # [capacity+1, N] value buffer instead of [m, N] local/cache stacks; the
 # host schedule names each slot's cache-read slot (``srcs``) and cache-
 # write slot (``dsts``), both scalar-prefetched.  The kernels below are the
-# rows kernels with TWO prefetch operands and the c2 scatter folded in: the
-# c2 output block lands directly at (dsts[j], i) and the buffer input is
-# aliased to it, so one dispatch does Eq. 6-8, both delta sums, AND the
-# cache write-back in place.  Sound because the host allocator guarantees
-# per-round src/dst slot disjointness (a value written in round t is first
-# read strictly later); the shared scratch slot (read AND written by inert
-# slots) carries only zero-weight contributions, so its value never
-# matters.  Dst-duplicate scratch writes resolve last-wins over the
-# innermost grid dim, exactly like ``ops.scatter_rows``.
+# rows kernels with TWO prefetch operands and the c2 scatter folded in:
+# each step writes its c2 row straight into the buffer at row dsts[j],
+# and the buffer input is aliased to the output, so one dispatch does
+# Eq. 6-8, both delta sums, AND the cache write-back in place.  Sound
+# because the host allocator guarantees per-round src/dst slot
+# disjointness (a value written in round t is first read strictly later);
+# the shared scratch slot (read AND written by inert slots) carries only
+# zero-weight contributions, so its value never matters.  Dst-duplicate
+# scratch writes resolve last-wins over the innermost grid dim, exactly
+# like ``ops.scatter_rows``.
+#
+# The buffer stays in HBM: each step copies the 8-row group of its source
+# row into VMEM, and writes its c2 row by a read-modify-write of the
+# destination row's group, both by explicit DMA (a TPU DMA moves whole
+# 8-row tiles).  The buffer's row count must therefore be a multiple of 8
+# (``backend.row_pad``); the engines allocate it so.
+
+
+def _group_window(hbm, r, i, tile: int):
+    """HBM window of the 8-row group holding row r, column tile i."""
+    g = pl.multiple_of(r // SUBLANES * SUBLANES, SUBLANES)
+    return hbm.at[pl.ds(g, SUBLANES), pl.ds(pl.multiple_of(i * tile, tile),
+                                            tile)]
+
+
+def _copy(src, dst, sem):
+    cp = pltpu.make_async_copy(src, dst, sem)
+    cp.start()
+    cp.wait()
+
+
+def read_row(hbm, grp, sem, r, i, tile: int):
+    """Row r, column tile i of an HBM buffer, through VMEM scratch grp."""
+    _copy(_group_window(hbm, r, i, tile), grp, sem)
+    return row_of(grp, r)
+
+
+def write_row(hbm, grp, sem, r, i, tile: int, value):
+    """Write ``value`` to row r, column tile i of an HBM buffer by a
+    read-modify-write of its 8-row group through VMEM scratch grp."""
+    window = _group_window(hbm, r, i, tile)
+    _copy(window, grp, sem)
+    set_row(grp, r, value)
+    _copy(grp, window, sem)
+
+
+def check_rows(r: int):
+    """Buffers written in place by row hold whole 8-row groups."""
+    if r % SUBLANES:
+        raise ValueError(
+            f'a buffer written in place by row needs a multiple of '
+            f'{SUBLANES} rows, got {r}; allocate backend.row_pad(rows)')
 
 
 def _tier_rows_kernel(srcs_ref, dsts_ref, buf_ref, trained_ref, global_ref,
                       agg_ref, picked_ref, undrafted_ref, deprecated_ref,
-                      weights_ref, new_global_ref, new_agg_ref, newbuf_ref):
-    del srcs_ref, dsts_ref  # consumed by the index maps
-    k = pl.program_id(1)
-    c0 = buf_ref[...].astype(jnp.float32)       # [1, T] — slot-gathered row
-    tr = trained_ref[...].astype(jnp.float32)
-    g = global_ref[...].astype(jnp.float32)
-    p = picked_ref[...] != 0                    # [1, 1]
-    u = undrafted_ref[...] != 0
-    d = deprecated_ref[...] != 0
-    w = weights_ref[...].astype(jnp.float32)
-    c1 = jnp.where(d & ~p, g, c0)               # Eq. 6
-    c1 = jnp.where(p, tr, c1)
-    c2 = jnp.where(u, tr, c1)                   # Eq. 8
-    newbuf_ref[...] = c2.astype(newbuf_ref.dtype)
+                      weights_ref, new_global_ref, new_agg_ref, newbuf_ref,
+                      grp, sem):
+    # buf_ref aliases newbuf_ref: every read and write goes through the
+    # output, which holds the buffer's current contents
+    del buf_ref
+    i, k = pl.program_id(0), pl.program_id(1)
+    tile = trained_ref.shape[1]
+    p, u, d, w = _roles(k, picked_ref, undrafted_ref, deprecated_ref,
+                        weights_ref)
+    c0 = read_row(newbuf_ref, grp, sem, srcs_ref[k], i,
+                  tile).astype(jnp.float32)
+    tr = row_of(trained_ref, k).astype(jnp.float32)
+    c1, c2 = _row_math(c0, tr, global_ref[...].astype(jnp.float32), p, u, d)
+    write_row(newbuf_ref, grp, sem, dsts_ref[k], i, tile,
+              c2.astype(newbuf_ref.dtype))
+    _accumulate(k, agg_ref, new_global_ref, new_agg_ref, w, c0, c1, c2)
 
-    @pl.when(k == 0)
-    def _():
-        new_global_ref[...] = agg_ref[...]
-        new_agg_ref[...] = agg_ref[...]
 
-    new_global_ref[...] += w * (c1 - c0)        # Eq. 7 as a delta
-    new_agg_ref[...] += w * (c2 - c0)
+def _tier_grid(in_specs, buf, *, k: int, tile: int):
+    """Grid spec and output shapes of a tier-rows dispatch over buf
+    [R, N]: new_global and new_agg rows, then the buffer itself, which
+    stays in HBM and is written in place."""
+    r, np_ = buf.shape
+    check_width(np_, tile)
+    check_rows(r)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(np_ // tile, k),      # k innermost: agg blocks revisit
+        in_specs=in_specs,
+        out_specs=[
+            pl.BlockSpec((1, tile), lambda i, j, *pf: (0, i)),
+            pl.BlockSpec((1, tile), lambda i, j, *pf: (0, i)),
+            pl.BlockSpec(memory_space=pl.ANY),                  # new buf
+        ],
+        scratch_shapes=[pltpu.VMEM((SUBLANES, tile), buf.dtype),
+                        pltpu.SemaphoreType.DMA])
+    out_shape = [
+        jax.ShapeDtypeStruct((1, np_), jnp.float32),
+        jax.ShapeDtypeStruct((1, np_), jnp.float32),
+        jax.ShapeDtypeStruct((r, np_), buf.dtype),
+    ]
+    return grid_spec, out_shape
 
 
 @functools.partial(jax.jit, static_argnames=('tile',))
@@ -819,57 +633,32 @@ def safa_aggregate_packed_tier_rows(buf, trained_rows, global_prev, agg,
                                     tile: int = DEFAULT_TILE):
     """Slot-indirected Eq. 6-8 with the cache write-back fused in place.
 
-    buf: [capacity+1, N] tier value buffer (trailing scratch row);
-    trained_rows: [K, N] post-wire uploads (base rows where not
-    committed); global_prev, agg: [N]; srcs/dsts: [K] int32 slot ids
-    (cache-read / cache-write, scratch == discard); roles/weights as in
+    buf: [capacity+1, N] tier value buffer (trailing scratch row; rows
+    padded to a multiple of 8, see ``backend.row_pad``); trained_rows:
+    [K, N] post-wire uploads (base rows where not committed); global_prev,
+    agg: [N]; srcs/dsts: [K] int32 slot ids (cache-read / cache-write,
+    scratch == discard); roles/weights as in
     ``safa_aggregate_packed_rows``.  The buffer input aliases the new-
     buffer output, so untouched slots persist with zero traffic.  Returns
     (new_global [N] f32, new_agg [N] f32, new_buf [capacity+1, N])."""
-    r, np_ = buf.shape
     k, _ = trained_rows.shape
-    if np_ % tile:
-        raise ValueError(
-            f'packed buffer width {np_} not a multiple of tile={tile}; '
-            f'pack with pad_to=tile')
-    col = lambda arr: arr.reshape(k, 1)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(np_ // tile, k),      # k innermost: agg blocks revisit
-        in_specs=[
-            pl.BlockSpec((1, tile),
-                         lambda i, j, srcs, dsts: (srcs[j], i)),   # buf
-            pl.BlockSpec((1, tile), lambda i, j, srcs, dsts: (j, i)),
-            pl.BlockSpec((1, tile), lambda i, j, srcs, dsts: (0, i)),
-            pl.BlockSpec((1, tile), lambda i, j, srcs, dsts: (0, i)),
-            pl.BlockSpec((1, 1), lambda i, j, srcs, dsts: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, srcs, dsts: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, srcs, dsts: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, srcs, dsts: (j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tile), lambda i, j, srcs, dsts: (0, i)),
-            pl.BlockSpec((1, tile), lambda i, j, srcs, dsts: (0, i)),
-            pl.BlockSpec((1, tile),
-                         lambda i, j, srcs, dsts: (dsts[j], i)),   # new buf
-        ])
+    in_specs = [
+        pl.BlockSpec(memory_space=pl.ANY),                      # buf
+        slot_spec(tile),                                        # trained
+        *_row_specs(tile, 3),
+    ]
+    grid_spec, out_shape = _tier_grid(in_specs, buf, k=k, tile=tile)
     new_global, new_agg, new_buf = pl.pallas_call(
         _tier_rows_kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((1, np_), jnp.float32),
-            jax.ShapeDtypeStruct((1, np_), jnp.float32),
-            jax.ShapeDtypeStruct((r, np_), buf.dtype),
-        ],
-        # operands 0/1 are the prefetched slot ids, so buf is input index 2;
-        # it aliases the new-buffer output (index 2) for the in-place write
+        out_shape=out_shape,
+        # operands 0/1 are the prefetched slot ids, so buf is input index
+        # 2; it aliases the new-buffer output (index 2): written in place
         input_output_aliases={2: 2},
-        interpret=INTERPRET,
+        interpret=backend.interpret(),
     )(srcs.astype(jnp.int32), dsts.astype(jnp.int32), buf, trained_rows,
-      global_prev.reshape(1, -1).astype(jnp.float32),
-      agg.reshape(1, -1).astype(jnp.float32),
-      col(picked_r.astype(jnp.int32)), col(undrafted_r.astype(jnp.int32)),
-      col(deprecated_r.astype(jnp.int32)), col(w_rows.astype(jnp.float32)))
+      *_row_args(agg, global_prev, (picked_r, undrafted_r, deprecated_r),
+                 w_rows))
     return new_global[0], new_agg[0], new_buf
 
 
@@ -877,32 +666,19 @@ def _q8_tier_rows_kernel(srcs_ref, dsts_ref, q_ref, scale_ref, base_ref,
                          buf_ref, global_ref, agg_ref, picked_ref,
                          undrafted_ref, deprecated_ref, completed_ref,
                          weights_ref, new_global_ref, new_agg_ref,
-                         newbuf_ref):
-    del srcs_ref, dsts_ref
-    k = pl.program_id(1)
-    _, t = q_ref.shape
-    deq = (q_ref[...].astype(jnp.float32).reshape(1, t // QBLOCK, QBLOCK)
-           * scale_ref[...][:, :, None]).reshape(1, t)
-    tr = jnp.where(completed_ref[...] != 0, deq,
-                   base_ref[...].astype(jnp.float32))
-    c0 = buf_ref[...].astype(jnp.float32)
-    g = global_ref[...].astype(jnp.float32)
-    p = picked_ref[...] != 0
-    u = undrafted_ref[...] != 0
-    d = deprecated_ref[...] != 0
-    w = weights_ref[...].astype(jnp.float32)
-    c1 = jnp.where(d & ~p, g, c0)
-    c1 = jnp.where(p, tr, c1)
-    c2 = jnp.where(u, tr, c1)
-    newbuf_ref[...] = c2.astype(newbuf_ref.dtype)
-
-    @pl.when(k == 0)
-    def _():
-        new_global_ref[...] = agg_ref[...]
-        new_agg_ref[...] = agg_ref[...]
-
-    new_global_ref[...] += w * (c1 - c0)
-    new_agg_ref[...] += w * (c2 - c0)
+                         newbuf_ref, grp, sem):
+    del buf_ref             # aliased: read and written through newbuf_ref
+    i, k = pl.program_id(0), pl.program_id(1)
+    tile = base_ref.shape[1]
+    tr = _q8_row(k, q_ref, scale_ref, base_ref, completed_ref)
+    p, u, d, w = _roles(k, picked_ref, undrafted_ref, deprecated_ref,
+                        weights_ref)
+    c0 = read_row(newbuf_ref, grp, sem, srcs_ref[k], i,
+                  tile).astype(jnp.float32)
+    c1, c2 = _row_math(c0, tr, global_ref[...].astype(jnp.float32), p, u, d)
+    write_row(newbuf_ref, grp, sem, dsts_ref[k], i, tile,
+              c2.astype(newbuf_ref.dtype))
+    _accumulate(k, agg_ref, new_global_ref, new_agg_ref, w, c0, c1, c2)
 
 
 @functools.partial(jax.jit, static_argnames=('tile',))
@@ -916,54 +692,23 @@ def safa_aggregate_packed_q8_tier_rows(q_rows, scales_rows, base_rows, buf,
     fall back to base_rows.  No local output exists — tier local state is
     virtual (base rows are always version snapshots).  Returns
     (new_global [N] f32, new_agg [N] f32, new_buf [capacity+1, N])."""
-    r, np_ = buf.shape
     k, _ = q_rows.shape
-    if np_ % tile:
-        raise ValueError(
-            f'packed buffer width {np_} not a multiple of tile={tile}; '
-            f'pack with pad_to=tile')
-    col = lambda arr: arr.reshape(k, 1)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(np_ // tile, k),
-        in_specs=[
-            pl.BlockSpec((1, tile), lambda i, j, srcs, dsts: (j, i)),   # q
-            pl.BlockSpec((1, tile // QBLOCK),
-                         lambda i, j, srcs, dsts: (j, i)),          # scales
-            pl.BlockSpec((1, tile), lambda i, j, srcs, dsts: (j, i)),  # base
-            pl.BlockSpec((1, tile),
-                         lambda i, j, srcs, dsts: (srcs[j], i)),    # buf
-            pl.BlockSpec((1, tile), lambda i, j, srcs, dsts: (0, i)),
-            pl.BlockSpec((1, tile), lambda i, j, srcs, dsts: (0, i)),
-            pl.BlockSpec((1, 1), lambda i, j, srcs, dsts: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, srcs, dsts: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, srcs, dsts: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, srcs, dsts: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, srcs, dsts: (j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tile), lambda i, j, srcs, dsts: (0, i)),
-            pl.BlockSpec((1, tile), lambda i, j, srcs, dsts: (0, i)),
-            pl.BlockSpec((1, tile),
-                         lambda i, j, srcs, dsts: (dsts[j], i)),
-        ])
+    in_specs = [
+        *_q8_slot_specs(tile),
+        pl.BlockSpec(memory_space=pl.ANY),                      # buf
+        *_row_specs(tile, 4),
+    ]
+    grid_spec, out_shape = _tier_grid(in_specs, buf, k=k, tile=tile)
     new_global, new_agg, new_buf = pl.pallas_call(
         _q8_tier_rows_kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((1, np_), jnp.float32),
-            jax.ShapeDtypeStruct((1, np_), jnp.float32),
-            jax.ShapeDtypeStruct((r, np_), buf.dtype),
-        ],
+        out_shape=out_shape,
         # operands 0/1 are prefetched slot ids, so buf is input index 5;
         # it aliases the new-buffer output (index 2)
         input_output_aliases={5: 2},
-        interpret=INTERPRET,
-    )(srcs.astype(jnp.int32), dsts.astype(jnp.int32), q_rows, scales_rows,
-      base_rows, buf,
-      global_prev.reshape(1, -1).astype(jnp.float32),
-      agg.reshape(1, -1).astype(jnp.float32),
-      col(picked_r.astype(jnp.int32)), col(undrafted_r.astype(jnp.int32)),
-      col(deprecated_r.astype(jnp.int32)), col(completed_r.astype(jnp.int32)),
-      col(w_rows.astype(jnp.float32)))
+        interpret=backend.interpret(),
+    )(srcs.astype(jnp.int32), dsts.astype(jnp.int32), q_rows,
+      scales_to_blocks(scales_rows, tile), base_rows, buf,
+      *_row_args(agg, global_prev,
+                 (picked_r, undrafted_r, deprecated_r, completed_r), w_rows))
     return new_global[0], new_agg[0], new_buf

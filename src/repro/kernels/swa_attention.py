@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.backend import INTERPRET
+from repro.kernels import backend
 
 NEG_INF = -1e30
 
@@ -30,16 +30,6 @@ NEG_INF = -1e30
 ALIAS_CONTRACTS = {
     '_kernel': ((),),
 }
-
-
-def _compiler_params():
-    """dimension_semantics: KV-block axis is sequential ('arbitrary')."""
-    cls = getattr(pltpu, 'CompilerParams', None) or getattr(
-        pltpu, 'TPUCompilerParams', None)
-    if cls is None:
-        return None
-    return cls(dimension_semantics=('parallel', 'parallel', 'parallel',
-                                    'arbitrary'))
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, scale,
@@ -127,7 +117,9 @@ def swa_attention(q, k, v, *, window=None, block_q: int = 128,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=_compiler_params(),
-        interpret=INTERPRET,
+        # the KV-block axis accumulates sequentially
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            'parallel', 'parallel', 'parallel', 'arbitrary')),
+        interpret=backend.interpret(),
     )(qp, kp, vp)
     return out[:, :S]

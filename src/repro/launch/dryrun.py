@@ -25,8 +25,12 @@ from repro.configs import (ARCH_IDS, INPUT_SHAPES, get_config,
 from repro.launch import analytic, hlo_parse
 from repro.launch import mesh as mesh_lib
 from repro.launch import roofline as rf
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import ServeSetup, SiloSetup
 from repro.models.model import build_model
+
+#: The chip whose roofline the dry run reports (a key of ``rf.PEAKS``).
+TARGET_DEVICE_KIND = 'TPU v5 lite'
 
 
 def active_params(cfg, model) -> int:
@@ -122,11 +126,10 @@ def lower_one(arch_id: str, shape_name: str, *, multi_pod: bool,
         n_params=model.n_params(), n_clients=n_cl)
     roof = rf.Roofline(flops=flops, hbm_bytes=byts,
                        coll_bytes=float(coll['adjusted_total_bytes']),
-                       chips=chips, model_flops=mf)
+                       chips=chips, device_kind=TARGET_DEVICE_KIND,
+                       model_flops=mf)
 
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):
-        cost = cost[0]
     result = {
         'arch': arch_id, 'shape': shape_name,
         'mesh': mesh_lib.describe(mesh), 'chips': chips,
@@ -159,6 +162,7 @@ def main(argv=None):
     ap.add_argument('--out', default=None)
     ap.add_argument('--skip-existing', action='store_true')
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     combos = []
     if args.all:

@@ -18,11 +18,18 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_local_mesh():
-    """1-device mesh with the production axis names, for CPU tests."""
+    """(data, model) mesh over every local device, with the production
+    axis names: 1x1 on one device, (n/2)x2 on an even count n.  An odd
+    count above one has no such layout and is refused rather than run
+    on fewer devices than the host has."""
     n = len(jax.devices())
-    if n >= 4:
-        return jax.make_mesh((n // 2, 2), ("data", "model"))
-    return jax.make_mesh((1, 1), ("data", "model"))
+    if n == 1:
+        return jax.make_mesh((1, 1), ("data", "model"))
+    if n % 2:
+        raise ValueError(
+            f'no (data, model) mesh with a model axis of 2 over {n} '
+            f'devices; run on one device or an even number of them')
+    return jax.make_mesh((n // 2, 2), ("data", "model"))
 
 
 def n_clients(mesh: Mesh, client_axes=("pod", "data")) -> int:

@@ -10,7 +10,8 @@ compiled HLO (per the assignment):
 FLOPs/bytes come from ``compiled.cost_analysis()``; collective bytes are
 parsed from the post-SPMD optimized HLO text (sum of output-shape bytes of
 all-gather / all-reduce / reduce-scatter / all-to-all / collective-permute /
-ragged-all-to-all ops).  Hardware constants: TPU v5e.
+ragged-all-to-all ops).  Hardware constants come from ``PEAKS``, keyed by
+the device kind JAX reports.
 """
 from __future__ import annotations
 
@@ -18,10 +19,32 @@ import dataclasses
 import re
 from typing import Optional
 
-# TPU v5e per-chip constants (assignment-specified)
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # B/s
-ICI_BW = 50e9                   # B/s per link
+
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks."""
+    flops_bf16: float           # FLOP/s
+    hbm_bw: float               # B/s
+    ici_bw: float               # B/s per interconnect link
+
+
+#: Per-chip peaks keyed by ``jax.devices()[0].device_kind``.  TPU v5e
+#: (device kind ``TPU v5 lite``): Google Cloud documentation, "TPU v5e" —
+#: 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip
+#: interconnect over 4 links (50 GB/s each).
+PEAKS = {
+    'TPU v5 lite': Peaks(flops_bf16=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The peak table's row for ``device_kind``; an unknown device is an
+    error, never a default."""
+    if device_kind not in PEAKS:
+        raise KeyError(f'no peaks for device kind {device_kind!r}; the '
+                       f'table has {sorted(PEAKS)}')
+    return PEAKS[device_kind]
 
 _DTYPE_BYTES = {
     'pred': 1, 's8': 1, 'u8': 1, 'f8e4m3fn': 1, 'f8e5m2': 1,
@@ -79,20 +102,21 @@ class Roofline:
     hbm_bytes: float            # total bytes accessed
     coll_bytes: float           # total collective bytes (per-chip shapes)
     chips: int
+    device_kind: str            # key of ``PEAKS``
     model_flops: float = 0.0    # 6*N*D useful-FLOPs estimate
 
     @property
     def t_compute(self) -> float:
-        return self.flops / (self.chips * PEAK_FLOPS_BF16)
+        return self.flops / (self.chips * peaks(self.device_kind).flops_bf16)
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes / (self.chips * HBM_BW)
+        return self.hbm_bytes / (self.chips * peaks(self.device_kind).hbm_bw)
 
     @property
     def t_collective(self) -> float:
         # HLO shapes are already per-chip after SPMD partitioning
-        return self.coll_bytes / ICI_BW
+        return self.coll_bytes / peaks(self.device_kind).ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -110,6 +134,7 @@ class Roofline:
         return {
             'flops': self.flops, 'hbm_bytes': self.hbm_bytes,
             'coll_bytes': self.coll_bytes, 'chips': self.chips,
+            'device_kind': self.device_kind,
             't_compute_s': self.t_compute, 't_memory_s': self.t_memory,
             't_collective_s': self.t_collective,
             'bottleneck': self.bottleneck,
@@ -126,14 +151,12 @@ def model_flops_estimate(n_active_params: int, tokens: int,
     return mult * n_active_params * tokens
 
 
-def from_compiled(compiled, lowered_text: str, chips: int,
+def from_compiled(compiled, lowered_text: str, chips: int, device_kind: str,
                   model_flops: float = 0.0) -> Roofline:
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0]
     flops = float(cost.get('flops', 0.0))
     byt = float(cost.get('bytes accessed', 0.0))
     coll = collective_bytes(lowered_text)
     return Roofline(flops=flops, hbm_bytes=byt,
                     coll_bytes=float(coll['total_bytes']), chips=chips,
-                    model_flops=model_flops)
+                    device_kind=device_kind, model_flops=model_flops)

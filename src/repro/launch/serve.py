@@ -19,6 +19,7 @@ import numpy as np
 from repro import checkpoint
 from repro.configs import ARCH_IDS, get_config
 from repro.launch import mesh as mesh_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 
 
@@ -75,6 +76,7 @@ def main(argv=None):
     ap.add_argument('--ckpt', default=None)
     ap.add_argument('--full-size', action='store_true')
     args = ap.parse_args(argv)
+    enable_compile_cache()
     run(args.arch, batch=args.batch, prompt_len=args.prompt_len,
         gen=args.gen, ckpt=args.ckpt, full_size=args.full_size)
 

@@ -23,6 +23,7 @@ from repro.core import protocol, selection
 from repro.data import make_lm_tokens
 from repro.fedsim import EnvSpec
 from repro.launch import mesh as mesh_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import SiloSetup
 from repro.models.model import build_model
 
@@ -130,6 +131,7 @@ def main(argv=None):
     ap.add_argument('--ckpt', default=None)
     ap.add_argument('--full-size', action='store_true')
     args = ap.parse_args(argv)
+    enable_compile_cache()
     t0 = time.time()
     hist = run(args.arch, rounds=args.rounds, n_clients=args.clients,
                fraction=args.fraction, lag_tolerance=args.lag_tolerance,

@@ -340,7 +340,7 @@ class TestJaxprMutations:
         assert bad and '_ghost_kernel' in bad[0].detail
 
     def test_jax004_f64_promotion_fires(self):
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             j = jax.make_jaxpr(lambda x: jnp.sin(x))(
                 jnp.asarray(1.0, jnp.float64))
         f64, _ = jaxpr_checks._check_dtypes_and_callbacks(j.jaxpr)

@@ -17,10 +17,8 @@ from repro.fedsim import FLEnv
 from repro.kernels import ops as kops
 from repro.kernels import ref
 from repro.kernels.comm_quant import (QBLOCK, dequantize, dequantize_packed,
-                                      quantize, quantize_packed,
-                                      quantize_packed_fleet)
-from repro.kernels.safa_aggregate import (safa_aggregate_packed_q8,
-                                          safa_aggregate_packed_q8_fleet)
+                                      quantize, quantize_packed)
+from repro.kernels.safa_aggregate import safa_aggregate_packed_q8
 
 
 def _env(**kw):
@@ -78,7 +76,7 @@ class TestQuantizePacked:
 
     def test_fleet_matches_singles(self):
         xs = jax.random.normal(jax.random.PRNGKey(2), (3, 5, 2048))
-        qf, sf = quantize_packed_fleet(xs)
+        qf, sf = jax.vmap(quantize_packed)(xs)
         for i in range(3):
             q1, s1 = quantize_packed(xs[i])
             np.testing.assert_array_equal(np.asarray(qf[i]), np.asarray(q1))
@@ -121,7 +119,7 @@ class TestPackedQ8Kernel:
         singles = [self._operands(key=k) for k in range(3)]
         stacked = [jnp.stack([np.asarray(s[k]) for s in singles])
                    for k in singles[0]]
-        outs_f = safa_aggregate_packed_q8_fleet(*stacked)
+        outs_f = jax.vmap(safa_aggregate_packed_q8)(*stacked)
         for i, ops in enumerate(singles):
             outs_1 = safa_aggregate_packed_q8(*ops.values())
             for a, b in zip(outs_f, outs_1):
@@ -283,27 +281,29 @@ class TestWireRound:
 
 class TestBackendHelper:
     def test_kernel_modules_share_backend_constant(self):
-        from repro.kernels import (backend, comm_quant, safa_aggregate,
+        """Every kernel module asks the one shared ``backend`` module when
+        a kernel is traced; none keeps a mode captured at import."""
+        from repro.kernels import (backend, comm_quant, ops, safa_aggregate,
                                    swa_attention)
-        assert comm_quant.INTERPRET is backend.INTERPRET
-        assert safa_aggregate.INTERPRET is backend.INTERPRET
-        assert swa_attention.INTERPRET is backend.INTERPRET
+        for mod in (comm_quant, ops, safa_aggregate, swa_attention):
+            assert mod.backend is backend
+            assert not hasattr(mod, 'INTERPRET')
+        assert not hasattr(backend, 'INTERPRET')
 
     def test_env_override(self, monkeypatch):
+        """The platform alone decides: no environment variable forces
+        interpret mode on a TPU or compilation on a CPU, and any other
+        platform is refused."""
         from repro.kernels import backend
-        monkeypatch.setenv('REPRO_FORCE_INTERPRET', '1')
-        assert backend.use_interpret() is True
-        monkeypatch.setenv('REPRO_FORCE_INTERPRET', '0')
-        assert backend.use_interpret() is False
-        monkeypatch.setenv('REPRO_FORCE_INTERPRET', 'false')
-        assert backend.use_interpret() is False
-        # set-but-empty must fall back to detection, not force compile
-        monkeypatch.setenv('REPRO_FORCE_INTERPRET', '')
-        assert backend.use_interpret() == \
-            (jax.default_backend() != 'tpu')
-        monkeypatch.delenv('REPRO_FORCE_INTERPRET')
-        assert backend.use_interpret() == \
-            (jax.default_backend() != 'tpu')
+        for value in ('1', '0', 'true', ''):
+            monkeypatch.setenv('REPRO_FORCE_INTERPRET', value)
+            monkeypatch.setattr(backend, 'kernel_platform', lambda: 'tpu')
+            assert backend.interpret() is False
+            monkeypatch.setattr(backend, 'kernel_platform', lambda: 'cpu')
+            assert backend.interpret() is True
+        monkeypatch.setattr(backend, 'kernel_platform', lambda: 'gpu')
+        with pytest.raises(RuntimeError, match='gpu'):
+            backend.interpret()
 
 
 class TestQuantizedTrainFnMemo:

@@ -423,10 +423,15 @@ class TestFleetKernel:
                 tr(jax.random.PRNGKey(1), (s, m)),
                 tr(jax.random.PRNGKey(2), (s,)), masks)
 
+    @staticmethod
+    def _fleet(cache, trained, g, masks):
+        """The fleet form of the packed aggregation: one vmapped launch."""
+        return jax.vmap(lambda c, t, gg, mm: kops.safa_aggregate_tree_packed(
+            c, t, gg, **mm))(cache, trained, g, masks)
+
     def test_fleet_grid_matches_per_member_packed(self):
         cache, trained, g, masks = self._operands()
-        out = kops.safa_aggregate_tree_packed_fleet(cache, trained, g,
-                                                    **masks)
+        out = self._fleet(cache, trained, g, masks)
         for s in range(3):
             ref = kops.safa_aggregate_tree_packed(
                 jax.tree.map(lambda a, i=s: a[i], cache),
@@ -444,8 +449,7 @@ class TestFleetKernel:
     def test_fleet_grid_single_dispatch(self):
         cache, trained, g, masks = self._operands()
         jaxpr = jax.make_jaxpr(
-            lambda c, t, gg: kops.safa_aggregate_tree_packed_fleet(
-                c, t, gg, **masks))(cache, trained, g)
+            lambda c, t, gg: self._fleet(c, t, gg, masks))(cache, trained, g)
         assert kops.count_pallas_calls(jaxpr.jaxpr) == 1
 
     def test_fleet_pack_roundtrip(self):
@@ -460,8 +464,7 @@ class TestFleetKernel:
         cache, trained, g, masks = self._operands()
         to16 = lambda t: jax.tree.map(lambda a: a.astype(jnp.bfloat16), t)
         with pytest.raises(TypeError, match='float32'):
-            kops.safa_aggregate_tree_packed_fleet(to16(cache), to16(trained),
-                                                  to16(g), **masks)
+            self._fleet(to16(cache), to16(trained), to16(g), masks)
 
 
 class TestEnvGrid:

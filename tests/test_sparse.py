@@ -29,6 +29,7 @@ from repro.data import make_regression, partition
 from repro.data.tasks import regression_task
 from repro.fedsim import FLEnv
 from repro.kernels import ops
+from repro.kernels.backend import row_pad
 
 M = 24
 BASE = dict(m=M, crash_prob=0.3, dataset_size=480, batch_size=10,
@@ -458,7 +459,9 @@ class TestRowsKernels:
     def test_gather_scatter_roundtrip(self):
         rng = np.random.default_rng(0)
         m, n, tile = 37, 512, 256
-        buf = self._buf(rng, m + 1, n)
+        # scatter_rows writes whole 8-row groups: the buffer holds m + 1
+        # rows (trailing scratch row) padded to a multiple of 8
+        buf = self._buf(rng, row_pad(m + 1), n)
         rows = jnp.asarray(np.array([3, 9, 14, m, 2], np.int32))
         got = ops.gather_rows(buf, rows, tile=tile)
         np.testing.assert_array_equal(np.asarray(got),
@@ -472,9 +475,12 @@ class TestRowsKernels:
     def test_gather_scatter_fleet(self):
         rng = np.random.default_rng(1)
         s, m, n, k, tile = 3, 21, 256, 4, 256
-        buf = self._buf(rng, s * (m + 1), n).reshape(s, m + 1, n)
+        r = row_pad(m + 1)
+        buf = self._buf(rng, s * r, n).reshape(s, r, n)
         rows = jnp.asarray(rng.integers(0, m + 1, (s, k)).astype(np.int32))
-        got = ops.gather_rows_fleet(buf, rows, tile=tile)
+        # fleets batch the rows kernels under vmap
+        got = jax.vmap(lambda b, i: ops.gather_rows(b, i, tile=tile))(
+            buf, rows)
         want = np.stack([np.asarray(buf)[b][np.asarray(rows)[b]]
                          for b in range(s)])
         np.testing.assert_array_equal(np.asarray(got), want)
@@ -482,7 +488,8 @@ class TestRowsKernels:
         want = np.asarray(buf).copy()           # snapshot: buf is donated
         for b in range(s):
             want[b][np.asarray(rows)[b]] = np.asarray(vals)[b]
-        out = ops.scatter_rows_fleet(buf, rows, vals, tile=tile)
+        out = jax.vmap(lambda b, i, v: ops.scatter_rows(b, i, v, tile=tile))(
+            buf, rows, vals)
         np.testing.assert_array_equal(np.asarray(out), want)
 
     def test_tile_mismatch_raises(self):

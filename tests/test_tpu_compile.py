@@ -1,0 +1,118 @@
+"""The main path's Pallas kernels, compiled for a TPU v5e that is described
+but not attached.
+
+The chip's compiler is installed with JAX: ``get_topology_desc`` describes
+a v5e:2x2 slice, and each test compiles one kernel for one of its chips at
+the width the paper-scale round uses, then checks that the compiled
+program holds the Mosaic kernel (``tpu_custom_call``).  Nothing runs;
+each compile takes a second or two.  What the compiler refuses here
+(blocks not aligned to the chip's tiles, more VMEM than a kernel may
+use) interpret mode on the CPU cannot show.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import comm_quant, ops, safa_aggregate
+
+#: PAPER_TASKS['task2_cnn']: clients, and the paper CNN's packed width
+M, N = 100, 342_016
+#: PAPER_TASKS['task3_svm'] clients; its 36 floats pack to one 2048 tile
+M_SVM, N_SVM = 500, 2048
+#: tier width of the task2 round: K active slots over a value buffer of
+#: R rows (capacity + 1, padded to whole 8-row groups)
+K, R = 40, 64
+
+F32, I8, I32, BOOL = jnp.float32, jnp.int8, jnp.int32, jnp.bool_
+
+
+@pytest.fixture(scope='module')
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:
+        pytest.skip(f'no v5e:2x2 topology can be described here: {e}')
+
+
+@pytest.fixture(scope='module')
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def compile_for_tpu(one_chip, monkeypatch):
+    """Compile ``fn`` for the described chip from (shape, dtype) pairs and
+    return the compiled text.  Kernels are traced for the TPU, and JAX's
+    persistent compilation cache is off: a compile for a described chip
+    is written to it but cannot be read back without the chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.kernels import backend
+    monkeypatch.setattr(backend, 'kernel_platform', lambda: 'tpu')
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update('jax_enable_compilation_cache', False)
+    compilation_cache.reset_cache()
+    jax.clear_caches()      # no trace made earlier in interpret mode
+
+    def compile_(fn, *shapes):
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in shapes]
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+    yield compile_
+    jax.clear_caches()      # no TPU trace left for later tests
+    jax.config.update('jax_enable_compilation_cache', cache_on)
+
+
+def _dense_operands(m, n):
+    return [((m, n), F32), ((m, n), F32), ((n,), F32), ((m,), BOOL),
+            ((m,), BOOL), ((m,), BOOL), ((m,), F32)]
+
+
+def test_safa_aggregate_packed_paper_cnn(compile_for_tpu):
+    text = compile_for_tpu(safa_aggregate.safa_aggregate_packed,
+                           *_dense_operands(M, N))
+    assert 'tpu_custom_call' in text
+
+
+def test_safa_aggregate_packed_svm_m500(compile_for_tpu):
+    """m=500 whole client columns: three f32 operands double-buffered at
+    the default tile would pass v5e's 16 MiB scoped VMEM."""
+    text = compile_for_tpu(safa_aggregate.safa_aggregate_packed,
+                           *_dense_operands(M_SVM, N_SVM))
+    assert 'tpu_custom_call' in text
+
+
+def test_quantize_packed_paper_cnn(compile_for_tpu):
+    text = compile_for_tpu(comm_quant.quantize_packed, ((M, N), F32))
+    assert 'tpu_custom_call' in text
+
+
+def test_safa_aggregate_packed_q8_paper_cnn(compile_for_tpu):
+    text = compile_for_tpu(
+        safa_aggregate.safa_aggregate_packed_q8,
+        ((M, N), I8), ((M, N // comm_quant.QBLOCK), F32), ((M, N), F32),
+        ((M, N), F32), ((N,), F32), ((M,), BOOL), ((M,), BOOL),
+        ((M,), BOOL), ((M,), BOOL), ((M,), F32))
+    assert 'tpu_custom_call' in text
+
+
+@pytest.mark.parametrize('kernel', ['gather_rows', 'scatter_rows'])
+def test_rows_kernels_tier_width(compile_for_tpu, kernel):
+    shapes = [((R, N), F32), ((K,), I32)]
+    if kernel == 'scatter_rows':
+        shapes.append(((K, N), F32))
+    text = compile_for_tpu(getattr(ops, kernel), *shapes)
+    assert 'tpu_custom_call' in text
+
+
+def test_tier_rows_tier_width(compile_for_tpu):
+    text = compile_for_tpu(
+        safa_aggregate.safa_aggregate_packed_tier_rows,
+        ((R, N), F32), ((K, N), F32), ((N,), F32), ((N,), F32),
+        ((K,), I32), ((K,), I32), ((K,), BOOL), ((K,), BOOL), ((K,), BOOL),
+        ((K,), F32))
+    assert 'tpu_custom_call' in text
