@@ -50,7 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import checkpoint as ckpt
-from repro import fedsim
+from repro import fedsim, obs
 from repro.core import federation, protocol, schedules
 from repro.core.federation import Task
 from repro.core.schedules import History, RoundRecord, SweepMember
@@ -432,7 +432,8 @@ def _eval_rounds(rounds: int, eval_every: int):
 
 
 def _record_eval(hist: History, rec: RoundRecord, task, global_w):
-    rec.eval = task.evaluate(global_w)
+    with obs.span('evaluate'):
+        rec.eval = task.evaluate(global_w)
     if hist.best_eval is None or rec.eval['loss'] < hist.best_eval['loss']:
         hist.best_eval = rec.eval
 
@@ -1128,8 +1129,9 @@ class Experiment:
                 pre = self._pdef.tier_precompute
             else:
                 pre = self._pdef.sparse_precompute
-            self._sched = pre(
-                self.env, self.protocol, rounds=self.rounds, seed=self.seed)
+            with obs.span('precompute'):
+                self._sched = pre(self.env, self.protocol,
+                                  rounds=self.rounds, seed=self.seed)
         return self._sched
 
     def compile(self) -> 'CompiledRunner':
@@ -1263,11 +1265,14 @@ class CompiledRunner:
             raise ValueError('numeric run needs a Task '
                              '(or ExecSpec(numeric=False))')
 
-        st = _init_state(exp.task, exp.env.m, exp.seed, self._pdef.uses_cache,
-                         self._stateless(ex))
-        weights_j = jnp.asarray(exp.env.weights)
-        if self._pdef.prepare_state is not None:
-            self._pdef.prepare_state(st, weights_j, ex, False, sched)
+        with obs.span('run.prepare'):
+            st = _init_state(exp.task, exp.env.m, exp.seed,
+                             self._pdef.uses_cache, self._stateless(ex))
+            weights_j = jnp.asarray(exp.env.weights)
+            if self._pdef.prepare_state is not None:
+                self._pdef.prepare_state(st, weights_j, ex, False, sched)
+            if engine == 'scan' and self._dev is None:
+                self._dev = sched.to_device()
         start_seg = 0
         fingerprint = exp.fingerprint()
         if checkpoint is not None and ckpt.exists(checkpoint):
@@ -1279,22 +1284,21 @@ class CompiledRunner:
         weights = weights_j
         train_fn = self._train_fn(exp.task)
         evals = _eval_rounds(exp.rounds, ex.eval_every)
-        if engine == 'scan' and self._dev is None:
-            self._dev = sched.to_device()
         start = evals[start_seg - 1] if start_seg else 0
         done = 0
         for k in range(start_seg, len(evals)):
             stop = evals[k]
-            if engine == 'scan':
-                seg = jax.tree.map(
-                    lambda a, s=start, e=stop: a[s:e], self._dev)
-                self._pdef.scan_segment(st, seg, weights, train_fn, ex)
-            else:
-                for t in range(start + 1, stop + 1):
-                    self._pdef.loop_round(st, sched, t - 1, weights,
-                                          train_fn, ex)
-            if self._pdef.finish_segment is not None:
-                self._pdef.finish_segment(st, weights, False)
+            with obs.span('segment'):
+                if engine == 'scan':
+                    seg = jax.tree.map(
+                        lambda a, s=start, e=stop: a[s:e], self._dev)
+                    self._pdef.scan_segment(st, seg, weights, train_fn, ex)
+                else:
+                    for t in range(start + 1, stop + 1):
+                        self._pdef.loop_round(st, sched, t - 1, weights,
+                                              train_fn, ex)
+                if self._pdef.finish_segment is not None:
+                    self._pdef.finish_segment(st, weights, False)
             _record_eval(hist, hist.records[stop - 1], exp.task, st.global_w)
             start = stop
             done += 1

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import protocol, schedules, selection
 from repro.core.schedules import (
     AsyncFleetSchedule,
@@ -146,87 +147,91 @@ def precompute_safa_schedule(env: FLEnv, *, fraction: float,
     committed_prev = np.ones(m, bool)      # round 1: everyone holds w(0)
     picked_prev = np.zeros(m, bool)
     pending = np.zeros(m)                  # straggler partial progress (fraction)
-    tim = env.round_timing(rounds)         # [rounds, m] trace/wire-aware
+    with obs.span('precompute.draw'):
+        tim = env.round_timing(rounds)     # [rounds, m] trace/wire-aware
+        crashed_all, cfrac_all = env.draw_rounds(rounds)
     work = env.n_batches * env.epochs      # per-round work units
     wasted = 0.0
     performed = 0.0
-    crashed_all, cfrac_all = env.draw_rounds(rounds)
     masks = {k: np.zeros((rounds, m), bool)
              for k in ('sync', 'committed', 'picked', 'undrafted',
                        'deprecated')} if form == 'dense' else None
     sparse_rows = []
     base_v_rows = []
     records = []
+    with obs.span('precompute.events'):
+        for t in range(1, rounds + 1):
+            gv = t - 1
+            up, dep, _ = protocol.classify_versions(v, gv, lag_tolerance,
+                                                    committed_prev)
+            sync = up | dep
+            # forced sync discards any pending straggler progress (futility);
+            # masked-sum form so the fleet-major precompute reduces identically
+            wasted += float(np.sum(np.where(sync, pending * work, 0.0)))
+            pending[sync] = 0.0
+            v[sync] = gv
 
-    for t in range(1, rounds + 1):
-        gv = t - 1
-        up, dep, _ = protocol.classify_versions(v, gv, lag_tolerance,
-                                                committed_prev)
-        sync = up | dep
-        # forced sync discards any pending straggler progress (futility);
-        # masked-sum form so the fleet-major precompute reduces identically
-        wasted += float(np.sum(np.where(sync, pending * work, 0.0)))
-        pending[sync] = 0.0
-        v[sync] = gv
+            crashed, cfrac = crashed_all[t - 1], cfrac_all[t - 1]
+            remaining = 1.0 - pending
+            t_train = remaining * tim.full_tt[t - 1]
+            t_dist = env.t_dist(int(sync.sum()))
+            # every live client uploads; sync'd ones first download the global
+            # (== t_updown * (1 + sync) bitwise when the traces are constant)
+            arrival = t_dist + (tim.t_up[t - 1] + sync * tim.t_down[t - 1]) \
+                + t_train
+            completed = ~crashed
+            arrival = np.where(completed, arrival, np.inf)
+            performed += float(np.sum(np.where(completed, remaining,
+                                               cfrac * remaining) * work))
+            base_versions = v.copy()
 
-        crashed, cfrac = crashed_all[t - 1], cfrac_all[t - 1]
-        remaining = 1.0 - pending
-        t_train = remaining * tim.full_tt[t - 1]
-        t_dist = env.t_dist(int(sync.sum()))
-        # every live client uploads; sync'd ones first download the global
-        # (== t_updown * (1 + sync) bitwise when the traces are constant)
-        arrival = t_dist + (tim.t_up[t - 1] + sync * tim.t_down[t - 1]) \
-            + t_train
-        completed = ~crashed
-        arrival = np.where(completed, arrival, np.inf)
-        performed += float(np.sum(np.where(completed, remaining,
-                                           cfrac * remaining) * work))
-        base_versions = v.copy()
+            sel = selection.cfcfm(arrival, completed, picked_prev, fraction,
+                                  env.t_lim)
+            pending = np.where(crashed,
+                               np.minimum(pending + cfrac * remaining, 0.999),
+                               pending)
+            pending[sel.committed] = 0.0
+            v[sel.committed] = t
 
-        sel = selection.cfcfm(arrival, completed, picked_prev, fraction, env.t_lim)
-        pending = np.where(crashed, np.minimum(pending + cfrac * remaining, 0.999),
-                           pending)
-        pending[sel.committed] = 0.0
-        v[sel.committed] = t
+            if form == 'dense':
+                i = t - 1
+                masks['sync'][i] = sync
+                masks['committed'][i] = sel.committed
+                masks['picked'][i] = sel.picked
+                masks['undrafted'][i] = sel.undrafted
+                masks['deprecated'][i] = dep
+            else:
+                row = schedules.safa_sparse_row(
+                    sync, sel.committed, sel.picked, sel.undrafted, dep,
+                    bootstrap=(t == 1))
+                sparse_rows.append(row)
+                if form == 'sparse_tier':
+                    base_v_rows.append(base_versions[row[0]])
 
-        if form == 'dense':
-            i = t - 1
-            masks['sync'][i] = sync
-            masks['committed'][i] = sel.committed
-            masks['picked'][i] = sel.picked
-            masks['undrafted'][i] = sel.undrafted
-            masks['deprecated'][i] = dep
-        else:
-            row = schedules.safa_sparse_row(
-                sync, sel.committed, sel.picked, sel.undrafted, dep,
-                bootstrap=(t == 1))
-            sparse_rows.append(row)
-            if form == 'sparse_tier':
-                base_v_rows.append(base_versions[row[0]])
-
-        records.append(RoundRecord(
-            round=t,
-            round_len=min(env.t_lim, sel.quota_met_time),
-            t_dist=t_dist,
-            eur=float(sel.picked.sum()) / m,
-            sr=float(sync.sum()) / m,
-            vv=float(_masked_var(base_versions, sel.committed)),
-            n_picked=int(sel.picked.sum()),
-            n_committed=int(sel.committed.sum()),
-            n_crashed=int(crashed.sum()),
-        ))
-        committed_prev = sel.committed.copy()
-        picked_prev = sel.picked.copy()
+            records.append(RoundRecord(
+                round=t,
+                round_len=min(env.t_lim, sel.quota_met_time),
+                t_dist=t_dist,
+                eur=float(sel.picked.sum()) / m,
+                sr=float(sync.sum()) / m,
+                vv=float(_masked_var(base_versions, sel.committed)),
+                n_picked=int(sel.picked.sum()),
+                n_committed=int(sel.committed.sum()),
+                n_crashed=int(crashed.sum()),
+            ))
+            committed_prev = sel.committed.copy()
+            picked_prev = sel.picked.copy()
 
     futility = wasted / max(performed, 1e-9)
-    if form == 'sparse_tier':
-        return schedules.build_tier_schedule(m, sparse_rows, base_v_rows,
-                                             records, futility)
-    if form == 'sparse':
+    if form == 'dense':
+        return SafaSchedule(records=records, futility=futility, **masks)
+    with obs.span('precompute.lower'):
+        if form == 'sparse_tier':
+            return schedules.build_tier_schedule(m, sparse_rows, base_v_rows,
+                                                 records, futility)
         idx, roles = schedules.pack_sparse_rows(sparse_rows, m)
         return schedules.SparseSchedule(m=m, idx=idx, roles=roles,
                                         records=records, futility=futility)
-    return SafaSchedule(records=records, futility=futility, **masks)
 
 
 def _quantized_train_fn(base_fn):
@@ -253,7 +258,8 @@ def _quantized_train_fn(base_fn):
                     for k in range(flat.shape[0])]
             return jnp.stack(rows).reshape(x.shape)
 
-        return jax.tree.map(per_leaf, trained)
+        with obs.scope('wire'):
+            return jax.tree.map(per_leaf, trained)
 
     owner = getattr(base_fn, '__self__', None)
     if owner is None:

@@ -9,6 +9,13 @@ masked updates: picked entries overwrite pre-aggregation (Eq. 6), undrafted
 entries overwrite post-aggregation (Eq. 8) — bit-identical to the paper's
 three-step discriminative aggregation (tests assert the step-by-step
 equivalence).
+
+Every op of a round body sits under one ``obs.scope`` phase: ``rows``
+(models moved between the state and training), ``train`` (the
+``local_train_fn`` call), ``wire`` (quantisation outside the aggregation
+kernel) and ``aggregate`` (Eq. 6-8).  The round functions below are
+shared by the scan, loop and fleet engines, so all of them carry the
+scopes.
 """
 from __future__ import annotations
 
@@ -17,6 +24,8 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+from repro import obs
 
 
 def _bmask(mask, leaf):
@@ -150,13 +159,15 @@ def safa_server_step(base, trained, cache, global_w, *, completed, picked,
             base, trained, cache, global_w, picked=picked,
             undrafted=undrafted, deprecated=deprecated, completed=completed,
             weights=weights)
-    # crashed clients make no visible progress this round
-    trained = masked_select(completed, trained, base)
-    res = discriminative_aggregation(
-        cache, trained, global_w, picked=picked, undrafted=undrafted,
-        deprecated=deprecated, weights=weights, use_kernel=use_kernel)
-    # committed clients now hold their own trained model locally
-    new_local = masked_select(completed, trained, base)
+    with obs.scope('aggregate'):
+        # crashed clients make no visible progress this round
+        trained = masked_select(completed, trained, base)
+        res = discriminative_aggregation(
+            cache, trained, global_w, picked=picked, undrafted=undrafted,
+            deprecated=deprecated, weights=weights, use_kernel=use_kernel)
+    with obs.scope('rows'):
+        # committed clients now hold their own trained model locally
+        new_local = masked_select(completed, trained, base)
     return res.new_global, new_local, res.new_cache
 
 
@@ -178,8 +189,10 @@ def safa_round(global_w, local_w, cache, *, sync_mask, completed, picked,
     Returns (new_global, new_local, new_cache).
     """
     check_wire(wire)
-    base = distribute(global_w, local_w, sync_mask)
-    trained = local_train_fn(base, *train_args)
+    with obs.scope('rows'):
+        base = distribute(global_w, local_w, sync_mask)
+    with obs.scope('train'):
+        trained = local_train_fn(base, *train_args)
     return safa_server_step(
         base, trained, cache, global_w, completed=completed, picked=picked,
         undrafted=undrafted, deprecated=deprecated, weights=weights,
@@ -447,8 +460,10 @@ def fedavg_round(global_w, local_w, *, selected, completed, weights,
     compressed transfer actually delivers.  Returns (new_global,
     new_local)."""
     check_wire(wire)
-    base = distribute(global_w, local_w, selected)
-    trained = local_train_fn(base, *train_args)
+    with obs.scope('rows'):
+        base = distribute(global_w, local_w, selected)
+    with obs.scope('train'):
+        trained = local_train_fn(base, *train_args)
     return fedavg_server_step(base, trained, global_w, selected=selected,
                               completed=completed, weights=weights, wire=wire)
 
@@ -461,26 +476,32 @@ def fedavg_server_step(base, trained, global_w, *, selected, completed,
     new_local)."""
     if wire == 'int8':
         from repro.kernels import ops as kops
-        trained = kops.wire_roundtrip_packed(trained, like=global_w)
-    ok = selected & completed
-    wsum = jnp.maximum(jnp.sum(weights * ok), 1e-12)
-    eff_w = jnp.where(ok, weights, 0.0) / wsum
+        with obs.scope('wire'):
+            trained = kops.wire_roundtrip_packed(trained, like=global_w)
+    with obs.scope('aggregate'):
+        ok = selected & completed
+        wsum = jnp.maximum(jnp.sum(weights * ok), 1e-12)
+        eff_w = jnp.where(ok, weights, 0.0) / wsum
 
-    def red(t, g):
-        w = eff_w.reshape((-1,) + (1,) * (t.ndim - 1)).astype(jnp.float32)
-        agg = jnp.sum(t.astype(jnp.float32) * w, axis=0)
-        any_ok = jnp.sum(ok) > 0
-        return jnp.where(any_ok, agg, g.astype(jnp.float32)).astype(g.dtype)
+        def red(t, g):
+            w = eff_w.reshape((-1,) + (1,) * (t.ndim - 1)).astype(jnp.float32)
+            agg = jnp.sum(t.astype(jnp.float32) * w, axis=0)
+            any_ok = jnp.sum(ok) > 0
+            return jnp.where(any_ok, agg,
+                             g.astype(jnp.float32)).astype(g.dtype)
 
-    new_global = jax.tree.map(red, trained, global_w)
-    new_local = masked_select(ok, trained, base)
+        new_global = jax.tree.map(red, trained, global_w)
+    with obs.scope('rows'):
+        new_local = masked_select(ok, trained, base)
     return new_global, new_local
 
 
 def local_only_round(local_w, *, completed, local_train_fn, train_args=()):
     """Fully-local baseline: train, never aggregate."""
-    trained = local_train_fn(local_w, *train_args)
-    return masked_select(completed, trained, local_w)
+    with obs.scope('train'):
+        trained = local_train_fn(local_w, *train_args)
+    with obs.scope('rows'):
+        return masked_select(completed, trained, local_w)
 
 
 def fedasync_merge(global_w, trained, *, order, alphas):
@@ -636,13 +657,16 @@ def safa_round_sparse(global_w, local_w, cache, *, idx, roles, weights,
     trace runs.  Returns (new_global, new_local, new_cache)."""
     check_wire(wire)
     m = weights.shape[0]
-    sync_mask, completed, picked, undrafted, deprecated = scatter_masks(
-        idx, roles, m, (ROLE_SYNC, ROLE_COMMITTED, ROLE_PICKED,
-                        ROLE_UNDRAFTED, ROLE_DEPRECATED))
-    base = distribute(global_w, local_w, sync_mask)
-    base_rows = tree_gather(base, idx)
-    trained_rows = local_train_fn(base_rows, idx, *train_args)
-    trained = tree_scatter(base, idx, trained_rows)
+    with obs.scope('rows'):
+        sync_mask, completed, picked, undrafted, deprecated = scatter_masks(
+            idx, roles, m, (ROLE_SYNC, ROLE_COMMITTED, ROLE_PICKED,
+                            ROLE_UNDRAFTED, ROLE_DEPRECATED))
+        base = distribute(global_w, local_w, sync_mask)
+        base_rows = tree_gather(base, idx)
+    with obs.scope('train'):
+        trained_rows = local_train_fn(base_rows, idx, *train_args)
+    with obs.scope('rows'):
+        trained = tree_scatter(base, idx, trained_rows)
     return safa_server_step(
         base, trained, cache, global_w, completed=completed, picked=picked,
         undrafted=undrafted, deprecated=deprecated, weights=weights,
@@ -664,37 +688,45 @@ def safa_round_sparse_delta(global_w, local_w, cache, agg, *, idx, roles,
     new_cache, new_agg)."""
     check_wire(wire)
     k = idx.shape[0]
-    sync_r = has_role(roles, ROLE_SYNC)
-    com_r = has_role(roles, ROLE_COMMITTED)
-    pick_r = has_role(roles, ROLE_PICKED)
-    und_r = has_role(roles, ROLE_UNDRAFTED)
-    dep_r = has_role(roles, ROLE_DEPRECATED)
-    g_rows = broadcast_global(global_w, k)
-    base_rows = masked_select(sync_r, g_rows, tree_gather(local_w, idx))
-    trained_rows = local_train_fn(base_rows, idx, *train_args)
+    with obs.scope('rows'):
+        sync_r = has_role(roles, ROLE_SYNC)
+        com_r = has_role(roles, ROLE_COMMITTED)
+        pick_r = has_role(roles, ROLE_PICKED)
+        und_r = has_role(roles, ROLE_UNDRAFTED)
+        dep_r = has_role(roles, ROLE_DEPRECATED)
+        g_rows = broadcast_global(global_w, k)
+        base_rows = masked_select(sync_r, g_rows, tree_gather(local_w, idx))
+    with obs.scope('train'):
+        trained_rows = local_train_fn(base_rows, idx, *train_args)
     if wire == 'int8':
         from repro.kernels import ops as kops
-        trained_rows = kops.wire_roundtrip_packed(trained_rows, like=global_w)
-    trained_rows = masked_select(com_r, trained_rows, base_rows)
-    c_rows = tree_gather(cache, idx)
-    w_rows = _slot_weights(idx, weights)
+        with obs.scope('wire'):
+            trained_rows = kops.wire_roundtrip_packed(trained_rows,
+                                                      like=global_w)
+    with obs.scope('aggregate'):
+        trained_rows = masked_select(com_r, trained_rows, base_rows)
+        c_rows = tree_gather(cache, idx)
+        w_rows = _slot_weights(idx, weights)
 
-    def delta(a, new, old):
-        w = w_rows.reshape((-1,) + (1,) * (new.ndim - 1))
-        return a + jnp.sum(
-            (new.astype(jnp.float32) - old.astype(jnp.float32)) * w, axis=0)
+        def delta(a, new, old):
+            w = w_rows.reshape((-1,) + (1,) * (new.ndim - 1))
+            return a + jnp.sum(
+                (new.astype(jnp.float32) - old.astype(jnp.float32)) * w,
+                axis=0)
 
-    # Eq. 6 on the active rows only
-    c1_rows = masked_select(dep_r & ~pick_r, g_rows, c_rows)
-    c1_rows = masked_select(pick_r, trained_rows, c1_rows)
-    # Eq. 7: the full weighted sum moves by the rows that changed
-    agg1 = jax.tree.map(delta, agg, c1_rows, c_rows)
-    new_global = jax.tree.map(lambda a, g: a.astype(g.dtype), agg1, global_w)
-    # Eq. 8: undrafted arrivals enter the cache for the next round
-    c2_rows = masked_select(und_r, trained_rows, c1_rows)
-    new_agg = jax.tree.map(delta, agg1, c2_rows, c1_rows)
-    new_cache = tree_scatter(cache, idx, c2_rows)
-    new_local = tree_scatter(local_w, idx, trained_rows)
+        # Eq. 6 on the active rows only
+        c1_rows = masked_select(dep_r & ~pick_r, g_rows, c_rows)
+        c1_rows = masked_select(pick_r, trained_rows, c1_rows)
+        # Eq. 7: the full weighted sum moves by the rows that changed
+        agg1 = jax.tree.map(delta, agg, c1_rows, c_rows)
+        new_global = jax.tree.map(lambda a, g: a.astype(g.dtype), agg1,
+                                  global_w)
+        # Eq. 8: undrafted arrivals enter the cache for the next round
+        c2_rows = masked_select(und_r, trained_rows, c1_rows)
+        new_agg = jax.tree.map(delta, agg1, c2_rows, c1_rows)
+    with obs.scope('rows'):
+        new_cache = tree_scatter(cache, idx, c2_rows)
+        new_local = tree_scatter(local_w, idx, trained_rows)
     return new_global, new_local, new_cache, new_agg
 
 
@@ -705,12 +737,15 @@ def fedavg_round_sparse(global_w, local_w, *, idx, roles, weights,
     dense server trace.  Returns (new_global, new_local)."""
     check_wire(wire)
     m = weights.shape[0]
-    selected, completed = scatter_masks(
-        idx, roles, m, (SROLE_SELECTED, SROLE_COMPLETED))
-    base = distribute(global_w, local_w, selected)
-    base_rows = tree_gather(base, idx)
-    trained_rows = local_train_fn(base_rows, idx, *train_args)
-    trained = tree_scatter(base, idx, trained_rows)
+    with obs.scope('rows'):
+        selected, completed = scatter_masks(
+            idx, roles, m, (SROLE_SELECTED, SROLE_COMPLETED))
+        base = distribute(global_w, local_w, selected)
+        base_rows = tree_gather(base, idx)
+    with obs.scope('train'):
+        trained_rows = local_train_fn(base_rows, idx, *train_args)
+    with obs.scope('rows'):
+        trained = tree_scatter(base, idx, trained_rows)
     return fedavg_server_step(base, trained, global_w, selected=selected,
                               completed=completed, weights=weights, wire=wire)
 
@@ -726,23 +761,29 @@ def fedavg_round_sparse_delta(global_w, *, idx, roles, weights,
     order.  Returns new_global."""
     check_wire(wire)
     k = idx.shape[0]
-    com_r = has_role(roles, SROLE_COMPLETED) & (idx < weights.shape[0])
-    base_rows = broadcast_global(global_w, k)
-    trained_rows = local_train_fn(base_rows, idx, *train_args)
+    with obs.scope('rows'):
+        com_r = has_role(roles, SROLE_COMPLETED) & (idx < weights.shape[0])
+        base_rows = broadcast_global(global_w, k)
+    with obs.scope('train'):
+        trained_rows = local_train_fn(base_rows, idx, *train_args)
     if wire == 'int8':
         from repro.kernels import ops as kops
-        trained_rows = kops.wire_roundtrip_packed(trained_rows, like=global_w)
-    w_rows = jnp.where(com_r, _slot_weights(idx, weights), 0.0)
-    wsum = jnp.maximum(jnp.sum(w_rows), 1e-12)
-    eff_w = w_rows / wsum
-    any_ok = jnp.sum(com_r) > 0
+        with obs.scope('wire'):
+            trained_rows = kops.wire_roundtrip_packed(trained_rows,
+                                                      like=global_w)
+    with obs.scope('aggregate'):
+        w_rows = jnp.where(com_r, _slot_weights(idx, weights), 0.0)
+        wsum = jnp.maximum(jnp.sum(w_rows), 1e-12)
+        eff_w = w_rows / wsum
+        any_ok = jnp.sum(com_r) > 0
 
-    def red(t, g):
-        w = eff_w.reshape((-1,) + (1,) * (t.ndim - 1))
-        agg = jnp.sum(t.astype(jnp.float32) * w, axis=0)
-        return jnp.where(any_ok, agg, g.astype(jnp.float32)).astype(g.dtype)
+        def red(t, g):
+            w = eff_w.reshape((-1,) + (1,) * (t.ndim - 1))
+            agg = jnp.sum(t.astype(jnp.float32) * w, axis=0)
+            return jnp.where(any_ok, agg,
+                             g.astype(jnp.float32)).astype(g.dtype)
 
-    return jax.tree.map(red, trained_rows, global_w)
+        return jax.tree.map(red, trained_rows, global_w)
 
 
 # -- sparse scan/fleet engines ----------------------------------------------
@@ -911,29 +952,37 @@ def safa_round_sparse_delta_packed(gbuf, lbuf, cbuf, abuf, *, idx, roles,
     sum.  Returns (gbuf', lbuf', cbuf', abuf')."""
     check_wire(wire)
     from repro.kernels import ops as kops
-    com_r = has_role(roles, ROLE_COMMITTED)
-    pick_r = has_role(roles, ROLE_PICKED)
-    und_r = has_role(roles, ROLE_UNDRAFTED)
-    dep_r = has_role(roles, ROLE_DEPRECATED)
-    sync_r = has_role(roles, ROLE_SYNC)
-    w_rows = _slot_weights(idx, weights)
-    l_rows = kops.gather_rows(lbuf, idx)
-    base_rows = jnp.where(sync_r[:, None], gbuf[None].astype(lbuf.dtype),
-                          l_rows)
-    trained = kops.pack_stacked(
-        local_train_fn(kops.unpack_stacked(base_rows, spec), idx,
-                       *train_args), spec)
+    with obs.scope('rows'):
+        com_r = has_role(roles, ROLE_COMMITTED)
+        pick_r = has_role(roles, ROLE_PICKED)
+        und_r = has_role(roles, ROLE_UNDRAFTED)
+        dep_r = has_role(roles, ROLE_DEPRECATED)
+        sync_r = has_role(roles, ROLE_SYNC)
+        w_rows = _slot_weights(idx, weights)
+        l_rows = kops.gather_rows(lbuf, idx)
+        base_rows = jnp.where(sync_r[:, None], gbuf[None].astype(lbuf.dtype),
+                              l_rows)
+        unpacked = kops.unpack_stacked(base_rows, spec)
+    with obs.scope('train'):
+        trained = local_train_fn(unpacked, idx, *train_args)
+    with obs.scope('rows'):
+        trained = kops.pack_stacked(trained, spec)
     if wire == 'int8':
-        q, scales = kops.quantize_packed(trained)
-        ng, na, c2_rows, local_rows = kops.safa_aggregate_packed_q8_rows(
-            q, scales, base_rows, cbuf, gbuf, abuf, idx, pick_r, und_r,
-            dep_r, com_r, w_rows)
+        with obs.scope('wire'):
+            q, scales = kops.quantize_packed(trained)
+        with obs.scope('aggregate'):
+            ng, na, c2_rows, local_rows = kops.safa_aggregate_packed_q8_rows(
+                q, scales, base_rows, cbuf, gbuf, abuf, idx, pick_r, und_r,
+                dep_r, com_r, w_rows)
     else:
-        local_rows = jnp.where(com_r[:, None], trained, base_rows)
-        ng, na, c2_rows = kops.safa_aggregate_packed_rows(
-            cbuf, local_rows, gbuf, abuf, idx, pick_r, und_r, dep_r, w_rows)
-    new_c = kops.scatter_rows(cbuf, idx, c2_rows.astype(cbuf.dtype))
-    new_l = kops.scatter_rows(lbuf, idx, local_rows.astype(lbuf.dtype))
+        with obs.scope('aggregate'):
+            local_rows = jnp.where(com_r[:, None], trained, base_rows)
+            ng, na, c2_rows = kops.safa_aggregate_packed_rows(
+                cbuf, local_rows, gbuf, abuf, idx, pick_r, und_r, dep_r,
+                w_rows)
+    with obs.scope('rows'):
+        new_c = kops.scatter_rows(cbuf, idx, c2_rows.astype(cbuf.dtype))
+        new_l = kops.scatter_rows(lbuf, idx, local_rows.astype(lbuf.dtype))
     return ng.astype(gbuf.dtype), new_l, new_c, na
 
 
@@ -1009,36 +1058,44 @@ def safa_round_sparse_tier(global_w, buf, agg, *, idx, roles, base_src,
     (new_global, new_buf, new_agg)."""
     check_wire(wire)
     k = idx.shape[0]
-    sync_r = has_role(roles, ROLE_SYNC)
-    com_r = has_role(roles, ROLE_COMMITTED)
-    pick_r = has_role(roles, ROLE_PICKED)
-    und_r = has_role(roles, ROLE_UNDRAFTED)
-    dep_r = has_role(roles, ROLE_DEPRECATED)
-    g_rows = broadcast_global(global_w, k)
-    base_rows = masked_select(sync_r, g_rows, tree_gather(buf, base_src))
-    trained_rows = local_train_fn(base_rows, idx, *train_args)
+    with obs.scope('rows'):
+        sync_r = has_role(roles, ROLE_SYNC)
+        com_r = has_role(roles, ROLE_COMMITTED)
+        pick_r = has_role(roles, ROLE_PICKED)
+        und_r = has_role(roles, ROLE_UNDRAFTED)
+        dep_r = has_role(roles, ROLE_DEPRECATED)
+        g_rows = broadcast_global(global_w, k)
+        base_rows = masked_select(sync_r, g_rows, tree_gather(buf, base_src))
+    with obs.scope('train'):
+        trained_rows = local_train_fn(base_rows, idx, *train_args)
     if wire == 'int8':
         from repro.kernels import ops as kops
-        trained_rows = kops.wire_roundtrip_packed(trained_rows, like=global_w)
-    trained_rows = masked_select(com_r, trained_rows, base_rows)
-    c_rows = tree_gather(buf, cache_src)
-    w_rows = _slot_weights(idx, weights)
+        with obs.scope('wire'):
+            trained_rows = kops.wire_roundtrip_packed(trained_rows,
+                                                      like=global_w)
+    with obs.scope('aggregate'):
+        trained_rows = masked_select(com_r, trained_rows, base_rows)
+        c_rows = tree_gather(buf, cache_src)
+        w_rows = _slot_weights(idx, weights)
 
-    def delta(a, new, old):
-        w = w_rows.reshape((-1,) + (1,) * (new.ndim - 1))
-        return a + jnp.sum(
-            (new.astype(jnp.float32) - old.astype(jnp.float32)) * w, axis=0)
+        def delta(a, new, old):
+            w = w_rows.reshape((-1,) + (1,) * (new.ndim - 1))
+            return a + jnp.sum(
+                (new.astype(jnp.float32) - old.astype(jnp.float32)) * w,
+                axis=0)
 
-    c1_rows = masked_select(dep_r & ~pick_r, g_rows, c_rows)
-    c1_rows = masked_select(pick_r, trained_rows, c1_rows)
-    agg1 = jax.tree.map(delta, agg, c1_rows, c_rows)
-    new_global = jax.tree.map(lambda a, g: a.astype(g.dtype), agg1, global_w)
-    c2_rows = masked_select(und_r, trained_rows, c1_rows)
-    new_agg = jax.tree.map(delta, agg1, c2_rows, c1_rows)
-    new_buf = tree_scatter(buf, cache_dst, c2_rows)
-    new_buf = jax.tree.map(
-        lambda b, g: b.at[global_dst].set(g.astype(b.dtype)), new_buf,
-        new_global)
+        c1_rows = masked_select(dep_r & ~pick_r, g_rows, c_rows)
+        c1_rows = masked_select(pick_r, trained_rows, c1_rows)
+        agg1 = jax.tree.map(delta, agg, c1_rows, c_rows)
+        new_global = jax.tree.map(lambda a, g: a.astype(g.dtype), agg1,
+                                  global_w)
+        c2_rows = masked_select(und_r, trained_rows, c1_rows)
+        new_agg = jax.tree.map(delta, agg1, c2_rows, c1_rows)
+    with obs.scope('rows'):
+        new_buf = tree_scatter(buf, cache_dst, c2_rows)
+        new_buf = jax.tree.map(
+            lambda b, g: b.at[global_dst].set(g.astype(b.dtype)), new_buf,
+            new_global)
     return new_global, new_buf, new_agg
 
 
@@ -1095,29 +1152,36 @@ def safa_round_sparse_tier_packed(gbuf, tbuf, abuf, *, idx, roles, base_src,
     (gbuf', tbuf', abuf')."""
     check_wire(wire)
     from repro.kernels import ops as kops
-    sync_r = has_role(roles, ROLE_SYNC)
-    com_r = has_role(roles, ROLE_COMMITTED)
-    pick_r = has_role(roles, ROLE_PICKED)
-    und_r = has_role(roles, ROLE_UNDRAFTED)
-    dep_r = has_role(roles, ROLE_DEPRECATED)
-    w_rows = _slot_weights(idx, weights)
-    b_rows = kops.gather_rows(tbuf, base_src)
-    base_rows = jnp.where(sync_r[:, None], gbuf[None].astype(tbuf.dtype),
-                          b_rows)
-    trained = kops.pack_stacked(
-        local_train_fn(kops.unpack_stacked(base_rows, spec), idx,
-                       *train_args), spec)
+    with obs.scope('rows'):
+        sync_r = has_role(roles, ROLE_SYNC)
+        com_r = has_role(roles, ROLE_COMMITTED)
+        pick_r = has_role(roles, ROLE_PICKED)
+        und_r = has_role(roles, ROLE_UNDRAFTED)
+        dep_r = has_role(roles, ROLE_DEPRECATED)
+        w_rows = _slot_weights(idx, weights)
+        b_rows = kops.gather_rows(tbuf, base_src)
+        base_rows = jnp.where(sync_r[:, None], gbuf[None].astype(tbuf.dtype),
+                              b_rows)
+        unpacked = kops.unpack_stacked(base_rows, spec)
+    with obs.scope('train'):
+        trained = local_train_fn(unpacked, idx, *train_args)
+    with obs.scope('rows'):
+        trained = kops.pack_stacked(trained, spec)
     if wire == 'int8':
-        q, scales = kops.quantize_packed(trained)
-        ng, na, new_t = kops.safa_aggregate_packed_q8_tier_rows(
-            q, scales, base_rows, tbuf, gbuf, abuf, cache_src, cache_dst,
-            pick_r, und_r, dep_r, com_r, w_rows)
+        with obs.scope('wire'):
+            q, scales = kops.quantize_packed(trained)
+        with obs.scope('aggregate'):
+            ng, na, new_t = kops.safa_aggregate_packed_q8_tier_rows(
+                q, scales, base_rows, tbuf, gbuf, abuf, cache_src, cache_dst,
+                pick_r, und_r, dep_r, com_r, w_rows)
     else:
-        local_rows = jnp.where(com_r[:, None], trained, base_rows)
-        ng, na, new_t = kops.safa_aggregate_packed_tier_rows(
-            tbuf, local_rows, gbuf, abuf, cache_src, cache_dst, pick_r,
-            und_r, dep_r, w_rows)
-    new_t = new_t.at[global_dst].set(ng.astype(new_t.dtype))
+        with obs.scope('aggregate'):
+            local_rows = jnp.where(com_r[:, None], trained, base_rows)
+            ng, na, new_t = kops.safa_aggregate_packed_tier_rows(
+                tbuf, local_rows, gbuf, abuf, cache_src, cache_dst, pick_r,
+                und_r, dep_r, w_rows)
+    with obs.scope('rows'):
+        new_t = new_t.at[global_dst].set(ng.astype(new_t.dtype))
     return ng.astype(gbuf.dtype), new_t, na
 
 
@@ -1168,12 +1232,17 @@ def fedasync_round(global_w, local_w, *, committed, order, alphas,
     model.  Shared by the per-round loop engine and the scan body so the
     two stay step-identical.  Returns (new_global, new_local)."""
     m = committed.shape[0]
-    trained = local_train_fn(local_w, *train_args)
-    trained = masked_select(committed, trained, local_w)
-    new_global = fedasync_merge(global_w, trained, order=order, alphas=alphas)
-    # committed clients pull the fresh global model
-    new_local = masked_select(committed, broadcast_global(new_global, m),
-                              masked_select(committed, trained, local_w))
+    with obs.scope('train'):
+        trained = local_train_fn(local_w, *train_args)
+    with obs.scope('rows'):
+        trained = masked_select(committed, trained, local_w)
+    with obs.scope('aggregate'):
+        new_global = fedasync_merge(global_w, trained, order=order,
+                                    alphas=alphas)
+    with obs.scope('rows'):
+        # committed clients pull the fresh global model
+        new_local = masked_select(committed, broadcast_global(new_global, m),
+                                  masked_select(committed, trained, local_w))
     return new_global, new_local
 
 
@@ -1249,16 +1318,21 @@ def weighted_round(global_w, local_w, *, committed, wrow, local_train_fn,
     (new_global, new_local)."""
     check_wire(wire)
     m = committed.shape[0]
-    trained = local_train_fn(local_w, *train_args)
-    trained = masked_select(committed, trained, local_w)
+    with obs.scope('train'):
+        trained = local_train_fn(local_w, *train_args)
+    with obs.scope('rows'):
+        trained = masked_select(committed, trained, local_w)
     uploads = trained
     if wire == 'int8':
         from repro.kernels import ops as kops
-        uploads = kops.wire_roundtrip_packed(trained, like=global_w)
-    new_global = weighted_merge(global_w, uploads, wrow=wrow,
-                                use_kernel=use_kernel)
-    new_local = masked_select(committed, broadcast_global(new_global, m),
-                              trained)
+        with obs.scope('wire'):
+            uploads = kops.wire_roundtrip_packed(trained, like=global_w)
+    with obs.scope('aggregate'):
+        new_global = weighted_merge(global_w, uploads, wrow=wrow,
+                                    use_kernel=use_kernel)
+    with obs.scope('rows'):
+        new_local = masked_select(committed, broadcast_global(new_global, m),
+                                  trained)
     return new_global, new_local
 
 

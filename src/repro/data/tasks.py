@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import optim
+from repro import obs, optim
 from repro.core.federation import Task
 from repro.data import FederatedData
 
@@ -37,8 +37,7 @@ class SupervisedTask(Task):
         self._train_jit = jax.jit(self._train_all)
         self._test_x = jnp.asarray(data.test_x)
         self._test_y = jnp.asarray(data.test_y)
-        self._eval_jit = jax.jit(
-            lambda p, ex, ey: (self.loss_fn(p, ex, ey), self.acc_fn(p, ex, ey)))
+        self._eval_jit = jax.jit(self._eval)
 
     def init_global(self, key):
         return self.init_fn(key)
@@ -78,6 +77,12 @@ class SupervisedTask(Task):
         if '_train_rows_jit' not in self.__dict__:
             self._train_rows_jit = jax.jit(self._train_rows)
         return self._train_rows_jit(params_rows, rows)
+
+    def _eval(self, params, x, y):
+        # inside the jitted function: a scope around the call would not
+        # reach the ops of the compiled program
+        with obs.scope('eval'):
+            return self.loss_fn(params, x, y), self.acc_fn(params, x, y)
 
     def evaluate(self, global_params) -> dict:
         loss, acc = self._eval_jit(global_params, self._test_x, self._test_y)
@@ -252,15 +257,25 @@ def _maxpool2(x):
 
 
 def _cnn_logits(p, x):
-    h = jax.lax.conv_general_dilated(x, p['c1'], (1, 1), 'SAME',
-                                     dimension_numbers=('NHWC', 'HWIO', 'NHWC'))
-    h = _maxpool2(jax.nn.relu(h + p['b1']))
-    h = jax.lax.conv_general_dilated(h, p['c2'], (1, 1), 'SAME',
-                                     dimension_numbers=('NHWC', 'HWIO', 'NHWC'))
-    h = _maxpool2(jax.nn.relu(h + p['b2']))
-    h = h.reshape(h.shape[0], -1)
-    h = jax.nn.relu(h @ p['f1'] + p['fb1'])
-    return h @ p['f2'] + p['fb2']
+    # one name scope per layer; autodiff carries each into its backward
+    # ops as transpose(jvp(cnn.<layer>)), so a trace can time the layers
+    dims = ('NHWC', 'HWIO', 'NHWC')
+    with jax.named_scope('cnn.conv1'):
+        h = jax.lax.conv_general_dilated(x, p['c1'], (1, 1), 'SAME',
+                                         dimension_numbers=dims)
+        h = jax.nn.relu(h + p['b1'])
+    with jax.named_scope('cnn.pool1'):
+        h = _maxpool2(h)
+    with jax.named_scope('cnn.conv2'):
+        h = jax.lax.conv_general_dilated(h, p['c2'], (1, 1), 'SAME',
+                                         dimension_numbers=dims)
+        h = jax.nn.relu(h + p['b2'])
+    with jax.named_scope('cnn.pool2'):
+        h = _maxpool2(h)
+    with jax.named_scope('cnn.fc'):
+        h = h.reshape(h.shape[0], -1)
+        h = jax.nn.relu(h @ p['f1'] + p['fb1'])
+        return h @ p['f2'] + p['fb2']
 
 
 def _cnn_loss(p, x, y):

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
 from repro.core.protocol import AggregationResult
 from repro.kernels import backend
 from repro.kernels.backend import SUBLANES, fit_tile, padded_rows
@@ -466,13 +467,15 @@ def safa_compressed_update(base, trained, cache, global_prev, *, picked,
     if spec is None:
         spec = wire_spec(global_prev)
     _require_f32(spec)
-    q, scales = quantize_packed(pack_stacked(trained, spec))
-    ng, nc, nl = safa_aggregate_packed_q8(
-        q, scales, pack_stacked(base, spec), pack_stacked(cache, spec),
-        pack_global(global_prev, spec), picked, undrafted, deprecated,
-        completed, weights)
-    return (unpack_global(ng, spec), unpack_stacked(nl, spec),
-            unpack_stacked(nc, spec))
+    with obs.scope('wire'):
+        q, scales = quantize_packed(pack_stacked(trained, spec))
+    with obs.scope('aggregate'):
+        ng, nc, nl = safa_aggregate_packed_q8(
+            q, scales, pack_stacked(base, spec), pack_stacked(cache, spec),
+            pack_global(global_prev, spec), picked, undrafted, deprecated,
+            completed, weights)
+        return (unpack_global(ng, spec), unpack_stacked(nl, spec),
+                unpack_stacked(nc, spec))
 
 
 def wire_roundtrip_packed(tree, spec: PackSpec = None, *, like=None):

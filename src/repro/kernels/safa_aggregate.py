@@ -8,8 +8,10 @@ The fused kernel performs all three steps in one pass over parameter tiles
 held in VMEM: per tile it reads cache/trained once, applies the Eq. 6 masks,
 accumulates the Eq. 7 weighted sum, applies the Eq. 8 bypass write, and
 emits the new global tile + new cache tile.  HBM traffic drops from
-~5 model-sized reads + 3 writes to 2 reads + 2 writes (measured by
-``benchmarks/kernels_bench.py``).
+~5 model-sized reads + 3 writes to 2 reads + 2 writes (counted from the
+shapes; the tier-rows kernels record what they move in
+``repro.obs.counters()``, and ``bench/metrics/`` reads the kernels'
+device time and roofline share from a chip trace).
 
 Layout: parameters are flattened to [m, N] (m = clients).  Grid is over N
 tiles; each program instance sees the full clients column for its tile.
@@ -39,12 +41,15 @@ adds a leading fleet dimension to the grid.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
 from repro.kernels import backend
 from repro.kernels.backend import SUBLANES, fit_tile, padded_rows
 from repro.kernels.comm_quant import QBLOCK, scale_spec, scales_to_blocks
@@ -600,29 +605,66 @@ def _tier_rows_kernel(srcs_ref, dsts_ref, buf_ref, trained_ref, global_ref,
     _accumulate(k, agg_ref, new_global_ref, new_agg_ref, w, c0, c1, c2)
 
 
-def _tier_grid(in_specs, buf, *, k: int, tile: int):
+#: explicit DMAs of one tier-rows grid step: ``read_row`` copies the
+#: source row's 8-row group in, ``write_row`` copies the destination
+#: row's group in and back out
+TIER_STEP_DMAS = 3
+
+
+def pipelined_bytes(grid, specs, arrays) -> int:
+    """Bytes the grid pipeline copies for the blocked operands ``arrays``
+    of ``specs``: a block moves whenever its index differs from the one
+    of the step before (steps in grid order, the last axis fastest).
+    Operands left in HBM (``pl.ANY``) move nothing here."""
+    steps = np.indices(grid).reshape(len(grid), -1)
+    total = 0
+    for spec, arr in zip(specs, arrays):
+        if spec.block_shape is None:
+            continue
+        idx = np.stack(np.broadcast_arrays(
+            steps[0], *spec.index_map(*steps))[1:])
+        moves = 1 + int(np.count_nonzero(
+            np.any(idx[:, 1:] != idx[:, :-1], axis=0)))
+        block = math.prod(1 if d is None else d for d in spec.block_shape)
+        total += moves * block * jnp.dtype(arr.dtype).itemsize
+    return total
+
+
+def _tier_grid(name, in_specs, operands, buf, *, k: int, tile: int):
     """Grid spec and output shapes of a tier-rows dispatch over buf
     [R, N]: new_global and new_agg rows, then the buffer itself, which
-    stays in HBM and is written in place."""
+    stays in HBM and is written in place.  ``operands`` are the call's
+    arrays after its two prefetched slot-id vectors, one per spec of
+    ``in_specs``.  Records under ``name`` in ``obs.counters()`` the
+    bytes the call's DMAs move (its explicit row-group copies and its
+    pipelined blocks) and how many explicit DMAs it issues: every slot
+    issues the same, sentinel slots included, so the count is exact."""
     r, np_ = buf.shape
     check_width(np_, tile)
     check_rows(r)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(np_ // tile, k),      # k innermost: agg blocks revisit
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, tile), lambda i, j, *pf: (0, i)),
-            pl.BlockSpec((1, tile), lambda i, j, *pf: (0, i)),
-            pl.BlockSpec(memory_space=pl.ANY),                  # new buf
-        ],
-        scratch_shapes=[pltpu.VMEM((SUBLANES, tile), buf.dtype),
-                        pltpu.SemaphoreType.DMA])
+    grid = (np_ // tile, k)         # k innermost: agg blocks revisit
+    out_specs = [
+        pl.BlockSpec((1, tile), lambda i, j, *pf: (0, i)),
+        pl.BlockSpec((1, tile), lambda i, j, *pf: (0, i)),
+        pl.BlockSpec(memory_space=pl.ANY),                      # new buf
+    ]
     out_shape = [
         jax.ShapeDtypeStruct((1, np_), jnp.float32),
         jax.ShapeDtypeStruct((1, np_), jnp.float32),
         jax.ShapeDtypeStruct((r, np_), buf.dtype),
     ]
+    dmas = TIER_STEP_DMAS * math.prod(grid)
+    group_bytes = SUBLANES * tile * jnp.dtype(buf.dtype).itemsize
+    obs.count(name, dmas=dmas, bytes=dmas * group_bytes
+              + pipelined_bytes(grid, in_specs, operands)
+              + pipelined_bytes(grid, out_specs, out_shape))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=[pltpu.VMEM((SUBLANES, tile), buf.dtype),
+                        pltpu.SemaphoreType.DMA])
     return grid_spec, out_shape
 
 
@@ -647,7 +689,13 @@ def safa_aggregate_packed_tier_rows(buf, trained_rows, global_prev, agg,
         slot_spec(tile),                                        # trained
         *_row_specs(tile, 3),
     ]
-    grid_spec, out_shape = _tier_grid(in_specs, buf, k=k, tile=tile)
+    slots = (srcs.astype(jnp.int32), dsts.astype(jnp.int32))
+    operands = (buf, trained_rows,
+                *_row_args(agg, global_prev,
+                           (picked_r, undrafted_r, deprecated_r), w_rows))
+    grid_spec, out_shape = _tier_grid(
+        'safa_aggregate_packed_tier_rows', in_specs, operands, buf, k=k,
+        tile=tile)
     new_global, new_agg, new_buf = pl.pallas_call(
         _tier_rows_kernel,
         grid_spec=grid_spec,
@@ -656,9 +704,7 @@ def safa_aggregate_packed_tier_rows(buf, trained_rows, global_prev, agg,
         # 2; it aliases the new-buffer output (index 2): written in place
         input_output_aliases={2: 2},
         interpret=backend.interpret(),
-    )(srcs.astype(jnp.int32), dsts.astype(jnp.int32), buf, trained_rows,
-      *_row_args(agg, global_prev, (picked_r, undrafted_r, deprecated_r),
-                 w_rows))
+    )(*slots, *operands)
     return new_global[0], new_agg[0], new_buf
 
 
@@ -698,7 +744,14 @@ def safa_aggregate_packed_q8_tier_rows(q_rows, scales_rows, base_rows, buf,
         pl.BlockSpec(memory_space=pl.ANY),                      # buf
         *_row_specs(tile, 4),
     ]
-    grid_spec, out_shape = _tier_grid(in_specs, buf, k=k, tile=tile)
+    slots = (srcs.astype(jnp.int32), dsts.astype(jnp.int32))
+    operands = (q_rows, scales_to_blocks(scales_rows, tile), base_rows, buf,
+                *_row_args(agg, global_prev,
+                           (picked_r, undrafted_r, deprecated_r,
+                            completed_r), w_rows))
+    grid_spec, out_shape = _tier_grid(
+        'safa_aggregate_packed_q8_tier_rows', in_specs, operands, buf, k=k,
+        tile=tile)
     new_global, new_agg, new_buf = pl.pallas_call(
         _q8_tier_rows_kernel,
         grid_spec=grid_spec,
@@ -707,8 +760,5 @@ def safa_aggregate_packed_q8_tier_rows(q_rows, scales_rows, base_rows, buf,
         # it aliases the new-buffer output (index 2)
         input_output_aliases={5: 2},
         interpret=backend.interpret(),
-    )(srcs.astype(jnp.int32), dsts.astype(jnp.int32), q_rows,
-      scales_to_blocks(scales_rows, tile), base_rows, buf,
-      *_row_args(agg, global_prev,
-                 (picked_r, undrafted_r, deprecated_r, completed_r), w_rows))
+    )(*slots, *operands)
     return new_global[0], new_agg[0], new_buf
