@@ -51,7 +51,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro import obs
 from repro.kernels import backend
-from repro.kernels.backend import SUBLANES, fit_tile, padded_rows
+from repro.kernels.backend import (SUBLANES, VMEM_BUDGET, fit_tile,
+                                   padded_rows)
 from repro.kernels.comm_quant import QBLOCK, scale_spec, scales_to_blocks
 
 DEFAULT_TILE = 2048
@@ -546,7 +547,12 @@ def safa_aggregate_packed_q8_rows(q_rows, scales_rows, base_rows, cache,
 # row into VMEM, and writes its c2 row by a read-modify-write of the
 # destination row's group, both by explicit DMA (a TPU DMA moves whole
 # 8-row tiles).  The buffer's row count must therefore be a multiple of 8
-# (``backend.row_pad``); the engines allocate it so.
+# (``backend.row_pad``); the engines allocate it so.  The copies of a
+# step are started and waited on one after another, so their latency is
+# paid once per column tile and slot: the column tile is the widest that
+# divides the packed width and fits VMEM (``tier_tile``), which moves the
+# same bytes in the fewest copies, and every column's sums over the
+# slots run in the same order at any width.
 
 
 def _group_window(hbm, r, i, tile: int):
@@ -630,6 +636,42 @@ def pipelined_bytes(grid, specs, arrays) -> int:
     return total
 
 
+def tier_tile(n: int, col_bytes: int):
+    """Column tile and compiler params of a tier-rows call over packed
+    width n whose grid steps hold twice ``col_bytes`` bytes of VMEM per
+    lane column (``backend.fit_tile``'s measure).  The widest multiple
+    of the pack granule ``DEFAULT_TILE`` that divides n and fits
+    ``backend.VMEM_BUDGET``: a step's fixed cost and its DMAs' latency
+    are paid once per column tile and slot, its bytes are not, so the
+    widest tile moves the same bytes in the fewest steps and copies.
+    Where no granule multiple fits, ``fit_tile`` narrows the granule."""
+    check_width(n, DEFAULT_TILE)
+    fits = [t for t in range(DEFAULT_TILE, n + 1, DEFAULT_TILE)
+            if n % t == 0 and 2 * col_bytes * t <= VMEM_BUDGET]
+    return fit_tile(max(fits, default=DEFAULT_TILE), col_bytes)
+
+
+#: VMEM the tier-rows bodies' temporaries take, in (8, tile) buffer groups:
+#: the v5e compiler asks ~209 (f32) and ~225 (int8 wire) bytes a lane
+#: column at tiles of 24,576-73,728 lanes, of which the blocks and the
+#: group scratch take ~130-145
+TIER_TEMP_GROUPS = 3
+
+
+def _tier_width(buf, tile, block_bytes: int):
+    """``(tile, None)`` for a tile given, else ``tier_tile``'s choice for
+    buf's width.  Per lane column a step holds its pipelined blocks
+    twice: ``block_bytes`` of the slot's groups, and the global and agg
+    rows in and out (one-row f32 blocks, laid out in (1, 128) tiles);
+    and once, so at half their bytes in ``fit_tile``'s measure, the
+    ``(8, tile)`` group scratch and the body's temporaries."""
+    if tile is not None:
+        return tile, None
+    group = SUBLANES * jnp.dtype(buf.dtype).itemsize
+    return tier_tile(buf.shape[1], block_bytes + 4 * 4
+                     + (1 + TIER_TEMP_GROUPS) * group // 2)
+
+
 def _tier_grid(name, in_specs, operands, buf, *, k: int, tile: int):
     """Grid spec and output shapes of a tier-rows dispatch over buf
     [R, N]: new_global and new_agg rows, then the buffer itself, which
@@ -672,7 +714,7 @@ def _tier_grid(name, in_specs, operands, buf, *, k: int, tile: int):
 def safa_aggregate_packed_tier_rows(buf, trained_rows, global_prev, agg,
                                     srcs, dsts, picked_r, undrafted_r,
                                     deprecated_r, w_rows, *,
-                                    tile: int = DEFAULT_TILE):
+                                    tile: int | None = None):
     """Slot-indirected Eq. 6-8 with the cache write-back fused in place.
 
     buf: [capacity+1, N] tier value buffer (trailing scratch row; rows
@@ -681,9 +723,11 @@ def safa_aggregate_packed_tier_rows(buf, trained_rows, global_prev, agg,
     agg: [N]; srcs/dsts: [K] int32 slot ids (cache-read / cache-write,
     scratch == discard); roles/weights as in
     ``safa_aggregate_packed_rows``.  The buffer input aliases the new-
-    buffer output, so untouched slots persist with zero traffic.  Returns
-    (new_global [N] f32, new_agg [N] f32, new_buf [capacity+1, N])."""
+    buffer output, so untouched slots persist with zero traffic.  ``tile``
+    defaults to ``tier_tile``'s choice for N.  Returns (new_global [N]
+    f32, new_agg [N] f32, new_buf [capacity+1, N])."""
     k, _ = trained_rows.shape
+    tile, params = _tier_width(buf, tile, 4 * SUBLANES)         # trained
     in_specs = [
         pl.BlockSpec(memory_space=pl.ANY),                      # buf
         slot_spec(tile),                                        # trained
@@ -703,6 +747,7 @@ def safa_aggregate_packed_tier_rows(buf, trained_rows, global_prev, agg,
         # operands 0/1 are the prefetched slot ids, so buf is input index
         # 2; it aliases the new-buffer output (index 2): written in place
         input_output_aliases={2: 2},
+        compiler_params=params,
         interpret=backend.interpret(),
     )(*slots, *operands)
     return new_global[0], new_agg[0], new_buf
@@ -732,13 +777,16 @@ def safa_aggregate_packed_q8_tier_rows(q_rows, scales_rows, base_rows, buf,
                                        global_prev, agg, srcs, dsts,
                                        picked_r, undrafted_r, deprecated_r,
                                        completed_r, w_rows, *,
-                                       tile: int = DEFAULT_TILE):
+                                       tile: int | None = None):
     """int8-wire variant of ``safa_aggregate_packed_tier_rows``: uploads
     arrive as the wire format and dequantise in-register; crashed slots
     fall back to base_rows.  No local output exists — tier local state is
     virtual (base rows are always version snapshots).  Returns
     (new_global [N] f32, new_agg [N] f32, new_buf [capacity+1, N])."""
     k, _ = q_rows.shape
+    # int8 q and f32 base groups, and the scales of one group
+    tile, params = _tier_width(buf, tile, padded_rows(SUBLANES, 1)
+                               + 4 * SUBLANES + -(-4 * SUBLANES // QBLOCK))
     in_specs = [
         *_q8_slot_specs(tile),
         pl.BlockSpec(memory_space=pl.ANY),                      # buf
@@ -759,6 +807,7 @@ def safa_aggregate_packed_q8_tier_rows(q_rows, scales_rows, base_rows, buf,
         # operands 0/1 are prefetched slot ids, so buf is input index 5;
         # it aliases the new-buffer output (index 2)
         input_output_aliases={5: 2},
+        compiler_params=params,
         interpret=backend.interpret(),
     )(*slots, *operands)
     return new_global[0], new_agg[0], new_buf

@@ -2,10 +2,12 @@
 recording, phase scopes in the op metadata of the compiled round
 programs, the tier-rows kernels' traffic counter, and the spans of
 precompute, run preparation, segments and evaluation."""
+import functools
 import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro import api, fedsim, obs
@@ -171,48 +173,114 @@ SLOT_IDS = (jax.ShapeDtypeStruct((K,), jnp.int32),) * 2
 ROLE = jax.ShapeDtypeStruct((K,), jnp.bool_)
 
 
-def _f32_call():
-    return (lambda *a: sa.safa_aggregate_packed_tier_rows(*a, tile=128),
-            (_f32(R, N), _f32(K, N), _f32(N), _f32(N), *SLOT_IDS,
-             *(ROLE,) * 3, _f32(K)))
+def _f32_args(n):
+    return (_f32(R, n), _f32(K, n), _f32(n), _f32(n), *SLOT_IDS,
+            *(ROLE,) * 3, _f32(K))
 
 
-def _q8_call():
-    return (lambda *a: sa.safa_aggregate_packed_q8_tier_rows(*a, tile=128),
-            (jax.ShapeDtypeStruct((K, N), jnp.int8), _f32(K, N // 128),
-             _f32(K, N), _f32(R, N), _f32(N), _f32(N), *SLOT_IDS,
-             *(ROLE,) * 4, _f32(K)))
+def _q8_args(n):
+    return (jax.ShapeDtypeStruct((K, n), jnp.int8), _f32(K, n // 128),
+            _f32(K, n), _f32(R, n), _f32(n), _f32(n), *SLOT_IDS,
+            *(ROLE,) * 4, _f32(K))
 
 
-# Hand counts for K=3 slots, N=2 tiles of 128 lanes, a 16-row buffer:
-# a (2, 3) grid of 6 steps, each with three explicit DMAs of one 8-row
-# f32 group (4,096 bytes): 73,728 bytes.  Pipelined blocks move once
-# per column tile where their index follows the tile (a slot's 8-row
-# group, since K < 8; the global and agg rows in and out) and once in
-# all where it does not (the [K, 1] role and weight columns).
+WRAPPERS = {
+    'safa_aggregate_packed_tier_rows': _f32_args,
+    'safa_aggregate_packed_q8_tier_rows': _q8_args,
+}
+
+
+# Hand counts for K=3 slots over a 16-row buffer N=256 wide, in column
+# tiles of 128 (a (2, 3) grid of 6 steps) or of 256 (a (1, 3) grid of
+# 3 steps).  Each step issues three explicit DMAs of one 8-row f32
+# group, 4,096 bytes at tile 128 and 8,192 at 256: 73,728 bytes either
+# way.  Pipelined blocks move once per column tile where their index
+# follows the tile (a slot's 8-row group, since K < 8; the global and
+# agg rows in and out) and once in all where it does not (the [K, 1]
+# role and weight columns), so they too move the same bytes at either
+# tile:
 #   f32:  trained 2 x 4,096; rows in and out 4 x 2 x 512;
 #         3 roles + weights 4 x 32                      -> 86,144
 #   int8: q 2 x 1,024; scales 2 x 32; base 2 x 4,096;
 #         rows 4 x 2 x 512; 4 roles + weights 5 x 32     -> 88,288
 COUNTS = {
-    'safa_aggregate_packed_tier_rows': (_f32_call, 86_144),
-    'safa_aggregate_packed_q8_tier_rows': (_q8_call, 88_288),
+    'safa_aggregate_packed_tier_rows': 86_144,
+    'safa_aggregate_packed_q8_tier_rows': 88_288,
 }
 
 
-@pytest.mark.parametrize('wrapper', sorted(COUNTS))
-def test_tier_counter_matches_a_hand_count(monkeypatch, wrapper):
-    call, want_bytes = COUNTS[wrapper]
-    fn, args = call()
+@pytest.mark.parametrize('wrapper,tile,dmas', [
+    *(pytest.param(w, 128, 18, id=w) for w in sorted(COUNTS)),
+    *(pytest.param(w, 256, 9, id=f'{w}-tile256') for w in sorted(COUNTS)),
+])
+def test_tier_counter_matches_a_hand_count(monkeypatch, wrapper, tile,
+                                           dmas):
+    fn = functools.partial(getattr(sa, wrapper), tile=tile)
     copies = []
     real_copy = sa._copy
     monkeypatch.setattr(sa, '_copy',
                         lambda *a: copies.append(1) or real_copy(*a))
     jax.clear_caches()
-    jax.eval_shape(fn, *args)
-    assert obs.counters()[wrapper] == {'dmas': 18, 'bytes': want_bytes}
+    jax.eval_shape(fn, *WRAPPERS[wrapper](N))
+    assert obs.counters()[wrapper] == {'dmas': dmas,
+                                       'bytes': COUNTS[wrapper]}
     # the kernel body issues TIER_STEP_DMAS copies in each grid step
     assert len(copies) == sa.TIER_STEP_DMAS == 3
+
+
+#: cache-read and cache-write slots of a round: disjoint, as the host
+#: allocator makes them, but for the scratch row 15 that inert slots share
+SRCS, DSTS = [0, 9, 15], [4, 10, 15]
+
+
+def _values(structs, key):
+    """Arrays for ``structs``: the slot ids above, then random values."""
+    slots = iter((SRCS, DSTS))
+    out = []
+    for i, st in enumerate(structs):
+        k = jax.random.fold_in(key, i)
+        if st.dtype == jnp.int32:
+            out.append(jnp.asarray(next(slots), jnp.int32))
+        elif st.dtype == jnp.bool_:
+            out.append(jax.random.bernoulli(k, 0.5, st.shape))
+        elif st.dtype == jnp.int8:
+            out.append(jax.random.randint(k, st.shape, -127, 128, jnp.int8))
+        else:
+            out.append(jax.random.uniform(k, st.shape, jnp.float32, -1, 1))
+    return out
+
+
+@pytest.mark.parametrize('wrapper', sorted(COUNTS))
+def test_tier_default_tile_is_wide_and_bitwise_the_narrow_one(wrapper):
+    """By default the tile is ``tier_tile``'s widest choice.  Every
+    column's adds over the slots run in the same order at any width, so
+    the outputs are bit for bit those of tile 128; the same bytes move,
+    in fewer DMAs by the ratio of the widths."""
+    n = 2 * sa.DEFAULT_TILE
+    args = _values(WRAPPERS[wrapper](n), jax.random.PRNGKey(15))
+    fn = getattr(sa, wrapper)
+    jax.clear_caches()
+    narrow = fn(*args, tile=128)
+    narrow_count = obs.counters()[wrapper]
+    wide = fn(*args)
+    wide_count = obs.counters()[wrapper]
+    for got, want in zip(wide, narrow):     # new_global, new_agg, new_buf
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert wide_count['bytes'] == narrow_count['bytes']
+    assert narrow_count['dmas'] == wide_count['dmas'] * n // 128
+
+
+# xdevice_1m's packed width is 684 granules of 2048 lanes (684 = 4 x 9 x
+# 19); the paper CNN's is 167, a prime
+@pytest.mark.parametrize('n,tile', [(1_400_832, 19 * 2048), (342_016, 2048)])
+@pytest.mark.parametrize('wrapper', sorted(COUNTS))
+def test_tier_tile_at_the_benchmark_widths(wrapper, n, tile):
+    """The tile a tier-rows call takes by default, read from its DMA
+    count: three a step over an (n / tile, K) grid."""
+    jax.clear_caches()
+    jax.eval_shape(getattr(sa, wrapper), *WRAPPERS[wrapper](n))
+    assert obs.counters()[wrapper]['dmas'] == \
+        sa.TIER_STEP_DMAS * (n // tile) * K
 
 
 def test_pipelined_bytes_follow_block_index_changes():

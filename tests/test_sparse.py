@@ -28,8 +28,8 @@ from repro.core import api, federation, protocol, selection
 from repro.data import make_regression, partition
 from repro.data.tasks import regression_task
 from repro.fedsim import FLEnv
-from repro.kernels import ops
-from repro.kernels.backend import row_pad
+from repro.kernels import ops, safa_aggregate
+from repro.kernels.backend import VMEM_BUDGET, row_pad
 
 M = 24
 BASE = dict(m=M, crash_prob=0.3, dataset_size=480, batch_size=10,
@@ -528,6 +528,29 @@ class TestRowsKernels:
         np.testing.assert_allclose(np.asarray(c2), c2_w, rtol=1e-6,
                                    atol=0)
 
+
+GRANULE = safa_aggregate.DEFAULT_TILE
+
+
+@pytest.mark.parametrize('col_bytes', [16, 112, 145, 1024])
+@pytest.mark.parametrize('granules', [1, 2, 3, 167, 684, 720])
+def test_tier_tile_is_the_widest_granule_multiple_that_fits(granules,
+                                                            col_bytes):
+    n = granules * GRANULE
+    tile, params = safa_aggregate.tier_tile(n, col_bytes)
+    fits = lambda t: 2 * col_bytes * t <= VMEM_BUDGET
+    assert n % tile == 0 and tile % GRANULE == 0
+    assert fits(tile) and params is None
+    wider = range(tile + GRANULE, n + 1, GRANULE)
+    assert not any(n % t == 0 and fits(t) for t in wider)
+
+
+def test_tier_tile_narrows_the_granule_where_none_fits():
+    col_bytes = VMEM_BUDGET // GRANULE          # the granule needs twice
+    tile, params = safa_aggregate.tier_tile(167 * GRANULE, col_bytes)
+    assert (tile, params) == (GRANULE // 2, None)
+    with pytest.raises(ValueError, match='pad_to'):
+        safa_aggregate.tier_tile(GRANULE + 128, 16)
 
 # ---------------------------------------------------------------------------
 # pack_spec validation

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import obs
 from repro.kernels import comm_quant, ops, safa_aggregate
 
 #: PAPER_TASKS['task2_cnn']: clients, and the paper CNN's packed width
@@ -23,6 +24,9 @@ M_SVM, N_SVM = 500, 2048
 #: tier width of the task2 round: K active slots over a value buffer of
 #: R rows (capacity + 1, padded to whole 8-row groups)
 K, R = 40, 64
+#: xdevice_1m's lag-tier round: K slots over a 1.4M-wide pack, and a
+#: value buffer of the cell's capacity (410) + 1 rows, padded to 8
+K_1M, N_1M, R_1M = 251, 1_400_832, 416
 
 F32, I8, I32, BOOL = jnp.float32, jnp.int8, jnp.int32, jnp.bool_
 
@@ -109,10 +113,31 @@ def test_rows_kernels_tier_width(compile_for_tpu, kernel):
     assert 'tpu_custom_call' in text
 
 
+def _tier_operands(wrapper, k, n, r):
+    slots = [((k,), I32)] * 2
+    if wrapper == 'safa_aggregate_packed_tier_rows':
+        return [((r, n), F32), ((k, n), F32), ((n,), F32), ((n,), F32),
+                *slots, *[((k,), BOOL)] * 3, ((k,), F32)]
+    return [((k, n), I8), ((k, n // comm_quant.QBLOCK), F32), ((k, n), F32),
+            ((r, n), F32), ((n,), F32), ((n,), F32), *slots,
+            *[((k,), BOOL)] * 4, ((k,), F32)]
+
+
 def test_tier_rows_tier_width(compile_for_tpu):
-    text = compile_for_tpu(
-        safa_aggregate.safa_aggregate_packed_tier_rows,
-        ((R, N), F32), ((K, N), F32), ((N,), F32), ((N,), F32),
-        ((K,), I32), ((K,), I32), ((K,), BOOL), ((K,), BOOL), ((K,), BOOL),
-        ((K,), F32))
+    wrapper = 'safa_aggregate_packed_tier_rows'
+    text = compile_for_tpu(getattr(safa_aggregate, wrapper),
+                           *_tier_operands(wrapper, K, N, R))
     assert 'tpu_custom_call' in text
+
+
+@pytest.mark.parametrize('wrapper', ['safa_aggregate_packed_tier_rows',
+                                     'safa_aggregate_packed_q8_tier_rows'])
+def test_tier_rows_xdevice_width(compile_for_tpu, wrapper):
+    """At xdevice_1m's width the default tile is wider than the pack
+    granule, and the compiler takes its blocks into VMEM."""
+    text = compile_for_tpu(getattr(safa_aggregate, wrapper),
+                           *_tier_operands(wrapper, K_1M, N_1M, R_1M))
+    assert 'tpu_custom_call' in text
+    steps = obs.counters()[wrapper]['dmas'] // safa_aggregate.TIER_STEP_DMAS
+    tile = N_1M * K_1M // steps
+    assert tile > safa_aggregate.DEFAULT_TILE and N_1M % tile == 0
