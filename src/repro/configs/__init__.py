@@ -1,5 +1,6 @@
 """Architecture registry: ``get_config('<arch-id>')`` for the 10 assigned
-architectures, plus input-shape definitions and paper-task FL settings."""
+architectures and Granite 4.0-H Micro (federated LoRA fine-tuning), plus
+input-shape definitions and paper-task FL settings."""
 from __future__ import annotations
 
 import dataclasses
@@ -18,6 +19,7 @@ ARCH_IDS = [
     'qwen3-1.7b',
     'mamba2-130m',
     'whisper-medium',
+    'granite-4.0-h-micro',
 ]
 
 

@@ -41,6 +41,7 @@ over this module and emit ``DeprecationWarning``.
 """
 from __future__ import annotations
 
+import copy
 import dataclasses
 import hashlib
 from typing import Any, Callable, Optional
@@ -393,11 +394,13 @@ class _RunState:
     under ``use_kernel='packed'``, ``packed`` — the (global, local,
     cache, agg) pack-buffer carry with layout ``spec`` (static, rebuilt
     on resume) that replaces the local/cache/agg trees entirely."""
-    __slots__ = ('global_w', 'local_w', 'cache', 'agg', 'packed', 'spec')
+    __slots__ = ('global_w', 'local_w', 'cache', 'agg', 'packed', 'spec',
+                 'operands')
 
     def __init__(self, global_w=None, local_w=None, cache=None):
         self.global_w, self.local_w, self.cache = global_w, local_w, cache
         self.agg, self.packed, self.spec = None, None, None
+        self.operands = None        # Task.operands(): arguments, not state
 
     def tree(self):
         t = {'global': self.global_w, 'local': self.local_w}
@@ -556,6 +559,50 @@ def _resolve_member(mem: SweepMember, *, pdef: ProtocolDef, task,
             f'(EnvSpec fields, e.g. crash_prob/traces/draw_seed)')
     return dataclasses.replace(mem, env=_realize_env(env, task=task, ex=ex),
                                overrides=(ov or None))
+
+
+def _operands_of(task):
+    """``task.operands()`` (``Task.operands``), looked up through a
+    wrapper that delegates to the task it holds as ``.task``."""
+    while task is not None:
+        fn = getattr(task, 'operands', None)
+        if fn is not None:
+            return fn()
+        task = getattr(task, 'task', None)
+    return None
+
+
+class _WithOperands:
+    """A task whose training takes its operands (``Task.operands``) as a
+    third argument, with them bound: ``local_train(stacked, round_idx)``."""
+
+    def __init__(self, task, operands):
+        self._task, self._operands = task, operands
+
+    def local_train(self, stacked, round_idx):
+        return self._task.local_train(stacked, round_idx, self._operands)
+
+
+def _operand_train_fn(task):
+    """``task``'s training as the engine calls it when the task has
+    operands: ``train(stacked, round_idx, operands)``.  A task that owns
+    them takes them itself.  A wrapper that holds one as ``.task`` and
+    forwards only ``(stacked, round_idx)`` to it (a harness's adapter)
+    runs as it is, around the task with the operands bound: a shallow
+    copy of it, made while the segment is traced, holds that instead."""
+    if getattr(task, 'operands', None) is not None:
+        return task.local_train
+
+    def bind(t, operands):
+        if getattr(t, 'operands', None) is not None:
+            return _WithOperands(t, operands)
+        outer = copy.copy(t)
+        outer.task = bind(t.task, operands)
+        return outer
+
+    def train(stacked, round_idx, operands):
+        return bind(task, operands).local_train(stacked, round_idx)
+    return train
 
 
 def _task_fp(task) -> str:
@@ -723,7 +770,8 @@ def _safa_scan_segment(st, seg, weights, train_fn, ex):
     if ex.schedule == 'dense':
         st.global_w, st.local_w, st.cache = protocol.safa_run_scan(
             st.global_w, st.local_w, st.cache, seg, weights,
-            local_train_fn=train_fn, use_kernel=ex.use_kernel, wire=ex.wire)
+            local_train_fn=train_fn, use_kernel=ex.use_kernel, wire=ex.wire,
+            operands=st.operands)
     elif ex.schedule == 'sparse':
         st.global_w, st.local_w, st.cache = protocol.safa_run_scan_sparse(
             st.global_w, st.local_w, st.cache, seg, weights,
@@ -1209,6 +1257,7 @@ class CompiledRunner:
         self.exp = exp
         self._pdef = exp._pdef
         self._dev = None            # cached device-resident schedule
+        self._operand_train = None  # _operand_train_fn, made once
         #: after a fleet-engine ``run_sweep``: the members' final global
         #: models stacked [S', ...], device-resident with the placement
         #: the sweep ran on (sharded over the fleet axis across devices;
@@ -1240,10 +1289,18 @@ class CompiledRunner:
             or ex.schedule == 'sparse_tier'
 
     def _train_fn(self, task):
+        quantized = getattr(self.exp.protocol, 'quantize_uploads', False)
+        if _operands_of(task) is not None:
+            # one callable a runner, so that every run hits its compile
+            if self._operand_train is None:
+                fn = _operand_train_fn(task)
+                self._operand_train = (federation._quantized_train_fn(fn)
+                                       if quantized else fn)
+            return self._operand_train
         if self.exp.exec.schedule != 'dense':
             # rows-train contract: (params_rows, rows, round_idx)
             return task.local_train_rows
-        if getattr(self.exp.protocol, 'quantize_uploads', False):
+        if quantized:
             return federation._quantized_train_fn(task.local_train)
         return task.local_train
 
@@ -1265,9 +1322,19 @@ class CompiledRunner:
             raise ValueError('numeric run needs a Task '
                              '(or ExecSpec(numeric=False))')
 
+        operands = _operands_of(exp.task)
+        if operands is not None and (
+                self._pdef.name != 'safa' or ex.schedule != 'dense'
+                or engine != 'scan'):
+            raise ValueError(
+                'a task with device operands (Task.operands) runs on the '
+                "dense SAFA scan engine only (schedule='dense', "
+                f"engine='scan'); got {self._pdef.name!r}, "
+                f'schedule={ex.schedule!r}, engine={engine!r}')
         with obs.span('run.prepare'):
             st = _init_state(exp.task, exp.env.m, exp.seed,
                              self._pdef.uses_cache, self._stateless(ex))
+            st.operands = operands
             weights_j = jnp.asarray(exp.env.weights)
             if self._pdef.prepare_state is not None:
                 self._pdef.prepare_state(st, weights_j, ex, False, sched)
@@ -1338,6 +1405,9 @@ class CompiledRunner:
             members, tasks = list(members), None
         if not members:
             raise ValueError('empty sweep')
+        if _operands_of(exp.task) is not None:
+            raise ValueError('a task with device operands (Task.operands) '
+                             'runs through run(), not run_sweep()')
         # resolve declarative member envs up front: split env-field
         # overrides from protocol overrides, apply them to the EnvSpec,
         # and build each member its own Env (one fleet dispatch may then

@@ -93,6 +93,14 @@ class Task:
     def evaluate(self, global_params) -> dict:
         raise NotImplementedError
 
+    def operands(self):
+        """Device arrays training reads but does not train (frozen
+        weights, client data), as a pytree, or None.  The dense SAFA scan
+        engine passes them to its compiled segment as arguments and on to
+        ``local_train(stacked, round_idx, operands)``, so a large operand
+        never becomes a program constant."""
+        return None
+
 
 class _NumericState:
     def __init__(self, task: Task, m: int, seed: int):

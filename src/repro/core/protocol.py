@@ -27,7 +27,6 @@ import jax.numpy as jnp
 
 from repro import obs
 
-
 def _bmask(mask, leaf):
     """Broadcast a [m] client mask against a [m, ...] leaf."""
     return mask.reshape(mask.shape + (1,) * (leaf.ndim - 1))
@@ -272,18 +271,23 @@ def _safa_scan(global_w, local_w, cache, schedule, weights, local_train_fn,
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2),
                    static_argnames=('local_train_fn', 'use_kernel', 'wire'))
 def safa_run_scan(global_w, local_w, cache, schedule: RoundSchedule, weights,
-                  *, local_train_fn, use_kernel=False, wire='f32'):
+                  *, local_train_fn, use_kernel=False, wire='f32',
+                  operands=None):
     """Run ``k = len(schedule.round_idx)`` SAFA rounds as one compiled scan.
 
     Bit-identical to ``k`` per-round ``safa_round`` dispatches: the scan
     body is the same trace, compiled once.  The carry is donated, so the
     caller's buffers are reused in place across the whole run.
     ``wire='int8'`` compiles the compressed-wire round body — 2 kernel
-    dispatches per round inside the one scanned program.
+    dispatches per round inside the one scanned program.  ``operands``
+    (the task's ``Task.operands()``) are arguments of the program, handed
+    to every train call after the round index
+    (``local_train_fn(base, round_idx, operands)``).
     Returns (new_global, new_local, new_cache).
     """
     return _safa_scan(global_w, local_w, cache, schedule, weights,
-                      local_train_fn, use_kernel, wire)
+                      local_train_fn, use_kernel, wire,
+                      train_extra=() if operands is None else (operands,))
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2),

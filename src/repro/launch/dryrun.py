@@ -166,7 +166,9 @@ def main(argv=None):
 
     combos = []
     if args.all:
-        for a in ARCH_IDS:
+        # Granite trains through the federated LoRA task
+        # (repro.data.lm_task), not the production-mesh steps lowered here
+        for a in (a for a in ARCH_IDS if get_config(a).family != 'granite'):
             for s in INPUT_SHAPES:
                 if shape_supported(a, s):
                     combos.append((a, s))

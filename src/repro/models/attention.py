@@ -26,19 +26,21 @@ def _block_mask(q_pos, k_pos, causal: bool, window: Optional[int]):
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, q_positions=None,
-                    k_positions=None, q_block=512, kv_block=512, kv_valid=None):
+                    k_positions=None, q_block=512, kv_block=512, kv_valid=None,
+                    scale=None):
     """Online-softmax attention.
 
     q: [B, Sq, H, D];  k, v: [B, Sk, KH, D]  (GQA: H % KH == 0).
     window: sliding-window size (keys with q_pos - k_pos >= window masked).
     kv_valid: optional scalar/int count of valid kv entries (decode caches).
+    scale: the score scale, D ** -0.5 unless given.
     Returns [B, Sq, H, D].
     """
     B, Sq, H, D = q.shape
     _, Sk, KH, _ = k.shape
     assert H % KH == 0
     G = H // KH
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
 
     if q_positions is None:
         q_positions = jnp.arange(Sq, dtype=jnp.int32)
@@ -130,20 +132,22 @@ def attention_ref(q, k, v, *, causal=True, window=None, q_positions=None,
 
 
 def decode_attention(q, k_cache, v_cache, cache_len, *, window=None,
-                     cache_positions=None):
+                     cache_positions=None, scale=None):
     """Single-step decode attention.
 
     q: [B, 1, H, D]; caches: [B, S, KH, D]; cache_len: int32 scalar — number
     of valid entries.  ``cache_positions`` supports ring-buffer (SWA) caches
-    where slot index != token position; defaults to arange.
+    where slot index != token position; defaults to arange.  ``scale``:
+    the score scale, D ** -0.5 unless given.
     """
     B, _, H, D = q.shape
     _, S, KH, _ = k_cache.shape
     G = H // KH
+    scale = D ** -0.5 if scale is None else scale
     if cache_positions is None:
         cache_positions = jnp.arange(S, dtype=jnp.int32)[None, :] * jnp.ones((B, 1), jnp.int32)
     q_pos = jnp.asarray(cache_len, jnp.int32) - 1  # position of the new token
-    qf = (q.astype(jnp.float32) * (D ** -0.5)).reshape(B, KH, G, D)
+    qf = (q.astype(jnp.float32) * scale).reshape(B, KH, G, D)
     s = jnp.einsum('bhgd,bkhd->bhgk', qf, k_cache.astype(jnp.float32))
     valid = (cache_positions >= 0) & (cache_positions < cache_len)
     if window is not None:

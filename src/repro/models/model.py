@@ -51,5 +51,8 @@ class Model:
         return dec.prefill(params, cache, tokens, self.cfg)
 
 
-def build_model(cfg: ModelConfig) -> Model:
+def build_model(cfg: ModelConfig):
+    if cfg.family == 'granite':
+        from repro.models import granite
+        return granite.Model(cfg)
     return Model(cfg)

@@ -24,13 +24,16 @@ def segsum(x):
     return jnp.where(mask, d, -jnp.inf)
 
 
-def ssd_chunked(x, dt, A, B, C, chunk=128, initial_state=None):
+def ssd_chunked(x, dt, A, B, C, chunk=128, initial_state=None,
+                precision=None):
     """SSD scan in chunked dual form.
 
     x: [b, s, h, p]   inputs per head
     dt: [b, s, h]     softplus'd step sizes
     A: [h]            negative per-head decay rates (A = -exp(A_log))
     B, C: [b, s, n]   (single group, broadcast over heads)
+    precision: of the float32 contractions (``jax.lax.Precision``); the
+    backend's default unless given, which on a TPU is one bfloat16 pass.
     Returns (y [b, s, h, p], final_state [b, h, p, n]).
     """
     b, s, h, p = x.shape
@@ -52,11 +55,14 @@ def ssd_chunked(x, dt, A, B, C, chunk=128, initial_state=None):
     dAc = jnp.cumsum(dA, axis=2)               # within-chunk cumsum
     # 1. intra-chunk (diagonal blocks)
     Lmat = jnp.exp(segsum(dA.transpose(0, 1, 3, 2)))        # [b,L,h,c,c]
-    scores = jnp.einsum('blin,bljn->blij', Cb, Bb)          # [b,L,c,c]
-    y_diag = jnp.einsum('blij,blhij,bljh,bljhp->blihp', scores, Lmat, dtb, xb)
+    scores = jnp.einsum('blin,bljn->blij', Cb, Bb,
+                        precision=precision)                 # [b,L,c,c]
+    y_diag = jnp.einsum('blij,blhij,bljh,bljhp->blihp', scores, Lmat, dtb, xb,
+                        precision=precision)
     # 2. chunk-final states
     decay_states = jnp.exp(dAc[:, :, -1:, :] - dAc)          # [b,L,c,h]
-    states = jnp.einsum('blcn,blch,blch,blchp->blhpn', Bb, decay_states, dtb, xb)
+    states = jnp.einsum('blcn,blch,blch,blchp->blhpn', Bb, decay_states, dtb,
+                        xb, precision=precision)
     # 3. inter-chunk recurrence
     chunk_decay = jnp.exp(dAc[:, :, -1, :])                  # [b,L,h]
 
@@ -76,7 +82,8 @@ def ssd_chunked(x, dt, A, B, C, chunk=128, initial_state=None):
     prev_states = prev_states.transpose(1, 0, 2, 3, 4)       # [b,L,h,p,n]
     # 4. inter-chunk outputs
     state_decay_out = jnp.exp(dAc)                           # [b,L,c,h]
-    y_off = jnp.einsum('blcn,blhpn,blch->blchp', Cb, prev_states, state_decay_out)
+    y_off = jnp.einsum('blcn,blhpn,blch->blchp', Cb, prev_states,
+                       state_decay_out, precision=precision)
     y = (y_diag + y_off).reshape(b, -1, h, p)[:, :s]
     return y.astype(x.dtype), final
 
@@ -149,23 +156,36 @@ def _causal_conv(xbc, w, b):
     return jax.nn.silu(out + b[None, None, :])
 
 
-def apply_mamba_block(p, x, *, d_state, headdim, chunk=128, expand=2):
+def _einsum_dense(name, x, w):  # noqa: ARG001
+    return jnp.einsum('...d,de->...e', x, w)
+
+
+def apply_mamba_block(p, x, *, d_state, headdim, chunk=128, expand=2,
+                      eps=1e-6, dense=_einsum_dense, ssd_precision=None):
+    """The Mamba2 mixer.  ``dense(name, x, w)`` computes the projections
+    ``in_proj`` and ``out_proj`` (a model may add adapters or change
+    their precision); ``eps`` is the gated RMSNorm's; ``ssd_precision``
+    that of ``ssd_chunked``'s contractions."""
     bsz, s, d_model = x.shape
     d_inner = expand * d_model
     n_heads = d_inner // headdim
-    zxbcdt = jnp.einsum('bsd,de->bse', x, p['in_proj'])
+    zxbcdt = dense('in_proj', x, p['in_proj'])
     z, xc, B, C, dt = _split_in_proj(zxbcdt, d_inner, d_state)
-    xbc = _causal_conv(jnp.concatenate([xc, B, C], axis=-1), p['conv_w'], p['conv_b'])
+    with jax.named_scope('lm.conv'):
+        xbc = _causal_conv(jnp.concatenate([xc, B, C], axis=-1),
+                           p['conv_w'], p['conv_b'])
     xc, B, C = jnp.split(xbc, [d_inner, d_inner + d_state], axis=-1)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p['dt_bias'])
-    A = -jnp.exp(p['A_log'])
+    A = -jnp.exp(p['A_log'].astype(jnp.float32))
     xh = xc.reshape(bsz, s, n_heads, headdim)
-    y, _ = ssd_chunked(xh, dt, A, B, C, chunk=chunk)
+    with jax.named_scope('lm.ssd'):
+        y, _ = ssd_chunked(xh, dt, A, B, C, chunk=chunk,
+                           precision=ssd_precision)
     y = y + xh * p['D'][None, None, :, None].astype(y.dtype)
     y = y.reshape(bsz, s, d_inner)
     y = cm.rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
-                    p['norm_scale'])
-    return jnp.einsum('bsi,id->bsd', y, p['out_proj'])
+                    p['norm_scale'], eps)
+    return dense('out_proj', y, p['out_proj'])
 
 
 def init_mamba_cache(bsz, d_model, d_state, headdim, dtype, expand=2):
@@ -178,7 +198,7 @@ def init_mamba_cache(bsz, d_model, d_state, headdim, dtype, expand=2):
     }
 
 
-def step_mamba_block(p, cache, x_t, *, d_state, headdim, expand=2):
+def step_mamba_block(p, cache, x_t, *, d_state, headdim, expand=2, eps=1e-6):
     """x_t: [b, 1, d_model] -> (new_cache, y_t [b, 1, d_model])."""
     bsz, _, d_model = x_t.shape
     d_inner = expand * d_model
@@ -191,13 +211,13 @@ def step_mamba_block(p, cache, x_t, *, d_state, headdim, expand=2):
     conv_out = jax.nn.silu(conv_out)
     xc, B, C = jnp.split(conv_out, [d_inner, d_inner + d_state], axis=-1)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p['dt_bias'])
-    A = -jnp.exp(p['A_log'])
+    A = -jnp.exp(p['A_log'].astype(jnp.float32))
     xh = xc.reshape(bsz, n_heads, headdim)
     new_ssm, y = ssd_step(cache['ssm'], xh, dt, A, B, C)
     y = y + xh * p['D'][None, :, None].astype(y.dtype)
     y = y.reshape(bsz, d_inner)
     y = cm.rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
-                    p['norm_scale'])
+                    p['norm_scale'], eps)
     y = jnp.einsum('bi,id->bd', y, p['out_proj'])
     new_cache = {'conv': conv_win[:, 1:], 'ssm': new_ssm}
     return new_cache, y[:, None, :]
