@@ -282,7 +282,8 @@ def tier_round(ops_: Operands, k: int = 24):
     """gather_rows, scatter_rows and the tier-rows kernel against plain
     indexing and the Eq. 6-8 delta oracle, on a value buffer of 64 rows
     whose K read slots and K write slots are distinct and disjoint (the
-    tier schedule's invariant)."""
+    tier schedule's invariant); the tier kernel and a gather read it as
+    the row slab [R, N/128, 128] that the tier engines carry."""
     import jax.numpy as jnp
 
     from repro.kernels import backend, ops
@@ -303,10 +304,14 @@ def tier_round(ops_: Operands, k: int = 24):
     want = (agg + jnp.sum(w[:, None] * (c1 - c0), axis=0),
             agg + jnp.sum(w[:, None] * (c2 - c0), axis=0),
             buf.at[dsts].set(c2))
-    got = ops.safa_aggregate_packed_tier_rows(buf, trained, ops_.g, agg,
+    slab = ops.as_slab(buf)
+    got = ops.safa_aggregate_packed_tier_rows(slab, trained, ops_.g, agg,
                                               srcs, dsts, p, u, d, w)
+    got = (*got[:2], got[2].reshape(r, -1))
     return {
         'round_gather_rows': _bounded(ops.gather_rows(buf, srcs), c0, 0.0),
+        'round_gather_slab_rows': _bounded(ops.gather_rows(slab, srcs), c0,
+                                           0.0),
         'round_scatter_rows': _bounded(ops.scatter_rows(buf + 0.0, dsts, c2),
                                        want[2], 0.0),
         'round_tier_rows': _bounded(got, want, RTOL_F32),

@@ -14,8 +14,8 @@ Rules
   written buffer slot (``cache_dst`` != scratch, ``global_dst``) is
   distinct from every other write and from every read slot
   (``base_src``/``cache_src``).  This is exactly the property that lets
-  ``safa_aggregate_packed_*_tier_rows`` alias the ``[capacity+1, N]``
-  buffer in place.
+  ``safa_aggregate_packed_*_tier_rows`` alias the value buffer of
+  capacity+1 rows (the row slab ``[capacity+1, N/128, 128]``) in place.
 * **SCH002** — capacity == peak live rows: replaying value lifetimes
   from the slot maps alone (a write opens an interval, the last read
   closes it) must reproduce ``capacity`` exactly — the first-fit

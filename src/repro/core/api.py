@@ -734,10 +734,12 @@ def _safa_prepare_state(st, weights, ex, fleet: bool, sched=None):
 
 def _safa_prepare_tier_state(st, weights, ex, fleet: bool, sched):
     """Build the lag-tier carry from the global alone: value buffer
-    (capacity + 1 rows of the init global; row ``capacity`` is scratch,
-    and the packed buffer is padded to whole 8-row groups for the
-    in-place row kernels) and the running aggregate
-    ``global * sum(weights)``."""
+    (capacity + 1 rows of the init global; row ``capacity`` is scratch)
+    and the running aggregate ``global * sum(weights)``.  The packed
+    buffer is a row slab [R, N/128, 128] (``[S, R, N/128, 128]`` for a
+    fleet), so the tier kernels copy exact rows; R is rounded up to whole
+    8-row groups, as the sparse engines' [R, N] buffers are, though the
+    slab kernels take any R."""
     from repro.kernels import ops as kops
     cap = int(sched.capacity)
     wsum = jnp.sum(weights, axis=-1) if fleet else jnp.sum(weights)
@@ -761,7 +763,7 @@ def _safa_prepare_tier_state(st, weights, ex, fleet: bool, sched):
     pack_g = kops.pack_stacked if fleet else kops.pack_global
     gbuf = pack_g(st.global_w, spec)
     cap = row_pad(cap + 1) - 1
-    st.packed = (gbuf, rows(gbuf),
+    st.packed = (gbuf, rows(kops.as_slab(gbuf)),
                  pack_g(jax.tree.map(scale, st.global_w), spec))
     st.spec = spec
 

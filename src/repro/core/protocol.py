@@ -1148,11 +1148,12 @@ def safa_round_sparse_tier_packed(gbuf, tbuf, abuf, *, idx, roles, base_src,
                                   cache_src, cache_dst, global_dst, weights,
                                   local_train_fn, train_args=(), spec,
                                   wire: str = 'f32'):
-    """Packed-buffer lag-tier round: gbuf [N] f32, tbuf [capacity+1, N]
-    value buffer, abuf [N] f32 running aggregate.  One fused tier-rows
-    dispatch does Eq. 6-8, both delta sums, and the ``cache_dst`` scatter
-    in place (the buffer aliases through the kernel); only the
-    ``global_dst`` row write remains outside.  Returns
+    """Packed-buffer lag-tier round: gbuf [N] f32, tbuf [capacity+1,
+    N/128, 128] value buffer (a row slab: each row whole (8, 128) tiles,
+    so the kernels copy exact rows), abuf [N] f32 running aggregate.  One
+    fused tier-rows dispatch does Eq. 6-8, both delta sums, and the
+    ``cache_dst`` scatter in place (the buffer aliases through the
+    kernel); only the ``global_dst`` row write remains outside.  Returns
     (gbuf', tbuf', abuf')."""
     check_wire(wire)
     from repro.kernels import ops as kops
@@ -1185,7 +1186,8 @@ def safa_round_sparse_tier_packed(gbuf, tbuf, abuf, *, idx, roles, base_src,
                 tbuf, local_rows, gbuf, abuf, cache_src, cache_dst, pick_r,
                 und_r, dep_r, w_rows)
     with obs.scope('rows'):
-        new_t = new_t.at[global_dst].set(ng.astype(new_t.dtype))
+        new_t = new_t.at[global_dst].set(
+            ng.reshape(new_t.shape[1:]).astype(new_t.dtype))
     return ng.astype(gbuf.dtype), new_t, na
 
 

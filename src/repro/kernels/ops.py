@@ -17,6 +17,7 @@ fleet dimension (``pack_fleet``/``unpack_fleet`` lay out [S, m, N]).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, NamedTuple
 
 import jax
@@ -27,11 +28,14 @@ from jax.experimental.pallas import tpu as pltpu
 from repro import obs
 from repro.core.protocol import AggregationResult
 from repro.kernels import backend
-from repro.kernels.backend import SUBLANES, fit_tile, padded_rows
+from repro.kernels.backend import LANES, SUBLANES, fit_tile, padded_rows
 from repro.kernels.comm_quant import (QBLOCK, dequantize, dequantize_packed,
                                       quantize, quantize_packed)
-from repro.kernels.safa_aggregate import (DEFAULT_TILE, check_rows,
-                                          check_width, group_spec, row_of,
+from repro.kernels.safa_aggregate import (DEFAULT_TILE, as_slab,
+                                          check_rows, check_slab_tile,
+                                          check_width, group_spec,
+                                          pipelined_bytes, read_slab_row,
+                                          row_of,
                                           safa_aggregate,
                                           safa_aggregate_packed,
                                           safa_aggregate_packed_q8,
@@ -39,7 +43,8 @@ from repro.kernels.safa_aggregate import (DEFAULT_TILE, check_rows,
                                           safa_aggregate_packed_q8_tier_rows,
                                           safa_aggregate_packed_rows,
                                           safa_aggregate_packed_tier_rows,
-                                          set_row, slot_spec, write_row)
+                                          set_row, slab_width, slot_spec,
+                                          tier_tile, write_row)
 from repro.kernels.swa_attention import swa_attention
 
 __all__ = ['safa_aggregate', 'safa_aggregate_packed', 'safa_aggregate_tree',
@@ -47,7 +52,7 @@ __all__ = ['safa_aggregate', 'safa_aggregate_packed', 'safa_aggregate_tree',
            'safa_aggregate_packed_rows', 'safa_aggregate_packed_q8_rows',
            'safa_aggregate_packed_tier_rows',
            'safa_aggregate_packed_q8_tier_rows',
-           'gather_rows', 'scatter_rows',
+           'gather_rows', 'scatter_rows', 'as_slab',
            'quantize', 'dequantize', 'quantize_packed', 'dequantize_packed',
            'safa_compressed_update',
            'weighted_merge_packed', 'weighted_merge_tree_packed',
@@ -241,6 +246,12 @@ def unpack_fleet(buf, spec: PackSpec):
 # in place (the buffer is aliased to the output, so untouched rows are
 # never copied).  Both use the same scalar-prefetch indexing and 8-row
 # group blocks as the rows-aggregation kernels in ``safa_aggregate``.
+#
+# The lag-tier engines keep their value buffer as a row slab [R, N/128,
+# 128] (see the lag-tier block of ``safa_aggregate``), whose rows are
+# whole (8, 128) tiles: ``gather_rows`` given a slab copies exactly each
+# active row by one DMA, at the tier kernels' column tile, into the 8-row
+# output groups of the [K, N] result.
 
 
 #: Static alias inventory for this module's pallas kernels (see
@@ -250,6 +261,7 @@ def unpack_fleet(buf, spec: PackSpec):
 #: this dict against the call sites (REP005) and lowered cells (JAX003).
 ALIAS_CONTRACTS = {
     '_copy_kernel': ((),),
+    '_slab_copy_kernel': ((),),
     '_scatter_kernel': (((2, 0),),),        # buf -> out (rows prefetched)
     '_weighted_merge_kernel': ((),),
 }
@@ -260,6 +272,49 @@ def _copy_kernel(rows_ref, src_ref, dst_ref):
     set_row(dst_ref, j, row_of(src_ref, rows_ref[j]))
 
 
+def _slab_copy_kernel(rows_ref, buf_ref, out_ref, row, sem):
+    i, j = pl.program_id(0), pl.program_id(1)
+    set_row(out_ref, j,
+            read_slab_row(buf_ref, row, sem, rows_ref[j], i).reshape(1, -1))
+
+
+#: VMEM a slab gather's grid step needs, in bytes a lane column of its
+#: tile (the output group, the scratch row and the row reshaped), found
+#: as ``safa_aggregate.TIER_VMEM_BYTES`` is
+GATHER_VMEM_BYTES = 92
+
+
+def _gather_slab(buf, rows, tile):
+    """``gather_rows`` of a row slab: a (N // tile, K) grid whose steps
+    each copy one row's column tile by one DMA; records the call's DMAs
+    and bytes under ``gather_rows`` in ``obs.counters()``, as the
+    tier-rows kernels do (every slot issues one, so the count is
+    exact)."""
+    n, k = slab_width(buf.shape), rows.shape[0]
+    params = None
+    if tile is None:        # tier_tile's measure counts each byte twice
+        tile, params = tier_tile(n, GATHER_VMEM_BYTES // 2)
+    check_slab_tile(n, tile)
+    grid = (n // tile, k)           # k innermost: output groups stay put
+    out_spec = slot_spec(tile)
+    out_shape = jax.ShapeDtypeStruct((k, n), buf.dtype)
+    dmas = math.prod(grid)
+    obs.count('gather_rows', dmas=dmas,
+              bytes=dmas * tile * jnp.dtype(buf.dtype).itemsize
+              + pipelined_bytes(grid, [out_spec], [out_shape]))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=grid,
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=out_spec,
+        scratch_shapes=[pltpu.VMEM((tile // LANES, LANES), buf.dtype),
+                        pltpu.SemaphoreType.DMA])
+    return pl.pallas_call(
+        _slab_copy_kernel, grid_spec=grid_spec, out_shape=out_shape,
+        compiler_params=params,
+        interpret=backend.interpret())(rows.astype(jnp.int32), buf)
+
+
 def _scatter_kernel(rows_ref, vals_ref, buf_ref, out_ref, grp, sem):
     del buf_ref  # aliased: read and written through out_ref
     i, j = pl.program_id(0), pl.program_id(1)
@@ -268,9 +323,15 @@ def _scatter_kernel(rows_ref, vals_ref, buf_ref, out_ref, grp, sem):
 
 
 @functools.partial(jax.jit, static_argnames=('tile',))
-def gather_rows(buf, rows, *, tile: int = DEFAULT_TILE):
-    """buf [R, N], rows [K] int32 < R -> [K, N] gathered rows (one
-    dispatch; only the K rows' 8-row groups stream through)."""
+def gather_rows(buf, rows, *, tile: int | None = None):
+    """buf [R, N], or a row slab [R, N/128, 128]; rows [K] int32 < R ->
+    [K, N] gathered rows, in one dispatch.  From [R, N] only the K rows'
+    8-row groups stream through, at ``tile`` (``DEFAULT_TILE`` by
+    default); from a slab each row is copied exactly, at ``tile`` or by
+    default the widest that ``tier_tile`` allows."""
+    if buf.ndim == 3:
+        return _gather_slab(buf, rows, tile)
+    tile = tile or DEFAULT_TILE
     _, n = buf.shape
     k = rows.shape[0]
     check_width(n, tile)

@@ -51,7 +51,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro import obs
 from repro.kernels import backend
-from repro.kernels.backend import (SUBLANES, VMEM_BUDGET, fit_tile,
+from repro.kernels.backend import (LANES, SUBLANES, VMEM_BUDGET, fit_tile,
                                    padded_rows)
 from repro.kernels.comm_quant import QBLOCK, scale_spec, scales_to_blocks
 
@@ -447,12 +447,11 @@ def _int8_row(ref, r):
                    keepdims=True)
 
 
-def _q8_row(k, q_ref, scale_ref, base_ref, completed_ref):
+def _q8_row(k, q_ref, scale_ref, base_ref, completed):
     """Slot k's post-wire upload: its dequantised int8 row, or its base
-    row when it crashed."""
+    row when it crashed (``completed``, [1, 1], False)."""
     deq = _dequant(_int8_row(q_ref, k), row_of(scale_ref, k))
-    return jnp.where(row_of(completed_ref, k) != 0, deq,
-                     row_of(base_ref, k).astype(jnp.float32))
+    return jnp.where(completed, deq, row_of(base_ref, k).astype(jnp.float32))
 
 
 def _q8_rows_kernel(rows_ref, q_ref, scale_ref, base_ref, cache_ref,
@@ -460,7 +459,7 @@ def _q8_rows_kernel(rows_ref, q_ref, scale_ref, base_ref, cache_ref,
                     deprecated_ref, completed_ref, weights_ref,
                     new_global_ref, new_agg_ref, c2_ref, local_ref):
     k = pl.program_id(1)
-    tr = _q8_row(k, q_ref, scale_ref, base_ref, completed_ref)
+    tr = _q8_row(k, q_ref, scale_ref, base_ref, row_of(completed_ref, k) != 0)
     set_row(local_ref, k, tr.astype(local_ref.dtype))
     p, u, d, w = _roles(k, picked_ref, undrafted_ref, deprecated_ref,
                         weights_ref)
@@ -529,13 +528,13 @@ def safa_aggregate_packed_q8_rows(q_rows, scales_rows, base_rows, cache,
 # ---------------------------------------------------------------------------
 #
 # The tier engines (protocol.safa_round_sparse_tier_packed) carry one
-# [capacity+1, N] value buffer instead of [m, N] local/cache stacks; the
-# host schedule names each slot's cache-read slot (``srcs``) and cache-
-# write slot (``dsts``), both scalar-prefetched.  The kernels below are the
-# rows kernels with TWO prefetch operands and the c2 scatter folded in:
-# each step writes its c2 row straight into the buffer at row dsts[j],
-# and the buffer input is aliased to the output, so one dispatch does
-# Eq. 6-8, both delta sums, AND the cache write-back in place.  Sound
+# value buffer of capacity+1 rows instead of [m, N] local/cache stacks;
+# the host schedule names each slot's cache-read slot (``srcs``) and
+# cache-write slot (``dsts``), both scalar-prefetched.  The kernels below
+# are the rows kernels with TWO prefetch operands and the c2 scatter
+# folded in: each step writes its c2 row straight into the buffer at row
+# dsts[j], and the buffer input is aliased to the output, so one dispatch
+# does Eq. 6-8, both delta sums, AND the cache write-back in place.  Sound
 # because the host allocator guarantees per-round src/dst slot
 # disjointness (a value written in round t is first read strictly later);
 # the shared scratch slot (read AND written by inert slots) carries only
@@ -543,16 +542,20 @@ def safa_aggregate_packed_q8_rows(q_rows, scales_rows, base_rows, cache,
 # scratch writes resolve last-wins over the innermost grid dim, exactly
 # like ``ops.scatter_rows``.
 #
-# The buffer stays in HBM: each step copies the 8-row group of its source
-# row into VMEM, and writes its c2 row by a read-modify-write of the
-# destination row's group, both by explicit DMA (a TPU DMA moves whole
-# 8-row tiles).  The buffer's row count must therefore be a multiple of 8
-# (``backend.row_pad``); the engines allocate it so.  The copies of a
+# The buffer is a row slab [R, N/128, 128]: each row is a run of whole
+# (8, 128) tiles, so the window of one row's column tile is itself whole
+# tiles, and a TPU DMA moves exactly that row (an [R, N] buffer's tiles
+# span 8 rows, so a copy of one row moves its whole 8-row group).  Each
+# step copies its source row's window into a (T/128, 128) VMEM scratch
+# row and copies c2 from that scratch out to the destination row: two
+# DMAs of one f32 row each.  The row math runs in the same dense
+# (T/128, 128) form, as do the global and agg rows; only the slot's
+# upload, read from its 8-row group block, is reshaped.  The copies of a
 # step are started and waited on one after another, so their latency is
 # paid once per column tile and slot: the column tile is the widest that
 # divides the packed width and fits VMEM (``tier_tile``), which moves the
-# same bytes in the fewest copies, and every column's sums over the
-# slots run in the same order at any width.
+# same bytes in the fewest copies, and every column's sums over the slots
+# run in the same order at any width and in either form.
 
 
 def _group_window(hbm, r, i, tile: int):
@@ -568,15 +571,9 @@ def _copy(src, dst, sem):
     cp.wait()
 
 
-def read_row(hbm, grp, sem, r, i, tile: int):
-    """Row r, column tile i of an HBM buffer, through VMEM scratch grp."""
-    _copy(_group_window(hbm, r, i, tile), grp, sem)
-    return row_of(grp, r)
-
-
 def write_row(hbm, grp, sem, r, i, tile: int, value):
-    """Write ``value`` to row r, column tile i of an HBM buffer by a
-    read-modify-write of its 8-row group through VMEM scratch grp."""
+    """Write ``value`` to row r, column tile i of an [R, N] HBM buffer by
+    a read-modify-write of its 8-row group through VMEM scratch grp."""
     window = _group_window(hbm, r, i, tile)
     _copy(window, grp, sem)
     set_row(grp, r, value)
@@ -591,30 +588,83 @@ def check_rows(r: int):
             f'{SUBLANES} rows, got {r}; allocate backend.row_pad(rows)')
 
 
-def _tier_rows_kernel(srcs_ref, dsts_ref, buf_ref, trained_ref, global_ref,
-                      agg_ref, picked_ref, undrafted_ref, deprecated_ref,
-                      weights_ref, new_global_ref, new_agg_ref, newbuf_ref,
-                      grp, sem):
-    # buf_ref aliases newbuf_ref: every read and write goes through the
-    # output, which holds the buffer's current contents
-    del buf_ref
+def slab_width(shape) -> int:
+    """Packed width N of a row slab of ``shape`` [R, N/128, 128]; raises
+    unless each row is a run of whole (8, 128) tiles."""
+    if len(shape) != 3 or shape[2] != LANES or shape[1] % SUBLANES:
+        raise ValueError(
+            f'a row slab is [R, N/{LANES}, {LANES}] with N/{LANES} a '
+            f'multiple of {SUBLANES}, so that each row is whole '
+            f'({SUBLANES}, {LANES}) tiles; got {tuple(shape)}')
+    return shape[1] * LANES
+
+
+def as_slab(rows):
+    """Rows [..., N] laid out as the row slab [..., N/128, 128]."""
+    return rows.reshape(rows.shape[:-1] + (-1, LANES))
+
+
+def check_slab_tile(n: int, tile: int):
+    """A slab column tile divides the width and is whole (8, 128) tiles."""
+    check_width(n, tile)
+    if tile % (SUBLANES * LANES):
+        raise ValueError(
+            f'a row slab moves whole ({SUBLANES}, {LANES}) tiles: tile '
+            f'{tile} is not a multiple of {SUBLANES * LANES}')
+
+
+def _slab_window(hbm, r, i, rows: int):
+    """HBM window of row r of a slab buffer at column tile i, ``rows``
+    (8, 128)-tile rows of 128 lanes each."""
+    return hbm.at[r, pl.ds(pl.multiple_of(i * rows, rows), rows), :]
+
+
+def read_slab_row(hbm, row, sem, r, i):
+    """Row r, column tile i of a slab buffer in HBM, copied into the VMEM
+    scratch ``row`` ((T/128, 128)) by one DMA; returns its value."""
+    _copy(_slab_window(hbm, r, i, row.shape[0]), row, sem)
+    return row[...]
+
+
+def write_slab_row(hbm, row, sem, r, i, value):
+    """Write ``value`` ((T/128, 128)) to row r, column tile i of a slab
+    buffer in HBM through the VMEM scratch ``row``, by one DMA."""
+    row[...] = value
+    _copy(row, _slab_window(hbm, r, i, row.shape[0]), sem)
+
+
+def _tier_step(srcs_ref, dsts_ref, tr, global_ref, agg_ref, role_refs,
+               new_global_ref, new_agg_ref, newbuf_ref, row, sem):
+    """One (column tile, slot) step of a tier-rows kernel, given the
+    slot's post-wire upload ``tr`` as a (1, T) row: read the cache row at
+    srcs[k], Eq. 6 and 8, write c2 to dsts[k], and the Eq. 7 deltas."""
     i, k = pl.program_id(0), pl.program_id(1)
-    tile = trained_ref.shape[1]
-    p, u, d, w = _roles(k, picked_ref, undrafted_ref, deprecated_ref,
-                        weights_ref)
-    c0 = read_row(newbuf_ref, grp, sem, srcs_ref[k], i,
-                  tile).astype(jnp.float32)
-    tr = row_of(trained_ref, k).astype(jnp.float32)
-    c1, c2 = _row_math(c0, tr, global_ref[...].astype(jnp.float32), p, u, d)
-    write_row(newbuf_ref, grp, sem, dsts_ref[k], i, tile,
-              c2.astype(newbuf_ref.dtype))
+    p, u, d, w = _tier_roles(k, *role_refs)
+    c0 = read_slab_row(newbuf_ref, row, sem, srcs_ref[k],
+                       i).astype(jnp.float32)
+    c1, c2 = _row_math(c0, tr.reshape(c0.shape),
+                       global_ref[...].astype(jnp.float32), p, u, d)
+    write_slab_row(newbuf_ref, row, sem, dsts_ref[k], i,
+                   c2.astype(row.dtype))
     _accumulate(k, agg_ref, new_global_ref, new_agg_ref, w, c0, c1, c2)
 
 
-#: explicit DMAs of one tier-rows grid step: ``read_row`` copies the
-#: source row's 8-row group in, ``write_row`` copies the destination
-#: row's group in and back out
-TIER_STEP_DMAS = 3
+def _tier_rows_kernel(srcs_ref, dsts_ref, buf_ref, trained_ref, global_ref,
+                      agg_ref, picked_ref, undrafted_ref, deprecated_ref,
+                      weights_ref, new_global_ref, new_agg_ref, newbuf_ref,
+                      row, sem):
+    # buf_ref aliases newbuf_ref: every read and write goes through the
+    # output, which holds the buffer's current contents
+    del buf_ref
+    tr = row_of(trained_ref, pl.program_id(1)).astype(jnp.float32)
+    _tier_step(srcs_ref, dsts_ref, tr, global_ref, agg_ref,
+               (picked_ref, undrafted_ref, deprecated_ref, weights_ref),
+               new_global_ref, new_agg_ref, newbuf_ref, row, sem)
+
+
+#: explicit DMAs of one tier-rows grid step: ``read_slab_row`` copies the
+#: source row in, ``write_slab_row`` copies c2 out to the destination row
+TIER_STEP_DMAS = 2
 
 
 def pipelined_bytes(grid, specs, arrays) -> int:
@@ -651,53 +701,74 @@ def tier_tile(n: int, col_bytes: int):
     return fit_tile(max(fits, default=DEFAULT_TILE), col_bytes)
 
 
-#: VMEM the tier-rows bodies' temporaries take, in (8, tile) buffer groups:
-#: the v5e compiler asks ~209 (f32) and ~225 (int8 wire) bytes a lane
-#: column at tiles of 24,576-73,728 lanes, of which the blocks and the
-#: group scratch take ~130-145
-TIER_TEMP_GROUPS = 3
+#: VMEM a tier-rows grid step needs, in bytes a lane column of its tile
+#: (its blocks, the scratch row and the body's temporaries), by wire: the
+#: least scoped-VMEM limit at which a v5e compile of the call at 251
+#: slots takes tiles of 38,912 and 116,736 lanes.  A chip's compiler has
+#: asked ~11% more, which ``VMEM_BUDGET``'s headroom covers
+TIER_VMEM_BYTES = {'f32': 98, 'int8': 137}
 
 
-def _tier_width(buf, tile, block_bytes: int):
+def _tier_width(n: int, tile, wire: str):
     """``(tile, None)`` for a tile given, else ``tier_tile``'s choice for
-    buf's width.  Per lane column a step holds its pipelined blocks
-    twice: ``block_bytes`` of the slot's groups, and the global and agg
-    rows in and out (one-row f32 blocks, laid out in (1, 128) tiles);
-    and once, so at half their bytes in ``fit_tile``'s measure, the
-    ``(8, tile)`` group scratch and the body's temporaries."""
+    width n, whose steps ask ``TIER_VMEM_BYTES[wire]`` a lane column."""
     if tile is not None:
         return tile, None
-    group = SUBLANES * jnp.dtype(buf.dtype).itemsize
-    return tier_tile(buf.shape[1], block_bytes + 4 * 4
-                     + (1 + TIER_TEMP_GROUPS) * group // 2)
+    return tier_tile(n, TIER_VMEM_BYTES[wire] // 2)
+
+
+def _dense_spec(tile: int):
+    """Block of column tile i of a row held dense as [N/128, 128]."""
+    return pl.BlockSpec((tile // LANES, LANES), lambda i, j, *pf: (i, 0))
+
+
+def _tier_args(agg, global_prev, masks, w_rows):
+    """``_row_args`` with the global and agg rows dense, [N/128, 128],
+    and each role and weight column as wide as a lane row, [K, 128]."""
+    g, a, *cols = _row_args(agg, global_prev, masks, w_rows)
+    return (g.reshape(-1, LANES), a.reshape(-1, LANES),
+            *(jnp.broadcast_to(c, (c.shape[0], LANES)) for c in cols))
+
+
+def _tier_row_specs(tile: int, k: int, n_masks: int):
+    """Specs of ``_tier_args``: the role and weight rows stay resident."""
+    roles = pl.BlockSpec((k, LANES), lambda i, j, *pf: (0, 0))
+    return [_dense_spec(tile)] * 2 + [roles] * (n_masks + 1)
+
+
+def _tier_roles(k, picked_ref, undrafted_ref, deprecated_ref, weights_ref):
+    """Slot k's (picked, undrafted, deprecated, weight), each [1, 128]:
+    a value a dense (T/128, 128) row takes by a sublane broadcast (a
+    [1, 1] one would need a broadcast in sublanes and lanes at once,
+    which the TPU compiler does not lower)."""
+    row = lambda ref: ref[pl.ds(k, 1), :]
+    return (row(picked_ref) != 0, row(undrafted_ref) != 0,
+            row(deprecated_ref) != 0, row(weights_ref).astype(jnp.float32))
 
 
 def _tier_grid(name, in_specs, operands, buf, *, k: int, tile: int):
-    """Grid spec and output shapes of a tier-rows dispatch over buf
-    [R, N]: new_global and new_agg rows, then the buffer itself, which
-    stays in HBM and is written in place.  ``operands`` are the call's
-    arrays after its two prefetched slot-id vectors, one per spec of
-    ``in_specs``.  Records under ``name`` in ``obs.counters()`` the
-    bytes the call's DMAs move (its explicit row-group copies and its
-    pipelined blocks) and how many explicit DMAs it issues: every slot
-    issues the same, sentinel slots included, so the count is exact."""
-    r, np_ = buf.shape
-    check_width(np_, tile)
-    check_rows(r)
+    """Grid spec and output shapes of a tier-rows dispatch over the slab
+    buf [R, N/128, 128]: new_global and new_agg rows (dense, [N/128,
+    128]), then the buffer itself, which stays in HBM and is written in
+    place.  ``operands`` are the call's arrays after its two prefetched
+    slot-id vectors, one per spec of ``in_specs``.  Records under
+    ``name`` in ``obs.counters()`` the bytes the call's DMAs move (its
+    explicit row copies and its pipelined blocks) and how many explicit
+    DMAs it issues: every slot issues the same, sentinel slots included,
+    so the count is exact."""
+    np_ = slab_width(buf.shape)
+    check_slab_tile(np_, tile)
     grid = (np_ // tile, k)         # k innermost: agg blocks revisit
-    out_specs = [
-        pl.BlockSpec((1, tile), lambda i, j, *pf: (0, i)),
-        pl.BlockSpec((1, tile), lambda i, j, *pf: (0, i)),
-        pl.BlockSpec(memory_space=pl.ANY),                      # new buf
-    ]
+    out_specs = [_dense_spec(tile), _dense_spec(tile),
+                 pl.BlockSpec(memory_space=pl.ANY)]                 # new buf
     out_shape = [
-        jax.ShapeDtypeStruct((1, np_), jnp.float32),
-        jax.ShapeDtypeStruct((1, np_), jnp.float32),
-        jax.ShapeDtypeStruct((r, np_), buf.dtype),
+        jax.ShapeDtypeStruct((np_ // LANES, LANES), jnp.float32),
+        jax.ShapeDtypeStruct((np_ // LANES, LANES), jnp.float32),
+        jax.ShapeDtypeStruct(buf.shape, buf.dtype),
     ]
     dmas = TIER_STEP_DMAS * math.prod(grid)
-    group_bytes = SUBLANES * tile * jnp.dtype(buf.dtype).itemsize
-    obs.count(name, dmas=dmas, bytes=dmas * group_bytes
+    row_bytes = tile * jnp.dtype(buf.dtype).itemsize
+    obs.count(name, dmas=dmas, bytes=dmas * row_bytes
               + pipelined_bytes(grid, in_specs, operands)
               + pipelined_bytes(grid, out_specs, out_shape))
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -705,7 +776,7 @@ def _tier_grid(name, in_specs, operands, buf, *, k: int, tile: int):
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
-        scratch_shapes=[pltpu.VMEM((SUBLANES, tile), buf.dtype),
+        scratch_shapes=[pltpu.VMEM((tile // LANES, LANES), buf.dtype),
                         pltpu.SemaphoreType.DMA])
     return grid_spec, out_shape
 
@@ -717,26 +788,26 @@ def safa_aggregate_packed_tier_rows(buf, trained_rows, global_prev, agg,
                                     tile: int | None = None):
     """Slot-indirected Eq. 6-8 with the cache write-back fused in place.
 
-    buf: [capacity+1, N] tier value buffer (trailing scratch row; rows
-    padded to a multiple of 8, see ``backend.row_pad``); trained_rows:
-    [K, N] post-wire uploads (base rows where not committed); global_prev,
-    agg: [N]; srcs/dsts: [K] int32 slot ids (cache-read / cache-write,
-    scratch == discard); roles/weights as in
+    buf: [capacity+1, N/128, 128] tier value buffer, a row slab (trailing
+    scratch row; N/128 a multiple of 8, see ``slab_width``);
+    trained_rows: [K, N] post-wire uploads (base rows where not
+    committed); global_prev, agg: [N]; srcs/dsts: [K] int32 slot ids
+    (cache-read / cache-write, scratch == discard); roles/weights as in
     ``safa_aggregate_packed_rows``.  The buffer input aliases the new-
     buffer output, so untouched slots persist with zero traffic.  ``tile``
     defaults to ``tier_tile``'s choice for N.  Returns (new_global [N]
-    f32, new_agg [N] f32, new_buf [capacity+1, N])."""
-    k, _ = trained_rows.shape
-    tile, params = _tier_width(buf, tile, 4 * SUBLANES)         # trained
+    f32, new_agg [N] f32, new_buf [capacity+1, N/128, 128])."""
+    k, np_ = trained_rows.shape
+    tile, params = _tier_width(np_, tile, 'f32')
     in_specs = [
         pl.BlockSpec(memory_space=pl.ANY),                      # buf
         slot_spec(tile),                                        # trained
-        *_row_specs(tile, 3),
+        *_tier_row_specs(tile, k, 3),
     ]
     slots = (srcs.astype(jnp.int32), dsts.astype(jnp.int32))
     operands = (buf, trained_rows,
-                *_row_args(agg, global_prev,
-                           (picked_r, undrafted_r, deprecated_r), w_rows))
+                *_tier_args(agg, global_prev,
+                            (picked_r, undrafted_r, deprecated_r), w_rows))
     grid_spec, out_shape = _tier_grid(
         'safa_aggregate_packed_tier_rows', in_specs, operands, buf, k=k,
         tile=tile)
@@ -750,26 +821,21 @@ def safa_aggregate_packed_tier_rows(buf, trained_rows, global_prev, agg,
         compiler_params=params,
         interpret=backend.interpret(),
     )(*slots, *operands)
-    return new_global[0], new_agg[0], new_buf
+    return new_global.reshape(-1), new_agg.reshape(-1), new_buf
 
 
 def _q8_tier_rows_kernel(srcs_ref, dsts_ref, q_ref, scale_ref, base_ref,
                          buf_ref, global_ref, agg_ref, picked_ref,
                          undrafted_ref, deprecated_ref, completed_ref,
                          weights_ref, new_global_ref, new_agg_ref,
-                         newbuf_ref, grp, sem):
+                         newbuf_ref, row, sem):
     del buf_ref             # aliased: read and written through newbuf_ref
-    i, k = pl.program_id(0), pl.program_id(1)
-    tile = base_ref.shape[1]
-    tr = _q8_row(k, q_ref, scale_ref, base_ref, completed_ref)
-    p, u, d, w = _roles(k, picked_ref, undrafted_ref, deprecated_ref,
-                        weights_ref)
-    c0 = read_row(newbuf_ref, grp, sem, srcs_ref[k], i,
-                  tile).astype(jnp.float32)
-    c1, c2 = _row_math(c0, tr, global_ref[...].astype(jnp.float32), p, u, d)
-    write_row(newbuf_ref, grp, sem, dsts_ref[k], i, tile,
-              c2.astype(newbuf_ref.dtype))
-    _accumulate(k, agg_ref, new_global_ref, new_agg_ref, w, c0, c1, c2)
+    k = pl.program_id(1)
+    tr = _q8_row(k, q_ref, scale_ref, base_ref,
+                 completed_ref[pl.ds(k, 1), :1] != 0)
+    _tier_step(srcs_ref, dsts_ref, tr, global_ref, agg_ref,
+               (picked_ref, undrafted_ref, deprecated_ref, weights_ref),
+               new_global_ref, new_agg_ref, newbuf_ref, row, sem)
 
 
 @functools.partial(jax.jit, static_argnames=('tile',))
@@ -782,21 +848,20 @@ def safa_aggregate_packed_q8_tier_rows(q_rows, scales_rows, base_rows, buf,
     arrive as the wire format and dequantise in-register; crashed slots
     fall back to base_rows.  No local output exists — tier local state is
     virtual (base rows are always version snapshots).  Returns
-    (new_global [N] f32, new_agg [N] f32, new_buf [capacity+1, N])."""
-    k, _ = q_rows.shape
-    # int8 q and f32 base groups, and the scales of one group
-    tile, params = _tier_width(buf, tile, padded_rows(SUBLANES, 1)
-                               + 4 * SUBLANES + -(-4 * SUBLANES // QBLOCK))
+    (new_global [N] f32, new_agg [N] f32, new_buf [capacity+1, N/128,
+    128])."""
+    k, np_ = q_rows.shape
+    tile, params = _tier_width(np_, tile, 'int8')
     in_specs = [
         *_q8_slot_specs(tile),
         pl.BlockSpec(memory_space=pl.ANY),                      # buf
-        *_row_specs(tile, 4),
+        *_tier_row_specs(tile, k, 4),
     ]
     slots = (srcs.astype(jnp.int32), dsts.astype(jnp.int32))
     operands = (q_rows, scales_to_blocks(scales_rows, tile), base_rows, buf,
-                *_row_args(agg, global_prev,
-                           (picked_r, undrafted_r, deprecated_r,
-                            completed_r), w_rows))
+                *_tier_args(agg, global_prev,
+                            (picked_r, undrafted_r, deprecated_r,
+                             completed_r), w_rows))
     grid_spec, out_shape = _tier_grid(
         'safa_aggregate_packed_q8_tier_rows', in_specs, operands, buf, k=k,
         tile=tile)
@@ -810,4 +875,4 @@ def safa_aggregate_packed_q8_tier_rows(q_rows, scales_rows, base_rows, buf,
         compiler_params=params,
         interpret=backend.interpret(),
     )(*slots, *operands)
-    return new_global[0], new_agg[0], new_buf
+    return new_global.reshape(-1), new_agg.reshape(-1), new_buf

@@ -1,7 +1,8 @@
 """The program's own tracing (``repro.obs``): host spans kept only while
 recording, phase scopes in the op metadata of the compiled round
-programs, the tier-rows kernels' traffic counter, and the spans of
-precompute, run preparation, segments and evaluation."""
+programs, the traffic counters of the tier-rows kernels and the slab
+gather, and the spans of precompute, run preparation, segments and
+evaluation."""
 import functools
 import re
 
@@ -14,6 +15,7 @@ from repro import api, fedsim, obs
 from repro.core import protocol
 from repro.data import make_images, make_regression, partition
 from repro.data.tasks import cnn_task, regression_task
+from repro.kernels import ops
 from repro.kernels import safa_aggregate as sa
 
 # -- host spans ---------------------------------------------------------------
@@ -160,9 +162,9 @@ def test_evaluate_is_under_the_eval_phase():
     assert any('repro.eval/cnn.conv1/' in n for n in names)
 
 
-# -- the tier-rows traffic counter ---------------------------------------------
+# -- the tier-rows and slab gather traffic counters --------------------------
 
-K, N, R = 3, 256, 16        # slots, packed width (two 128-lane tiles), rows
+K, N, R = 3, 2048, 16       # slots, packed width (one 2048 granule), rows
 
 
 def _f32(*shape):
@@ -173,14 +175,18 @@ SLOT_IDS = (jax.ShapeDtypeStruct((K,), jnp.int32),) * 2
 ROLE = jax.ShapeDtypeStruct((K,), jnp.bool_)
 
 
+def _slab(n):
+    return _f32(R, n // 128, 128)
+
+
 def _f32_args(n):
-    return (_f32(R, n), _f32(K, n), _f32(n), _f32(n), *SLOT_IDS,
+    return (_slab(n), _f32(K, n), _f32(n), _f32(n), *SLOT_IDS,
             *(ROLE,) * 3, _f32(K))
 
 
 def _q8_args(n):
     return (jax.ShapeDtypeStruct((K, n), jnp.int8), _f32(K, n // 128),
-            _f32(K, n), _f32(R, n), _f32(n), _f32(n), *SLOT_IDS,
+            _f32(K, n), _slab(n), _f32(n), _f32(n), *SLOT_IDS,
             *(ROLE,) * 4, _f32(K))
 
 
@@ -190,28 +196,27 @@ WRAPPERS = {
 }
 
 
-# Hand counts for K=3 slots over a 16-row buffer N=256 wide, in column
-# tiles of 128 (a (2, 3) grid of 6 steps) or of 256 (a (1, 3) grid of
-# 3 steps).  Each step issues three explicit DMAs of one 8-row f32
-# group, 4,096 bytes at tile 128 and 8,192 at 256: 73,728 bytes either
-# way.  Pipelined blocks move once per column tile where their index
-# follows the tile (a slot's 8-row group, since K < 8; the global and
-# agg rows in and out) and once in all where it does not (the [K, 1]
-# role and weight columns), so they too move the same bytes at either
-# tile:
-#   f32:  trained 2 x 4,096; rows in and out 4 x 2 x 512;
-#         3 roles + weights 4 x 32                      -> 86,144
-#   int8: q 2 x 1,024; scales 2 x 32; base 2 x 4,096;
-#         rows 4 x 2 x 512; 4 roles + weights 5 x 32     -> 88,288
+# Hand counts for K=3 slots over a 16-row slab buffer N=2048 wide, in
+# column tiles of 1024 (a (2, 3) grid of 6 steps) or of 2048 (a (1, 3)
+# grid of 3 steps).  Each step issues two explicit DMAs of one f32 row,
+# 4,096 bytes at tile 1024 and 8,192 at 2048: 49,152 bytes either way.
+# Pipelined blocks move once per column tile where their index follows
+# the tile (a slot's 8-row group, since K < 8; the dense global and agg
+# rows in and out) and once in all where it does not (the [K, 128] role
+# and weight rows), so they too move the same bytes at either tile:
+#   f32:  trained 8 x 8,192; rows in and out 4 x 8,192;
+#         3 roles + weights 4 x 1,536                   -> 153,600
+#   int8: q 8 x 2,048; scales 8 x 64; base 8 x 8,192;
+#         rows 4 x 8,192; 4 roles + weights 5 x 1,536    -> 172,032
 COUNTS = {
-    'safa_aggregate_packed_tier_rows': 86_144,
-    'safa_aggregate_packed_q8_tier_rows': 88_288,
+    'safa_aggregate_packed_tier_rows': 153_600,
+    'safa_aggregate_packed_q8_tier_rows': 172_032,
 }
 
 
 @pytest.mark.parametrize('wrapper,tile,dmas', [
-    *(pytest.param(w, 128, 18, id=w) for w in sorted(COUNTS)),
-    *(pytest.param(w, 256, 9, id=f'{w}-tile256') for w in sorted(COUNTS)),
+    *(pytest.param(w, 1024, 12, id=w) for w in sorted(COUNTS)),
+    *(pytest.param(w, 2048, 6, id=f'{w}-tile2048') for w in sorted(COUNTS)),
 ])
 def test_tier_counter_matches_a_hand_count(monkeypatch, wrapper, tile,
                                            dmas):
@@ -225,7 +230,24 @@ def test_tier_counter_matches_a_hand_count(monkeypatch, wrapper, tile,
     assert obs.counters()[wrapper] == {'dmas': dmas,
                                        'bytes': COUNTS[wrapper]}
     # the kernel body issues TIER_STEP_DMAS copies in each grid step
-    assert len(copies) == sa.TIER_STEP_DMAS == 3
+    assert len(copies) == sa.TIER_STEP_DMAS == 2
+
+
+@pytest.mark.parametrize('tile,dmas', [(1024, 6), (2048, 3)])
+def test_gather_counter_matches_a_hand_count(monkeypatch, tile, dmas):
+    """A slab gather of K=3 rows: each step copies one f32 row in by one
+    DMA, 49,152 // 2 = 24,576 bytes at either tile; the [K, N] output
+    moves once per column tile as the 8-row group that holds the K rows,
+    8 x 8,192 = 65,536 bytes: 90,112 in all."""
+    fn = functools.partial(ops.gather_rows, tile=tile)
+    copies = []
+    real_copy = sa._copy
+    monkeypatch.setattr(sa, '_copy',
+                        lambda *a: copies.append(1) or real_copy(*a))
+    jax.clear_caches()
+    jax.eval_shape(fn, _slab(N), SLOT_IDS[0])
+    assert obs.counters()['gather_rows'] == {'dmas': dmas, 'bytes': 90_112}
+    assert len(copies) == 1         # one copy a grid step
 
 
 #: cache-read and cache-write slots of a round: disjoint, as the host
@@ -254,29 +276,34 @@ def _values(structs, key):
 def test_tier_default_tile_is_wide_and_bitwise_the_narrow_one(wrapper):
     """By default the tile is ``tier_tile``'s widest choice.  Every
     column's adds over the slots run in the same order at any width, so
-    the outputs are bit for bit those of tile 128; the same bytes move,
+    the outputs are bit for bit those of tile 1024; the same bytes move,
     in fewer DMAs by the ratio of the widths."""
     n = 2 * sa.DEFAULT_TILE
     args = _values(WRAPPERS[wrapper](n), jax.random.PRNGKey(15))
     fn = getattr(sa, wrapper)
     jax.clear_caches()
-    narrow = fn(*args, tile=128)
+    narrow = fn(*args, tile=1024)
     narrow_count = obs.counters()[wrapper]
     wide = fn(*args)
     wide_count = obs.counters()[wrapper]
     for got, want in zip(wide, narrow):     # new_global, new_agg, new_buf
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     assert wide_count['bytes'] == narrow_count['bytes']
-    assert narrow_count['dmas'] == wide_count['dmas'] * n // 128
+    assert narrow_count['dmas'] == wide_count['dmas'] * n // 1024
 
 
 # xdevice_1m's packed width is 684 granules of 2048 lanes (684 = 4 x 9 x
-# 19); the paper CNN's is 167, a prime
-@pytest.mark.parametrize('n,tile', [(1_400_832, 19 * 2048), (342_016, 2048)])
+# 19), of which the f32 kernel takes 57 and the int8 one 38 at a time;
+# the paper CNN's is 167, a prime
+@pytest.mark.parametrize('n,tiles', [
+    (1_400_832, {'safa_aggregate_packed_tier_rows': 57 * 2048,
+                 'safa_aggregate_packed_q8_tier_rows': 38 * 2048}),
+    (342_016, dict.fromkeys(COUNTS, 2048))])
 @pytest.mark.parametrize('wrapper', sorted(COUNTS))
-def test_tier_tile_at_the_benchmark_widths(wrapper, n, tile):
+def test_tier_tile_at_the_benchmark_widths(wrapper, n, tiles):
     """The tile a tier-rows call takes by default, read from its DMA
-    count: three a step over an (n / tile, K) grid."""
+    count: two a step over an (n / tile, K) grid."""
+    tile = tiles[wrapper]
     jax.clear_caches()
     jax.eval_shape(getattr(sa, wrapper), *WRAPPERS[wrapper](n))
     assert obs.counters()[wrapper]['dmas'] == \

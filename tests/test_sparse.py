@@ -529,6 +529,110 @@ class TestRowsKernels:
                                    atol=0)
 
 
+class TestSlabKernels:
+    """The lag-tier value buffer as a row slab [R, N/128, 128]: the
+    tier-rows kernels and ``gather_rows`` copy exact rows of it."""
+    R, N, K = 16, 4096, 6
+    # cache-read and cache-write slots of a round: disjoint, as the host
+    # allocator makes them, but for the scratch row 15 that inert slots
+    # share (last-wins, like the sentinel writes of ``scatter_rows``)
+    SRCS, DSTS = [0, 9, 15, 2, 15, 11], [4, 10, 15, 5, 15, 12]
+
+    def _slab(self, rng, *lead):
+        x = rng.standard_normal(lead + (self.R, self.N)).astype(np.float32)
+        return jnp.asarray(x.reshape(lead + (self.R, self.N // 128, 128)))
+
+    @staticmethod
+    @jax.jit
+    def _slot_math(buf, trained, gprev, agg, srcs, dsts, pick, und, dep,
+                   w):
+        """The tier kernels' slot math on an [R, N] buffer, one slot
+        after another as their inner grid axis runs: Eq. 6 and 8 on the
+        row at srcs[k], c2 written to dsts[k], both Eq. 7 deltas."""
+        new_global = new_agg = agg
+        for k in range(srcs.shape[0]):
+            c0 = buf[srcs[k]]
+            c1 = jnp.where(dep[k] & ~pick[k], gprev, c0)
+            c1 = jnp.where(pick[k], trained[k], c1)
+            c2 = jnp.where(und[k], trained[k], c1)
+            buf = buf.at[dsts[k]].set(c2)
+            new_global = new_global + w[k] * (c1 - c0)
+            new_agg = new_agg + w[k] * (c2 - c0)
+        return new_global, new_agg, buf
+
+    def _round(self, rng, wire):
+        k, n = self.K, self.N
+        f32 = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+        mask = lambda: jnp.asarray(rng.random(k) < 0.5)
+        slab, gprev, agg = self._slab(rng), f32(n), f32(n)
+        roles = (mask(), mask(), mask())
+        w = jnp.asarray(rng.random(k), jnp.float32).at[
+            np.array(self.SRCS) == self.R - 1].set(0.0)
+        slots = (jnp.asarray(self.SRCS, jnp.int32),
+                 jnp.asarray(self.DSTS, jnp.int32))
+        if wire == 'f32':
+            trained = f32(k, n)
+            return (trained, (slab, trained, gprev, agg, *slots, *roles, w))
+        q = jnp.asarray(rng.integers(-127, 128, (k, n)), jnp.int8)
+        scales = jnp.asarray(rng.random((k, n // 128)) + 0.01, jnp.float32)
+        base, completed = f32(k, n), mask()
+        deq = (q.astype(jnp.float32).reshape(k, n // 128, 128)
+               * scales[:, :, None]).reshape(k, n)
+        trained = jnp.where(completed[:, None], deq, base)
+        return (trained, (q, scales, base, slab, gprev, agg, *slots, *roles,
+                          completed, w))
+
+    @pytest.mark.parametrize('tile', [1024, None])
+    @pytest.mark.parametrize('wire', ['f32', 'int8'])
+    def test_tier_rows_bitwise_the_slot_math(self, wire, tile):
+        trained, args = self._round(np.random.default_rng(7), wire)
+        if wire == 'f32':
+            fn, (slab, _, gprev, agg, srcs, dsts, p, u, d, w) = \
+                ops.safa_aggregate_packed_tier_rows, args
+        else:
+            fn = ops.safa_aggregate_packed_q8_tier_rows
+            _, _, _, slab, gprev, agg, srcs, dsts, p, u, d, _, w = args
+        want = self._slot_math(slab.reshape(self.R, self.N), trained, gprev,
+                               agg, srcs, dsts, p, u, d, w)
+        got = fn(*args, tile=tile)
+        assert got[2].shape == slab.shape
+        got = (got[0], got[1], got[2].reshape(self.R, self.N))
+        for g, x in zip(got, want):     # new_global, new_agg, new_buf
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(x))
+
+    @pytest.mark.parametrize('tile', [1024, None])
+    def test_gather_rows_from_a_slab(self, tile):
+        rng = np.random.default_rng(8)
+        slab = self._slab(rng)
+        rows = jnp.asarray([3, 0, 15, 3, 7, 15, 12, 1, 9], jnp.int32)
+        got = ops.gather_rows(slab, rows, tile=tile)
+        want = slab.reshape(self.R, self.N)[rows]
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    def test_gather_rows_from_a_fleet_slab(self):
+        rng = np.random.default_rng(9)
+        s = 3
+        slab = self._slab(rng, s)
+        rows = jnp.asarray(rng.integers(0, self.R, (s, self.K)), jnp.int32)
+        got = jax.vmap(ops.gather_rows)(slab, rows)
+        want = jax.vmap(lambda b, i: b.reshape(self.R, self.N)[i])(slab,
+                                                                   rows)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    def test_a_slab_of_partial_tiles_raises(self):
+        """N/128 = 12 rows of 128 lanes: a row is not whole (8, 128)
+        tiles, so no DMA can move it alone."""
+        slab = jnp.zeros((self.R, 12, 128), jnp.float32)
+        with pytest.raises(ValueError, match='row slab'):
+            ops.gather_rows(slab, jnp.zeros((2,), jnp.int32))
+        k, n = 2, 12 * 128
+        with pytest.raises(ValueError, match='row slab'):
+            ops.safa_aggregate_packed_tier_rows(
+                slab, jnp.zeros((k, n)), jnp.zeros(n), jnp.zeros(n),
+                *(jnp.zeros(k, jnp.int32),) * 2,
+                *(jnp.zeros(k, bool),) * 3, jnp.zeros(k), tile=1536)
+
+
 GRANULE = safa_aggregate.DEFAULT_TILE
 
 

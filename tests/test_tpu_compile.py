@@ -104,34 +104,47 @@ def test_safa_aggregate_packed_q8_paper_cnn(compile_for_tpu):
     assert 'tpu_custom_call' in text
 
 
-@pytest.mark.parametrize('kernel', ['gather_rows', 'scatter_rows'])
-def test_rows_kernels_tier_width(compile_for_tpu, kernel):
-    shapes = [((R, N), F32), ((K,), I32)]
+@pytest.mark.parametrize('kernel,slab', [('gather_rows', False),
+                                         ('scatter_rows', False),
+                                         ('gather_rows', True)],
+                         ids=['gather_rows', 'scatter_rows',
+                              'gather_rows-slab'])
+def test_rows_kernels_tier_width(compile_for_tpu, kernel, slab):
+    shapes = [(_buffer(R, N, slab), F32), ((K,), I32)]
     if kernel == 'scatter_rows':
         shapes.append(((K, N), F32))
     text = compile_for_tpu(getattr(ops, kernel), *shapes)
     assert 'tpu_custom_call' in text
 
 
+def _buffer(r, n, slab=True):
+    """Shape of a value buffer of r rows over width n: the lag-tier row
+    slab [r, n/128, 128], or the [r, n] buffer of the sparse engines."""
+    return (r, n // 128, 128) if slab else (r, n)
+
+
 def _tier_operands(wrapper, k, n, r):
     slots = [((k,), I32)] * 2
     if wrapper == 'safa_aggregate_packed_tier_rows':
-        return [((r, n), F32), ((k, n), F32), ((n,), F32), ((n,), F32),
-                *slots, *[((k,), BOOL)] * 3, ((k,), F32)]
+        return [(_buffer(r, n), F32), ((k, n), F32), ((n,), F32),
+                ((n,), F32), *slots, *[((k,), BOOL)] * 3, ((k,), F32)]
     return [((k, n), I8), ((k, n // comm_quant.QBLOCK), F32), ((k, n), F32),
-            ((r, n), F32), ((n,), F32), ((n,), F32), *slots,
+            (_buffer(r, n), F32), ((n,), F32), ((n,), F32), *slots,
             *[((k,), BOOL)] * 4, ((k,), F32)]
 
 
-def test_tier_rows_tier_width(compile_for_tpu):
-    wrapper = 'safa_aggregate_packed_tier_rows'
+TIER_WRAPPERS = ['safa_aggregate_packed_tier_rows',
+                 'safa_aggregate_packed_q8_tier_rows']
+
+
+@pytest.mark.parametrize('wrapper', TIER_WRAPPERS)
+def test_tier_rows_tier_width(compile_for_tpu, wrapper):
     text = compile_for_tpu(getattr(safa_aggregate, wrapper),
                            *_tier_operands(wrapper, K, N, R))
     assert 'tpu_custom_call' in text
 
 
-@pytest.mark.parametrize('wrapper', ['safa_aggregate_packed_tier_rows',
-                                     'safa_aggregate_packed_q8_tier_rows'])
+@pytest.mark.parametrize('wrapper', TIER_WRAPPERS)
 def test_tier_rows_xdevice_width(compile_for_tpu, wrapper):
     """At xdevice_1m's width the default tile is wider than the pack
     granule, and the compiler takes its blocks into VMEM."""
@@ -140,4 +153,14 @@ def test_tier_rows_xdevice_width(compile_for_tpu, wrapper):
     assert 'tpu_custom_call' in text
     steps = obs.counters()[wrapper]['dmas'] // safa_aggregate.TIER_STEP_DMAS
     tile = N_1M * K_1M // steps
+    assert tile > safa_aggregate.DEFAULT_TILE and N_1M % tile == 0
+
+
+def test_gather_rows_slab_xdevice_width(compile_for_tpu):
+    """The slab gather at xdevice_1m's width takes a tile wider than the
+    pack granule too: one row copy a step over an (N / tile, K) grid."""
+    text = compile_for_tpu(ops.gather_rows, (_buffer(R_1M, N_1M), F32),
+                           ((K_1M,), I32))
+    assert 'tpu_custom_call' in text
+    tile = N_1M * K_1M // obs.counters()['gather_rows']['dmas']
     assert tile > safa_aggregate.DEFAULT_TILE and N_1M % tile == 0
