@@ -75,8 +75,9 @@ def _dispatches_per_round(task, env, mode: str) -> int:
     elif mode == 'packed':
         use_kernel, wire = False, 'int8'
     jaxpr = jax.make_jaxpr(
-        lambda g, l, c, s, ww: protocol._safa_scan(
-            g, l, c, s, ww, train_fn, use_kernel, wire)
+        lambda g, l, c, s, ww: protocol.safa_run_scan(
+            g, l, c, s, ww, local_train_fn=train_fn, use_kernel=use_kernel,
+            wire=wire)
     )(ns.global_w, ns.local_w, ns.cache, sched.to_device(), w)
     return count_pallas_calls(jaxpr.jaxpr)
 

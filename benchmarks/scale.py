@@ -208,12 +208,17 @@ def _timed_segment(runner, reps: int = 5):
     state_b = _tree_nbytes(st.tree())
     train_fn = runner._train_fn(exp.task)
     seg = jax.tree.map(lambda a: a[0:exp.rounds], runner._dev)
-    runner._pdef.scan_segment(st, seg, weights_j, train_fn, ex)
+
+    def segment():
+        _api._run_segment(runner._form, 'scan', st, seg, weights_j,
+                          train_fn, ex)
+
+    segment()
     jax.block_until_ready(st.global_w)
     best = float('inf')
     for _ in range(reps):
         t0 = time.perf_counter()
-        runner._pdef.scan_segment(st, seg, weights_j, train_fn, ex)
+        segment()
         jax.block_until_ready(st.global_w)
         best = min(best, time.perf_counter() - t0)
     return best, state_b

@@ -44,8 +44,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import api, fedsim
-from repro.core.api import _init_state, _resolve_member, _RunState, \
-    init_fleet_global
+from repro.core.api import _init_state, _resolve_member, _run_segment, \
+    _RunState, init_fleet_global
 from repro.kernels import comm_quant, ops, safa_aggregate
 
 from .report import Report
@@ -200,6 +200,7 @@ def lower_cell(cell: Cell, task=None, env=None,
     (``engine='scan'``) takes any task, env and round count."""
     task = task if task is not None else _tiny_task()
     pdef, ex = cell.pdef, cell.ex
+    form = pdef.form(ex)
     stateless = _stateless(pdef, ex)
     if ex.engine == 'scan':
         exp = api.Experiment(task, env if env is not None else _tiny_env(),
@@ -223,7 +224,7 @@ def lower_cell(cell: Cell, task=None, env=None,
                 st2 = _RunState()
                 st2.set_tree(tree)
                 st2.spec = spec_static
-                pdef.scan_segment(st2, seg, w, train_fn, ex)
+                _run_segment(form, 'scan', st2, seg, w, train_fn, ex)
                 return st2.tree()
             return seg_fn
 
@@ -270,7 +271,7 @@ def lower_cell(cell: Cell, task=None, env=None,
             st2 = _RunState()
             st2.set_tree(tree)
             st2.spec = spec_static
-            pdef.fleet_segment(st2, seg, w, train_fn, ex, None)
+            _run_segment(form, 'fleet', st2, seg, w, train_fn, ex)
             return st2.tree()
         return seg_fn
 

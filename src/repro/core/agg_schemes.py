@@ -36,11 +36,10 @@ from __future__ import annotations
 
 import dataclasses
 
-import jax.numpy as jnp
 import numpy as np
 
-from repro.core import federation, protocol, schedules, selection
-from repro.core.api import (STALENESS_FNS, ProtocolDef, ProtocolSpec,
+from repro.core import federation, schedules, selection
+from repro.core.api import (STALENESS_FNS, Form, ProtocolDef, ProtocolSpec,
                             register)
 from repro.core.schedules import RoundRecord
 
@@ -367,28 +366,9 @@ def _weighted_fleet_precompute(members, sp, *, rounds):
         for mem in members])
 
 
-def _weighted_scan_segment(st, seg, weights, train_fn, ex):
-    del weights  # merge weights live in the schedule
-    st.global_w, st.local_w = protocol.weighted_run_scan(
-        st.global_w, st.local_w, seg, local_train_fn=train_fn,
-        use_kernel=ex.use_kernel, wire=ex.wire)
-
-
-def _weighted_loop_round(st, sched, i, weights, train_fn, ex):
-    del weights
-    st.global_w, st.local_w = protocol.weighted_round(
-        st.global_w, st.local_w,
-        committed=jnp.asarray(sched.committed[i]),
-        wrow=jnp.asarray(sched.wrow[i], jnp.float32),
-        local_train_fn=train_fn, train_args=(i + 1,),
-        use_kernel=ex.use_kernel, wire=ex.wire)
-
-
-def _weighted_fleet_segment(st, seg, weights, train_fn, ex, ctx):
-    del weights
-    st.global_w, st.local_w = protocol.weighted_run_fleet(
-        st.global_w, st.local_w, seg, local_train_fn=train_fn,
-        use_kernel=ex.use_kernel, wire=ex.wire, train_ctx=ctx)
+def _weighted_form(ex) -> Form:
+    del ex  # every scheme of the family replays through one engine
+    return Form('weighted_run_scan', ('global_w', 'local_w'))
 
 
 def _weighted_dispatch_budget(ex) -> int:
@@ -403,8 +383,7 @@ register(ProtocolDef(
     name='seafl', spec_cls=SeaflSpec,
     precompute=_weighted_precompute,
     fleet_precompute=_weighted_fleet_precompute,
-    scan_segment=_weighted_scan_segment, loop_round=_weighted_loop_round,
-    fleet_segment=_weighted_fleet_segment,
+    form=_weighted_form,
     supports_wire=True, supports_kernel='packed', spec_overrides=True,
     dispatch_budget=_weighted_dispatch_budget))
 
@@ -412,7 +391,6 @@ register(ProtocolDef(
     name='csafl', spec_cls=CsaflSpec,
     precompute=_weighted_precompute,
     fleet_precompute=_weighted_fleet_precompute,
-    scan_segment=_weighted_scan_segment, loop_round=_weighted_loop_round,
-    fleet_segment=_weighted_fleet_segment,
+    form=_weighted_form,
     supports_wire=True, supports_kernel='packed', spec_overrides=True,
     dispatch_budget=_weighted_dispatch_budget))

@@ -44,7 +44,7 @@ from __future__ import annotations
 import copy
 import dataclasses
 import hashlib
-from typing import Any, Callable, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -59,7 +59,7 @@ from repro.kernels.backend import row_pad
 
 __all__ = [
     'CompiledRunner', 'ExecSpec', 'Experiment', 'FedAsyncSpec', 'FedAvgSpec',
-    'FedCSSpec', 'History', 'LocalSpec', 'PROTOCOLS', 'ProtocolDef',
+    'FedCSSpec', 'Form', 'History', 'LocalSpec', 'PROTOCOLS', 'ProtocolDef',
     'ProtocolSpec', 'RoundRecord', 'STALENESS_FNS', 'SafaSpec', 'SweepMember',
     'SweepSpec', 'Task', 'check_compat', 'init_fleet_global', 'register',
     'spec',
@@ -195,25 +195,35 @@ class SweepSpec:
 # Protocol registry
 # ---------------------------------------------------------------------------
 
+class Form(NamedTuple):
+    """One state form of a protocol, as the runners drive it: the name of
+    its scan engine on ``protocol`` (whose step adapter and fleet twin
+    ``protocol.ENGINES[engine]`` names), the ``_RunState`` fields its
+    carry is made of, in order, and whether the carry is instead the
+    state's pack buffers (``_RunState.packed``), whose global the runner
+    unpacks after every segment."""
+    engine: str
+    carry: tuple = ()
+    packed: bool = False
+
+
 @dataclasses.dataclass(frozen=True)
 class ProtocolDef:
     """Everything the runners need to execute one protocol.
 
     ``precompute(env, spec, *, rounds, seed)`` runs the host event state
     machine; ``fleet_precompute(members, *, rounds)`` the fleet-major
-    form.  ``scan_segment`` / ``fleet_segment`` advance the model state
-    through one compiled eval segment; ``loop_round`` is the per-round
-    reference; ``finish_segment`` (optional) runs at eval stops (the
-    fully-local aggregation).  Registering a new def via ``register``
-    makes the protocol available to ``Experiment`` and sweeps without
-    touching ``federation.py``."""
+    form.  ``form(ex)`` names the ``Form`` an exec spec runs: the runners
+    drive every form through one generic segment path (``_run_segment``).
+    ``finish_segment`` (optional) runs at eval stops (the fully-local
+    aggregation).  Registering a new def via ``register`` makes the
+    protocol available to ``Experiment`` and sweeps without touching
+    ``federation.py``."""
     name: str
     spec_cls: type
     precompute: Callable
     fleet_precompute: Callable
-    scan_segment: Callable
-    loop_round: Callable
-    fleet_segment: Callable
+    form: Callable
     finish_segment: Optional[Callable] = None
     uses_cache: bool = False
     supports_wire: bool = False
@@ -394,13 +404,11 @@ class _RunState:
     under ``use_kernel='packed'``, ``packed`` — the (global, local,
     cache, agg) pack-buffer carry with layout ``spec`` (static, rebuilt
     on resume) that replaces the local/cache/agg trees entirely."""
-    __slots__ = ('global_w', 'local_w', 'cache', 'agg', 'packed', 'spec',
-                 'operands')
+    __slots__ = ('global_w', 'local_w', 'cache', 'agg', 'packed', 'spec')
 
     def __init__(self, global_w=None, local_w=None, cache=None):
         self.global_w, self.local_w, self.cache = global_w, local_w, cache
         self.agg, self.packed, self.spec = None, None, None
-        self.operands = None        # Task.operands(): arguments, not state
 
     def tree(self):
         t = {'global': self.global_w, 'local': self.local_w}
@@ -417,10 +425,6 @@ class _RunState:
         self.cache = t.get('cache')
         self.agg = t.get('agg')
         self.packed = t.get('packed')
-
-
-def _to_j(mask: np.ndarray):
-    return jnp.asarray(mask)
 
 
 def _eval_rounds(rounds: int, eval_every: int):
@@ -690,6 +694,29 @@ def _pack_layout(global_w, wire):
         else kops.pack_spec(global_w)
 
 
+_GLC = ('global_w', 'local_w', 'cache')
+
+#: SAFA's state forms.  Under ``use_kernel='packed'`` the sparse_delta
+#: and sparse_tier carries are pack buffers (``_safa_prepare_state``).
+_SAFA_FORMS = {
+    'dense': Form('safa_run_scan', _GLC),
+    'sparse': Form('safa_run_scan_sparse', _GLC),
+    'sparse_delta': Form('safa_run_scan_sparse_delta', _GLC + ('agg',)),
+    'sparse_delta_packed': Form('safa_run_scan_sparse_delta_packed',
+                                packed=True),
+    'sparse_tier': Form('safa_run_scan_sparse_tier',
+                        ('global_w', 'cache', 'agg')),
+    'sparse_tier_packed': Form('safa_run_scan_sparse_tier_packed',
+                               packed=True),
+}
+
+
+def _safa_form(ex) -> Form:
+    packed = ex.use_kernel == 'packed' and ex.schedule in ('sparse_delta',
+                                                          'sparse_tier')
+    return _SAFA_FORMS[ex.schedule + '_packed' * packed]
+
+
 def _safa_prepare_state(st, weights, ex, fleet: bool, sched=None):
     """Sparse-delta carries: the running aggregate tree, or — under
     ``use_kernel='packed'`` — the whole state as resident pack buffers
@@ -707,7 +734,7 @@ def _safa_prepare_state(st, weights, ex, fleet: bool, sched=None):
     if ex.schedule != 'sparse_delta':
         return
     from repro.kernels import ops as kops
-    if ex.use_kernel != 'packed':
+    if not _safa_form(ex).packed:
         init = jax.vmap(protocol.init_aggregate) if fleet \
             else protocol.init_aggregate
         st.agg = init(st.cache, weights)
@@ -754,7 +781,7 @@ def _safa_prepare_tier_state(st, weights, ex, fleet: bool, sched):
                                     (g.shape[0], cap + 1) + g.shape[1:])
         return jnp.broadcast_to(g[None], (cap + 1,) + g.shape)
 
-    if ex.use_kernel != 'packed':
+    if not _safa_form(ex).packed:
         st.cache = jax.tree.map(rows, st.global_w)
         st.agg = jax.tree.map(scale, st.global_w)
         return
@@ -766,125 +793,6 @@ def _safa_prepare_tier_state(st, weights, ex, fleet: bool, sched):
     st.packed = (gbuf, rows(kops.as_slab(gbuf)),
                  pack_g(jax.tree.map(scale, st.global_w), spec))
     st.spec = spec
-
-
-def _safa_scan_segment(st, seg, weights, train_fn, ex):
-    if ex.schedule == 'dense':
-        st.global_w, st.local_w, st.cache = protocol.safa_run_scan(
-            st.global_w, st.local_w, st.cache, seg, weights,
-            local_train_fn=train_fn, use_kernel=ex.use_kernel, wire=ex.wire,
-            operands=st.operands)
-    elif ex.schedule == 'sparse':
-        st.global_w, st.local_w, st.cache = protocol.safa_run_scan_sparse(
-            st.global_w, st.local_w, st.cache, seg, weights,
-            local_train_fn=train_fn, use_kernel=ex.use_kernel, wire=ex.wire)
-    elif ex.schedule == 'sparse_tier':
-        if st.packed is not None:
-            from repro.kernels import ops as kops
-            st.packed = protocol.safa_run_scan_sparse_tier_packed(
-                *st.packed, seg, weights, local_train_fn=train_fn,
-                spec=st.spec, wire=ex.wire)
-            st.global_w = kops.unpack_global(st.packed[0], st.spec)
-        else:
-            st.global_w, st.cache, st.agg = \
-                protocol.safa_run_scan_sparse_tier(
-                    st.global_w, st.cache, st.agg, seg, weights,
-                    local_train_fn=train_fn, wire=ex.wire)
-    elif st.packed is not None:
-        from repro.kernels import ops as kops
-        st.packed = protocol.safa_run_scan_sparse_delta_packed(
-            *st.packed, seg, weights, local_train_fn=train_fn,
-            spec=st.spec, wire=ex.wire)
-        st.global_w = kops.unpack_global(st.packed[0], st.spec)
-    else:
-        st.global_w, st.local_w, st.cache, st.agg = \
-            protocol.safa_run_scan_sparse_delta(
-                st.global_w, st.local_w, st.cache, st.agg, seg, weights,
-                local_train_fn=train_fn, wire=ex.wire)
-
-
-def _safa_loop_round(st, sched, i, weights, train_fn, ex):
-    if ex.schedule == 'dense':
-        st.global_w, st.local_w, st.cache = protocol.safa_round(
-            st.global_w, st.local_w, st.cache,
-            sync_mask=_to_j(sched.sync[i]),
-            completed=_to_j(sched.committed[i]),
-            picked=_to_j(sched.picked[i]),
-            undrafted=_to_j(sched.undrafted[i]),
-            deprecated=_to_j(sched.deprecated[i]), weights=weights,
-            local_train_fn=train_fn, train_args=(i + 1,),
-            use_kernel=ex.use_kernel, wire=ex.wire)
-        return
-    idx, roles = _to_j(sched.idx[i]), _to_j(sched.roles[i])
-    if ex.schedule == 'sparse':
-        st.global_w, st.local_w, st.cache = protocol.safa_round_sparse(
-            st.global_w, st.local_w, st.cache, idx=idx, roles=roles,
-            weights=weights, local_train_fn=train_fn, train_args=(i + 1,),
-            use_kernel=ex.use_kernel, wire=ex.wire)
-    elif ex.schedule == 'sparse_tier':
-        maps = dict(
-            idx=idx, roles=roles, base_src=_to_j(sched.base_src[i]),
-            cache_src=_to_j(sched.cache_src[i]),
-            cache_dst=_to_j(sched.cache_dst[i]),
-            global_dst=jnp.asarray(sched.global_dst[i]))
-        if st.packed is not None:
-            from repro.kernels import ops as kops
-            st.packed = protocol.safa_round_sparse_tier_packed(
-                *st.packed, **maps, weights=weights, local_train_fn=train_fn,
-                train_args=(i + 1,), spec=st.spec, wire=ex.wire)
-            st.global_w = kops.unpack_global(st.packed[0], st.spec)
-        else:
-            st.global_w, st.cache, st.agg = protocol.safa_round_sparse_tier(
-                st.global_w, st.cache, st.agg, **maps, weights=weights,
-                local_train_fn=train_fn, train_args=(i + 1,), wire=ex.wire)
-    elif st.packed is not None:
-        from repro.kernels import ops as kops
-        st.packed = protocol.safa_round_sparse_delta_packed(
-            *st.packed, idx=idx, roles=roles, weights=weights,
-            local_train_fn=train_fn, train_args=(i + 1,), spec=st.spec,
-            wire=ex.wire)
-        st.global_w = kops.unpack_global(st.packed[0], st.spec)
-    else:
-        st.global_w, st.local_w, st.cache, st.agg = \
-            protocol.safa_round_sparse_delta(
-                st.global_w, st.local_w, st.cache, st.agg, idx=idx,
-                roles=roles, weights=weights, local_train_fn=train_fn,
-                train_args=(i + 1,), wire=ex.wire)
-
-
-def _safa_fleet_segment(st, seg, weights, train_fn, ex, ctx):
-    if ex.schedule == 'dense':
-        st.global_w, st.local_w, st.cache = protocol.safa_run_fleet(
-            st.global_w, st.local_w, st.cache, seg, weights,
-            local_train_fn=train_fn, use_kernel=ex.use_kernel, wire=ex.wire,
-            train_ctx=ctx)
-    elif ex.schedule == 'sparse':
-        st.global_w, st.local_w, st.cache = protocol.safa_run_fleet_sparse(
-            st.global_w, st.local_w, st.cache, seg, weights,
-            local_train_fn=train_fn, use_kernel=ex.use_kernel, wire=ex.wire)
-    elif ex.schedule == 'sparse_tier':
-        if st.packed is not None:
-            from repro.kernels import ops as kops
-            st.packed = protocol.safa_run_fleet_sparse_tier_packed(
-                *st.packed, seg, weights, local_train_fn=train_fn,
-                spec=st.spec, wire=ex.wire)
-            st.global_w = kops.unpack_stacked(st.packed[0], st.spec)
-        else:
-            st.global_w, st.cache, st.agg = \
-                protocol.safa_run_fleet_sparse_tier(
-                    st.global_w, st.cache, st.agg, seg, weights,
-                    local_train_fn=train_fn, wire=ex.wire)
-    elif st.packed is not None:
-        from repro.kernels import ops as kops
-        st.packed = protocol.safa_run_fleet_sparse_delta_packed(
-            *st.packed, seg, weights, local_train_fn=train_fn,
-            spec=st.spec, wire=ex.wire)
-        st.global_w = kops.unpack_stacked(st.packed[0], st.spec)
-    else:
-        st.global_w, st.local_w, st.cache, st.agg = \
-            protocol.safa_run_fleet_sparse_delta(
-                st.global_w, st.local_w, st.cache, st.agg, seg, weights,
-                local_train_fn=train_fn, wire=ex.wire)
 
 
 def _sync_precompute(fedcs, form='dense'):
@@ -911,50 +819,12 @@ def _fedavg_prepare_state(st, weights, ex, fleet: bool, sched=None):
         st.local_w = None
 
 
-def _fedavg_scan_segment(st, seg, weights, train_fn, ex):
-    if ex.schedule == 'dense':
-        st.global_w, st.local_w = protocol.fedavg_run_scan(
-            st.global_w, st.local_w, seg, weights, local_train_fn=train_fn,
-            wire=ex.wire)
-    elif ex.schedule == 'sparse':
-        st.global_w, st.local_w = protocol.fedavg_run_scan_sparse(
-            st.global_w, st.local_w, seg, weights, local_train_fn=train_fn,
-            wire=ex.wire)
-    else:
-        st.global_w = protocol.fedavg_run_scan_sparse_delta(
-            st.global_w, seg, weights, local_train_fn=train_fn, wire=ex.wire)
-
-
-def _fedavg_loop_round(st, sched, i, weights, train_fn, ex):
-    if ex.schedule == 'dense':
-        st.global_w, st.local_w = protocol.fedavg_round(
-            st.global_w, st.local_w, selected=_to_j(sched.selected[i]),
-            completed=_to_j(sched.completed[i]), weights=weights,
-            local_train_fn=train_fn, train_args=(i + 1,), wire=ex.wire)
-        return
-    idx, roles = _to_j(sched.idx[i]), _to_j(sched.roles[i])
-    if ex.schedule == 'sparse':
-        st.global_w, st.local_w = protocol.fedavg_round_sparse(
-            st.global_w, st.local_w, idx=idx, roles=roles, weights=weights,
-            local_train_fn=train_fn, train_args=(i + 1,), wire=ex.wire)
-    else:
-        st.global_w = protocol.fedavg_round_sparse_delta(
-            st.global_w, idx=idx, roles=roles, weights=weights,
-            local_train_fn=train_fn, train_args=(i + 1,), wire=ex.wire)
-
-
-def _fedavg_fleet_segment(st, seg, weights, train_fn, ex, ctx):
-    if ex.schedule == 'dense':
-        st.global_w, st.local_w = protocol.fedavg_run_fleet(
-            st.global_w, st.local_w, seg, weights, local_train_fn=train_fn,
-            wire=ex.wire, train_ctx=ctx)
-    elif ex.schedule == 'sparse':
-        st.global_w, st.local_w = protocol.fedavg_run_fleet_sparse(
-            st.global_w, st.local_w, seg, weights, local_train_fn=train_fn,
-            wire=ex.wire)
-    else:
-        st.global_w = protocol.fedavg_run_fleet_sparse_delta(
-            st.global_w, seg, weights, local_train_fn=train_fn, wire=ex.wire)
+#: FedAvg/FedCS state forms, by schedule (``sparse_delta`` is stateless)
+_FEDAVG_FORMS = {
+    'dense': Form('fedavg_run_scan', ('global_w', 'local_w')),
+    'sparse': Form('fedavg_run_scan_sparse', ('global_w', 'local_w')),
+    'sparse_delta': Form('fedavg_run_scan_sparse_delta', ('global_w',)),
+}
 
 
 def _local_precompute(env, sp, *, rounds, seed):
@@ -968,26 +838,6 @@ def _local_fleet_precompute(members, sp, *, rounds):
         federation.precompute_local_schedule(
             mem.env, fraction=mem.fraction, rounds=rounds, seed=mem.seed)
         for mem in members])
-
-
-def _local_scan_segment(st, seg, weights, train_fn, ex):
-    del weights, ex
-    st.local_w = protocol.local_run_scan(st.local_w, seg,
-                                         local_train_fn=train_fn)
-
-
-def _local_loop_round(st, sched, i, weights, train_fn, ex):
-    del weights, ex
-    st.local_w = protocol.local_only_round(
-        st.local_w, completed=_to_j(sched.completed[i]),
-        local_train_fn=train_fn, train_args=(i + 1,))
-
-
-def _local_fleet_segment(st, seg, weights, train_fn, ex, ctx):
-    del weights, ex
-    st.local_w = protocol.local_run_fleet(st.local_w, seg,
-                                          local_train_fn=train_fn,
-                                          train_ctx=ctx)
 
 
 def _local_finish_segment(st, weights, fleet: bool):
@@ -1012,28 +862,6 @@ def _fedasync_fleet_precompute(members, sp, *, rounds):
         agg_schemes.precompute_async_schedule(
             mem.env, rounds=rounds, **agg_schemes.async_kwargs(sp, mem))
         for mem in members])
-
-
-def _fedasync_scan_segment(st, seg, weights, train_fn, ex):
-    del weights, ex
-    st.global_w, st.local_w = protocol.fedasync_run_scan(
-        st.global_w, st.local_w, seg, local_train_fn=train_fn)
-
-
-def _fedasync_loop_round(st, sched, i, weights, train_fn, ex):
-    del weights, ex
-    st.global_w, st.local_w = protocol.fedasync_round(
-        st.global_w, st.local_w, committed=_to_j(sched.committed[i]),
-        order=jnp.asarray(sched.order[i]),
-        alphas=jnp.asarray(sched.alphas[i], jnp.float32),
-        local_train_fn=train_fn, train_args=(i + 1,))
-
-
-def _fedasync_fleet_segment(st, seg, weights, train_fn, ex, ctx):
-    del weights, ex
-    st.global_w, st.local_w = protocol.fedasync_run_fleet(
-        st.global_w, st.local_w, seg, local_train_fn=train_fn,
-        train_ctx=ctx)
 
 
 def _safa_dispatch_budget(ex) -> Optional[int]:
@@ -1092,9 +920,8 @@ register(ProtocolDef(
     precompute=_safa_precompute,
     fleet_precompute=lambda members, sp, *, rounds:
         federation.precompute_fleet_schedule(members, rounds=rounds),
-    scan_segment=_safa_scan_segment, loop_round=_safa_loop_round,
-    fleet_segment=_safa_fleet_segment,
-    uses_cache=True, supports_wire=True, supports_kernel=True,
+    form=_safa_form, uses_cache=True, supports_wire=True,
+    supports_kernel=True,
     sparse_precompute=_safa_sparse_precompute,
     prepare_state=_safa_prepare_state,
     tier_precompute=_safa_tier_precompute,
@@ -1105,8 +932,7 @@ register(ProtocolDef(
     name='fedavg', spec_cls=FedAvgSpec,
     precompute=_sync_precompute(fedcs=False),
     fleet_precompute=_sync_fleet_precompute(fedcs=False),
-    scan_segment=_fedavg_scan_segment, loop_round=_fedavg_loop_round,
-    fleet_segment=_fedavg_fleet_segment, supports_wire=True,
+    form=lambda ex: _FEDAVG_FORMS[ex.schedule], supports_wire=True,
     sparse_precompute=_sync_precompute(fedcs=False, form='sparse'),
     prepare_state=_fedavg_prepare_state, delta_stateless=True,
     dispatch_budget=_wire_only_dispatch_budget))
@@ -1115,8 +941,7 @@ register(ProtocolDef(
     name='fedcs', spec_cls=FedCSSpec,
     precompute=_sync_precompute(fedcs=True),
     fleet_precompute=_sync_fleet_precompute(fedcs=True),
-    scan_segment=_fedavg_scan_segment, loop_round=_fedavg_loop_round,
-    fleet_segment=_fedavg_fleet_segment, supports_wire=True,
+    form=lambda ex: _FEDAVG_FORMS[ex.schedule], supports_wire=True,
     sparse_precompute=_sync_precompute(fedcs=True, form='sparse'),
     prepare_state=_fedavg_prepare_state, delta_stateless=True,
     dispatch_budget=_wire_only_dispatch_budget))
@@ -1125,8 +950,7 @@ register(ProtocolDef(
     name='local', spec_cls=LocalSpec,
     precompute=_local_precompute,
     fleet_precompute=_local_fleet_precompute,
-    scan_segment=_local_scan_segment, loop_round=_local_loop_round,
-    fleet_segment=_local_fleet_segment,
+    form=lambda ex: Form('local_run_scan', ('local_w',)),
     finish_segment=_local_finish_segment,
     dispatch_budget=lambda ex: 0))
 
@@ -1134,8 +958,8 @@ register(ProtocolDef(
     name='fedasync', spec_cls=FedAsyncSpec,
     precompute=_fedasync_precompute,
     fleet_precompute=_fedasync_fleet_precompute,
-    scan_segment=_fedasync_scan_segment, loop_round=_fedasync_loop_round,
-    fleet_segment=_fedasync_fleet_segment, spec_overrides=True,
+    form=lambda ex: Form('fedasync_run_scan', ('global_w', 'local_w')),
+    spec_overrides=True,
     dispatch_budget=lambda ex: 0))
 
 
@@ -1214,11 +1038,53 @@ class Experiment:
         return '|'.join(parts)
 
 
-def _sharded_segment(pdef, train_fn, ex, ctx, spec, sharding):
-    """``pdef.fleet_segment`` on every device of ``sharding``'s mesh at
-    once, each device running the unsharded segment program on the
-    members it holds; the outputs are reassembled into fleet arrays
-    sharded like the inputs.
+def _carry_of(st: _RunState, form: Form) -> tuple:
+    if form.packed:
+        return st.packed
+    return tuple(getattr(st, field) for field in form.carry)
+
+
+def _set_carry(st: _RunState, form: Form, carry: tuple, fleet: bool):
+    if form.packed:
+        from repro.kernels import ops as kops
+        st.packed = carry
+        unpack = kops.unpack_stacked if fleet else kops.unpack_global
+        st.global_w = unpack(carry[0], st.spec)
+    else:
+        for field, tree in zip(form.carry, carry):
+            setattr(st, field, tree)
+
+
+def _run_segment(form: Form, engine: str, st: _RunState, seg, weights,
+                 train_fn, ex: ExecSpec, extra=()):
+    """Advance ``st`` through the rounds of ``seg``, a slice of the device
+    schedule, in ``form``.  ``engine='scan'`` makes one call of the
+    form's engine, ``'fleet'`` one call of its fleet twin (state, ``seg``,
+    ``weights`` and ``extra`` member-stacked), and ``'loop'`` applies the
+    form's step to each row in turn, eagerly, op by op.  ``extra``
+    reaches every train call after the round index."""
+    static = dict(local_train_fn=train_fn, use_kernel=ex.use_kernel,
+                  wire=ex.wire, spec=st.spec)
+    if engine == 'loop':
+        step = protocol.ENGINES[form.engine].step
+        for t in range(len(seg.round_idx)):
+            row = jax.tree.map(lambda a, t=t: a[t], seg)
+            _set_carry(st, form, step(_carry_of(st, form), row, weights,
+                                      extra, **static), False)
+        return
+    fleet = engine == 'fleet'
+    name = protocol.ENGINES[form.engine].fleet if fleet else form.engine
+    carry = _carry_of(st, form)
+    out = getattr(protocol, name)(*carry, seg, weights, extra=extra,
+                                  **static)
+    _set_carry(st, form, out if len(carry) > 1 else (out,), fleet)
+
+
+def _sharded_segment(form, train_fn, ex, extra, spec, sharding):
+    """The fleet engine of ``_run_segment`` on every device of
+    ``sharding``'s mesh at once, each device running the unsharded
+    segment program on the members it holds; the outputs are reassembled
+    into fleet arrays sharded like the inputs.
 
     Not one partitioned program: Mosaic kernels cannot be partitioned
     automatically, and a fleet traced over a mesh (jit, shard_map or pmap
@@ -1229,17 +1095,18 @@ def _sharded_segment(pdef, train_fn, ex, ctx, spec, sharding):
     devices = list(sharding.mesh.devices.flat)
 
     def run(st, seg, weights):
-        leaves, treedef = jax.tree.flatten((st.tree(), seg, weights, ctx))
+        leaves, treedef = jax.tree.flatten((st.tree(), seg, weights, extra))
         by_device = [{sh.device: sh.data for sh in leaf.addressable_shards}
                      for leaf in leaves]
         outs = []
         for d in devices:
-            tree, seg_d, w_d, ctx_d = jax.tree.unflatten(
+            tree, seg_d, w_d, extra_d = jax.tree.unflatten(
                 treedef, [shards[d] for shards in by_device])
             local = _RunState()
             local.set_tree(tree)
             local.spec = spec
-            pdef.fleet_segment(local, seg_d, w_d, train_fn, ex, ctx_d)
+            _run_segment(form, 'fleet', local, seg_d, w_d, train_fn, ex,
+                         extra_d)
             outs.append(local.tree())
         st.set_tree(jax.tree.map(
             lambda *parts: jax.make_array_from_single_device_arrays(
@@ -1258,6 +1125,7 @@ class CompiledRunner:
     def __init__(self, exp: Experiment):
         self.exp = exp
         self._pdef = exp._pdef
+        self._form = exp._pdef.form(exp.exec)
         self._dev = None            # cached device-resident schedule
         self._operand_train = None  # _operand_train_fn, made once
         #: after a fleet-engine ``run_sweep``: the members' final global
@@ -1336,11 +1204,10 @@ class CompiledRunner:
         with obs.span('run.prepare'):
             st = _init_state(exp.task, exp.env.m, exp.seed,
                              self._pdef.uses_cache, self._stateless(ex))
-            st.operands = operands
             weights_j = jnp.asarray(exp.env.weights)
             if self._pdef.prepare_state is not None:
                 self._pdef.prepare_state(st, weights_j, ex, False, sched)
-            if engine == 'scan' and self._dev is None:
+            if self._dev is None:
                 self._dev = sched.to_device()
         start_seg = 0
         fingerprint = exp.fingerprint()
@@ -1352,20 +1219,17 @@ class CompiledRunner:
 
         weights = weights_j
         train_fn = self._train_fn(exp.task)
+        extra = () if operands is None else (operands,)
         evals = _eval_rounds(exp.rounds, ex.eval_every)
         start = evals[start_seg - 1] if start_seg else 0
         done = 0
         for k in range(start_seg, len(evals)):
             stop = evals[k]
             with obs.span('segment'):
-                if engine == 'scan':
-                    seg = jax.tree.map(
-                        lambda a, s=start, e=stop: a[s:e], self._dev)
-                    self._pdef.scan_segment(st, seg, weights, train_fn, ex)
-                else:
-                    for t in range(start + 1, stop + 1):
-                        self._pdef.loop_round(st, sched, t - 1, weights,
-                                              train_fn, ex)
+                seg = jax.tree.map(
+                    lambda a, s=start, e=stop: a[s:e], self._dev)
+                _run_segment(self._form, engine, st, seg, weights, train_fn,
+                             ex, extra)
                 if self._pdef.finish_segment is not None:
                     self._pdef.finish_segment(st, weights, False)
             _record_eval(hist, hist.records[stop - 1], exp.task, st.global_w)
@@ -1477,7 +1341,8 @@ class CompiledRunner:
                 for stop in evals:
                     seg = jax.tree.map(
                         lambda a, s=start, e=stop: a[s:e], dev)
-                    self._pdef.scan_segment(st, seg, w_s, train_fn, ex)
+                    _run_segment(self._form, 'scan', st, seg, w_s,
+                                 train_fn, ex)
                     if self._pdef.finish_segment is not None:
                         self._pdef.finish_segment(st, w_s, False)
                     _record_eval(hist, hist.records[stop - 1], task_s,
@@ -1491,12 +1356,12 @@ class CompiledRunner:
         # broadcast into the fleet-major carry
         if tasks is not None:
             stacked = _stacked_task(tasks)
-            ctx = stacked.fleet_ctx()
+            extra = (stacked.fleet_ctx(),)
             train_fn = stacked.fleet_train
             g = _stack_trees([tasks[s].init_global(jax.random.PRNGKey(mem.seed))
                               for s, mem in enumerate(members)])
         else:
-            ctx = None
+            extra = ()
             train_fn = self._train_fn(shared_task)
             g = init_fleet_global(shared_task, [mem.seed for mem in members])
 
@@ -1524,26 +1389,26 @@ class CompiledRunner:
         dev = fleet.to_device()
         size = len(members)
         ndev = len(jax.devices())
-        pdef = self._pdef
-        segment = lambda st_, seg_, w_: pdef.fleet_segment(
-            st_, seg_, w_, train_fn, ex, ctx)
+        form = self._form
+        segment = lambda st_, seg_, w_: _run_segment(
+            form, 'fleet', st_, seg_, w_, train_fn, ex, extra)
         if ex.shard and ndev > 1:
             # every device holds an equal share of the fleet: pad it with
             # copies of the last member up to a multiple of the device
             # count (their results are never read or saved)
             from jax.sharding import Mesh, NamedSharding, PartitionSpec
             pad = -size % ndev
-            tree, dev, weights, ctx = jax.tree.map(
+            tree, dev, weights, extra = jax.tree.map(
                 lambda a: jnp.concatenate(
                     [a, jnp.repeat(a[-1:], pad, axis=0)]) if pad else a,
-                (st.tree(), dev, weights, ctx))
+                (st.tree(), dev, weights, extra))
             mesh = Mesh(np.asarray(jax.devices()), ('fleet',))
             sharding = NamedSharding(mesh, PartitionSpec('fleet'))
-            tree, dev, weights, ctx = jax.device_put(
-                (tree, dev, weights, ctx), sharding)
+            tree, dev, weights, extra = jax.device_put(
+                (tree, dev, weights, extra), sharding)
             st.set_tree(tree)
-            segment = _sharded_segment(self._pdef, train_fn, ex, ctx,
-                                       st.spec, sharding)
+            segment = _sharded_segment(form, train_fn, ex, extra, st.spec,
+                                       sharding)
 
         start = evals[start_seg - 1] if start_seg else 0
         done = 0

@@ -68,10 +68,10 @@ class Task:
     the protocol layer.  ``local_train(stacked_params, round_idx)`` must
     train every client replica for E epochs (vmapped inside).
 
-    ``round_idx`` is a Python int under ``engine='loop'`` but a traced
-    int32 scalar under the default scanned engine — implementations must
-    not branch on it in Python (use ``jnp.where``/``lax.cond`` if the
-    round number matters)."""
+    ``round_idx`` is an int32 scalar: a device array under
+    ``engine='loop'``, traced under the scanned engines — implementations
+    must not branch on it in Python (use ``jnp.where``/``lax.cond`` if
+    the round number matters)."""
 
     def init_global(self, key):
         raise NotImplementedError

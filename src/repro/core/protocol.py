@@ -20,7 +20,7 @@ scopes.
 from __future__ import annotations
 
 import functools
-from typing import Any, NamedTuple
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -246,208 +246,75 @@ class AsyncSchedule(NamedTuple):
     round_idx: Any
 
 
-def _safa_scan(global_w, local_w, cache, schedule, weights, local_train_fn,
-               use_kernel, wire='f32', train_extra=()):
-    """Unjitted scan body shared by the single-run and fleet engines.
+# -- the segment driver -------------------------------------------------------
+#
+# Each (protocol, state form) pair is one *step adapter*
+# ``step(carry, row, weights, extra, **static) -> carry`` (the last
+# section of this module): it hands one schedule row to the form's
+# round function, with ``train_args=(row.round_idx, *extra)``.  ``_scan``
+# runs a step over the rows of a segment, and ``_engines`` makes a form's
+# two jitted programs from it: the single-run engine and its fleet twin,
+# the ``vmap`` of the same scan.  The loop engine (``api``) applies the
+# same step eagerly, one row at a time.
 
-    ``train_extra`` holds per-run constants appended to the train call
-    (``local_train_fn(base, round_idx, *train_extra)``) — the per-member
-    data context of a per-member-Task fleet rides here."""
-    def step(carry, sched):
-        g, l, c = carry
-        out = safa_round(
-            g, l, c, sync_mask=sched.sync, completed=sched.completed,
-            picked=sched.picked, undrafted=sched.undrafted,
-            deprecated=sched.deprecated, weights=weights,
-            local_train_fn=local_train_fn,
-            train_args=(sched.round_idx,) + tuple(train_extra),
-            use_kernel=use_kernel, wire=wire)
-        return out, None
+class Engine(NamedTuple):
+    """What a runner needs beside a scan engine's name: the step adapter
+    it scans (the loop engine applies it round by round) and the name of
+    its fleet twin."""
+    step: Callable
+    fleet: str
 
-    carry, _ = jax.lax.scan(step, (global_w, local_w, cache), schedule)
+
+#: scan engine name -> ``Engine``, one entry per state form
+ENGINES: dict = {}
+
+#: the engines' static keywords: every engine takes all four, and each
+#: step reads the ones its round function needs
+STATIC = ('local_train_fn', 'use_kernel', 'wire', 'spec')
+
+
+def _scan(step, carry, schedule, weights, extra, static):
+    """``step`` over every row of ``schedule``: the one ``lax.scan`` over
+    rounds.  ``carry`` is the tuple of state trees."""
+    def body(c, row):
+        return step(c, row, weights, extra, **static), None
+
+    carry, _ = jax.lax.scan(body, carry, schedule)
     return carry
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1, 2),
-                   static_argnames=('local_train_fn', 'use_kernel', 'wire'))
-def safa_run_scan(global_w, local_w, cache, schedule: RoundSchedule, weights,
-                  *, local_train_fn, use_kernel=False, wire='f32',
-                  operands=None):
-    """Run ``k = len(schedule.round_idx)`` SAFA rounds as one compiled scan.
+def _engines(name: str, step, carry: int):
+    """The jitted engine ``name`` (``<p>_run_scan<form>``) of one state
+    form and its fleet twin (``<p>_run_fleet<form>``).
 
-    Bit-identical to ``k`` per-round ``safa_round`` dispatches: the scan
-    body is the same trace, compiled once.  The carry is donated, so the
-    caller's buffers are reused in place across the whole run.
-    ``wire='int8'`` compiles the compressed-wire round body — 2 kernel
-    dispatches per round inside the one scanned program.  ``operands``
-    (the task's ``Task.operands()``) are arguments of the program, handed
+    Both take the ``carry`` state trees positionally, donated, so the
+    caller's buffers are reused in place across the whole segment; then
+    the segment's schedule (rows stacked [k, ...]) and the aggregation
+    weights; then the keywords ``STATIC`` and ``extra``, a pytree handed
     to every train call after the round index
-    (``local_train_fn(base, round_idx, operands)``).
-    Returns (new_global, new_local, new_cache).
-    """
-    return _safa_scan(global_w, local_w, cache, schedule, weights,
-                      local_train_fn, use_kernel, wire,
-                      train_extra=() if operands is None else (operands,))
+    (``local_train_fn(base, round_idx, *extra)``: a task's operands, a
+    per-member data context).  Each returns the new carry, the tree
+    itself when ``carry == 1``.  The fleet twin takes every argument,
+    ``extra`` included, with a leading member axis [S, ...] and vmaps the
+    same scan, so each member computes exactly the single-run program
+    (a pallas_call in the round batches into one launch over a grid
+    with a leading fleet dimension)."""
+    fleet = name.replace('_run_scan', '_run_fleet')
+    ENGINES[name] = Engine(step, fleet)
 
+    def make(title, vmapped):
+        def run(*args, extra=(), **static):
+            state, (schedule, weights) = args[:carry], args[carry:]
+            seg = lambda s, sch, w, e: _scan(step, s, sch, w, e, static)
+            out = (jax.vmap(seg) if vmapped else seg)(state, schedule,
+                                                      weights, extra)
+            return out if carry > 1 else out[0]
 
-@functools.partial(jax.jit, donate_argnums=(0, 1, 2),
-                   static_argnames=('local_train_fn', 'use_kernel', 'wire'))
-def safa_run_fleet(global_w, local_w, cache, schedule: RoundSchedule, weights,
-                   *, local_train_fn, use_kernel=False, wire='f32',
-                   train_ctx=None):
-    """Run S independent SAFA simulations as ONE vmapped-scan dispatch.
+        run.__name__ = run.__qualname__ = title
+        return jax.jit(run, donate_argnums=tuple(range(carry)),
+                       static_argnames=STATIC)
 
-    Every operand gains a leading fleet axis: global_w [S, ...] leaves,
-    local_w/cache [S, m, ...], schedule fields [S, k, m] (round_idx [S, k]),
-    weights [S, m].  Fleet members may differ in crash draws, selection
-    masks, lag tolerance, fraction and aggregation weights — anything the
-    precomputed schedule captures — but share the Task (model shapes and
-    client data) and round count.
-
-    ``train_ctx`` (optional) is a pytree of [S, ...] leaves vmapped with
-    the carry and handed to every train call as an extra argument
-    (``local_train_fn(base, round_idx, ctx)``) — this is how a fleet of
-    per-member Tasks ships each member its own (padded) client data while
-    the train function stays one static, shared callable.
-
-    Per member this computes exactly the ``safa_run_scan`` program; the
-    regression tests assert per-run bit-identity against S sequential scan
-    runs.  The whole [S, ...] carry is donated, so sweeping S configs costs
-    one dispatch and no extra state copies.  Under ``use_kernel='packed'``
-    the per-round pallas_call is vmapped into a batched-grid launch (still
-    a single kernel dispatch per round for the whole fleet).
-    Returns (new_global, new_local, new_cache), each fleet-stacked.
-    """
-    if train_ctx is None:
-        run = lambda g, l, c, s, w: _safa_scan(g, l, c, s, w, local_train_fn,
-                                               use_kernel, wire)
-        return jax.vmap(run)(global_w, local_w, cache, schedule, weights)
-    run = lambda g, l, c, s, w, ctx: _safa_scan(
-        g, l, c, s, w, local_train_fn, use_kernel, wire, train_extra=(ctx,))
-    return jax.vmap(run)(global_w, local_w, cache, schedule, weights,
-                         train_ctx)
-
-
-def _fedavg_scan(global_w, local_w, schedule, weights, local_train_fn,
-                 wire='f32', train_extra=()):
-    def step(carry, sched):
-        g, l = carry
-        ng, nl = fedavg_round(
-            g, l, selected=sched.selected, completed=sched.completed,
-            weights=weights, local_train_fn=local_train_fn,
-            train_args=(sched.round_idx,) + tuple(train_extra), wire=wire)
-        return (ng, nl), None
-
-    carry, _ = jax.lax.scan(step, (global_w, local_w), schedule)
-    return carry
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1),
-                   static_argnames=('local_train_fn', 'wire'))
-def fedavg_run_scan(global_w, local_w, schedule: SyncSchedule, weights, *,
-                    local_train_fn, wire='f32'):
-    """FedAvg counterpart of ``safa_run_scan``: k synchronous rounds in one
-    dispatch with the (global, local) carry donated.  ``wire='int8'``
-    round-trips the uploads through the packed int8 wire format (2 kernel
-    dispatches per round) before the synchronous aggregation."""
-    return _fedavg_scan(global_w, local_w, schedule, weights, local_train_fn,
-                        wire)
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1),
-                   static_argnames=('local_train_fn', 'wire'))
-def fedavg_run_fleet(global_w, local_w, schedule: SyncSchedule, weights, *,
-                     local_train_fn, wire='f32', train_ctx=None):
-    """FedAvg/FedCS counterpart of ``safa_run_fleet``: S synchronous
-    simulations (schedule fields [S, k, m], weights [S, m]) in one vmapped
-    scan with the fleet-stacked (global, local) carry donated.
-    ``train_ctx``: per-member train context, as in ``safa_run_fleet``."""
-    if train_ctx is None:
-        run = lambda g, l, s, w: _fedavg_scan(g, l, s, w, local_train_fn,
-                                              wire)
-        return jax.vmap(run)(global_w, local_w, schedule, weights)
-    run = lambda g, l, s, w, ctx: _fedavg_scan(g, l, s, w, local_train_fn,
-                                               wire, train_extra=(ctx,))
-    return jax.vmap(run)(global_w, local_w, schedule, weights, train_ctx)
-
-
-def _local_scan(local_w, schedule, local_train_fn, train_extra=()):
-    def step(l, sched):
-        return local_only_round(
-            l, completed=sched.completed, local_train_fn=local_train_fn,
-            train_args=(sched.round_idx,) + tuple(train_extra)), None
-
-    carry, _ = jax.lax.scan(step, local_w, schedule)
-    return carry
-
-
-@functools.partial(jax.jit, donate_argnums=(0,),
-                   static_argnames=('local_train_fn',))
-def local_run_scan(local_w, schedule: LocalSchedule, *, local_train_fn):
-    """Fully-local counterpart of ``safa_run_scan``: k rounds of train +
-    survivor masking in one dispatch with the local stack donated.  There
-    is no global model in the carry — the caller aggregates at eval
-    points."""
-    return _local_scan(local_w, schedule, local_train_fn)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,),
-                   static_argnames=('local_train_fn',))
-def local_run_fleet(local_w, schedule: LocalSchedule, *, local_train_fn,
-                    train_ctx=None):
-    """S fully-local simulations (local_w [S, m, ...], schedule fields
-    [S, k, m]) in one vmapped scan with the fleet stack donated.
-    ``train_ctx``: per-member train context, as in ``safa_run_fleet``."""
-    if train_ctx is None:
-        run = lambda l, s: _local_scan(l, s, local_train_fn)
-        return jax.vmap(run)(local_w, schedule)
-    run = lambda l, s, ctx: _local_scan(l, s, local_train_fn,
-                                        train_extra=(ctx,))
-    return jax.vmap(run)(local_w, schedule, train_ctx)
-
-
-def _fedasync_scan(global_w, local_w, schedule, local_train_fn,
-                   train_extra=()):
-    def step(carry, sched):
-        g, l = carry
-        return fedasync_round(
-            g, l, committed=sched.committed, order=sched.order,
-            alphas=sched.alphas, local_train_fn=local_train_fn,
-            train_args=(sched.round_idx,) + tuple(train_extra)), None
-
-    carry, _ = jax.lax.scan(step, (global_w, local_w), schedule)
-    return carry
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1),
-                   static_argnames=('local_train_fn',))
-def fedasync_run_scan(global_w, local_w, schedule: AsyncSchedule, weights=None,
-                      *, local_train_fn):
-    """FedAsync counterpart of ``safa_run_scan``: k rounds in one dispatch
-    with the (global, local) carry donated.  The per-round arrival-ordered
-    server mixes run as an inner ``lax.scan`` over the schedule's
-    precomputed [k, m] merge-order/alpha tensors (``fedasync_merge``), so
-    the whole run is still a single compiled program.  ``weights`` is
-    accepted for signature parity with the other engines and ignored
-    (FedAsync's mixing weights live in the schedule)."""
-    del weights
-    return _fedasync_scan(global_w, local_w, schedule, local_train_fn)
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1),
-                   static_argnames=('local_train_fn',))
-def fedasync_run_fleet(global_w, local_w, schedule: AsyncSchedule,
-                       weights=None, *, local_train_fn, train_ctx=None):
-    """S FedAsync simulations (schedule fields [S, k, m]) in one vmapped
-    scan with the fleet-stacked (global, local) carry donated.
-    ``train_ctx``: per-member train context, as in ``safa_run_fleet``."""
-    del weights
-    if train_ctx is None:
-        run = lambda g, l, s: _fedasync_scan(g, l, s, local_train_fn)
-        return jax.vmap(run)(global_w, local_w, schedule)
-    run = lambda g, l, s, ctx: _fedasync_scan(g, l, s, local_train_fn,
-                                              train_extra=(ctx,))
-    return jax.vmap(run)(global_w, local_w, schedule, train_ctx)
+    return make(name, False), make(fleet, True)
 
 
 # ---------------------------------------------------------------------------
@@ -790,152 +657,6 @@ def fedavg_round_sparse_delta(global_w, *, idx, roles, weights,
         return jax.tree.map(red, trained_rows, global_w)
 
 
-# -- sparse scan/fleet engines ----------------------------------------------
-
-def _safa_sparse_scan(global_w, local_w, cache, schedule, weights,
-                      local_train_fn, use_kernel, wire='f32'):
-    def step(carry, sched):
-        g, l, c = carry
-        out = safa_round_sparse(
-            g, l, c, idx=sched.idx, roles=sched.roles, weights=weights,
-            local_train_fn=local_train_fn, train_args=(sched.round_idx,),
-            use_kernel=use_kernel, wire=wire)
-        return out, None
-
-    carry, _ = jax.lax.scan(step, (global_w, local_w, cache), schedule)
-    return carry
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1, 2),
-                   static_argnames=('local_train_fn', 'use_kernel', 'wire'))
-def safa_run_scan_sparse(global_w, local_w, cache,
-                         schedule: SparseRoundSchedule, weights, *,
-                         local_train_fn, use_kernel=False, wire='f32'):
-    """Sparse-schedule counterpart of ``safa_run_scan``.  Bit-identical to
-    the dense scan on the masks the schedule encodes; local training runs
-    over the K active rows only.  ``local_train_fn`` follows the
-    rows-train contract (``Task.local_train_rows``)."""
-    return _safa_sparse_scan(global_w, local_w, cache, schedule, weights,
-                             local_train_fn, use_kernel, wire)
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1, 2),
-                   static_argnames=('local_train_fn', 'use_kernel', 'wire'))
-def safa_run_fleet_sparse(global_w, local_w, cache,
-                          schedule: SparseRoundSchedule, weights, *,
-                          local_train_fn, use_kernel=False, wire='f32'):
-    """S sparse SAFA simulations in one vmapped scan (schedule fields
-    [S, k, K], carry fleet-stacked and donated), per-member bit-identical
-    to ``safa_run_scan_sparse``."""
-    run = lambda g, l, c, s, w: _safa_sparse_scan(
-        g, l, c, s, w, local_train_fn, use_kernel, wire)
-    return jax.vmap(run)(global_w, local_w, cache, schedule, weights)
-
-
-def _safa_sparse_delta_scan(global_w, local_w, cache, agg, schedule, weights,
-                            local_train_fn, wire='f32'):
-    def step(carry, sched):
-        out = safa_round_sparse_delta(
-            *carry, idx=sched.idx, roles=sched.roles, weights=weights,
-            local_train_fn=local_train_fn, train_args=(sched.round_idx,),
-            wire=wire)
-        return out, None
-
-    carry, _ = jax.lax.scan(step, (global_w, local_w, cache, agg), schedule)
-    return carry
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3),
-                   static_argnames=('local_train_fn', 'wire'))
-def safa_run_scan_sparse_delta(global_w, local_w, cache, agg,
-                               schedule: SparseRoundSchedule, weights, *,
-                               local_train_fn, wire='f32'):
-    """O(K·N)-per-round SAFA scan: carries (global, local, cache, agg) with
-    ``agg = init_aggregate(cache, weights)`` at entry.  Allclose- (not
-    bit-) equivalent to the dense scan."""
-    return _safa_sparse_delta_scan(global_w, local_w, cache, agg, schedule,
-                                   weights, local_train_fn, wire)
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3),
-                   static_argnames=('local_train_fn', 'wire'))
-def safa_run_fleet_sparse_delta(global_w, local_w, cache, agg,
-                                schedule: SparseRoundSchedule, weights, *,
-                                local_train_fn, wire='f32'):
-    """Fleet counterpart of ``safa_run_scan_sparse_delta`` (one vmapped
-    scan, [S, ...] carry donated)."""
-    run = lambda g, l, c, a, s, w: _safa_sparse_delta_scan(
-        g, l, c, a, s, w, local_train_fn, wire)
-    return jax.vmap(run)(global_w, local_w, cache, agg, schedule, weights)
-
-
-def _fedavg_sparse_scan(global_w, local_w, schedule, weights, local_train_fn,
-                        wire='f32'):
-    def step(carry, sched):
-        g, l = carry
-        ng, nl = fedavg_round_sparse(
-            g, l, idx=sched.idx, roles=sched.roles, weights=weights,
-            local_train_fn=local_train_fn, train_args=(sched.round_idx,),
-            wire=wire)
-        return (ng, nl), None
-
-    carry, _ = jax.lax.scan(step, (global_w, local_w), schedule)
-    return carry
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1),
-                   static_argnames=('local_train_fn', 'wire'))
-def fedavg_run_scan_sparse(global_w, local_w, schedule: SparseSyncSchedule,
-                           weights, *, local_train_fn, wire='f32'):
-    """Sparse-schedule counterpart of ``fedavg_run_scan`` (bit-identical to
-    the dense scan; trains the selected rows only)."""
-    return _fedavg_sparse_scan(global_w, local_w, schedule, weights,
-                               local_train_fn, wire)
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1),
-                   static_argnames=('local_train_fn', 'wire'))
-def fedavg_run_fleet_sparse(global_w, local_w, schedule: SparseSyncSchedule,
-                            weights, *, local_train_fn, wire='f32'):
-    """S sparse FedAvg/FedCS simulations in one vmapped scan."""
-    run = lambda g, l, s, w: _fedavg_sparse_scan(g, l, s, w, local_train_fn,
-                                                 wire)
-    return jax.vmap(run)(global_w, local_w, schedule, weights)
-
-
-def _fedavg_sparse_delta_scan(global_w, schedule, weights, local_train_fn,
-                              wire='f32'):
-    def step(g, sched):
-        ng = fedavg_round_sparse_delta(
-            g, idx=sched.idx, roles=sched.roles, weights=weights,
-            local_train_fn=local_train_fn, train_args=(sched.round_idx,),
-            wire=wire)
-        return ng, None
-
-    carry, _ = jax.lax.scan(step, global_w, schedule)
-    return carry
-
-
-@functools.partial(jax.jit, donate_argnums=(0,),
-                   static_argnames=('local_train_fn', 'wire'))
-def fedavg_run_scan_sparse_delta(global_w, schedule: SparseSyncSchedule,
-                                 weights, *, local_train_fn, wire='f32'):
-    """Stateless FedAvg/FedCS scan: the global model is the whole carry —
-    peak device memory is O(N + K·N), independent of m."""
-    return _fedavg_sparse_delta_scan(global_w, schedule, weights,
-                                     local_train_fn, wire)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,),
-                   static_argnames=('local_train_fn', 'wire'))
-def fedavg_run_fleet_sparse_delta(global_w, schedule: SparseSyncSchedule,
-                                  weights, *, local_train_fn, wire='f32'):
-    """Fleet counterpart of ``fedavg_run_scan_sparse_delta``."""
-    run = lambda g, s, w: _fedavg_sparse_delta_scan(g, s, w, local_train_fn,
-                                                    wire)
-    return jax.vmap(run)(global_w, schedule, weights)
-
-
 # -- packed sparse-delta engine: rows kernels on resident pack buffers ------
 
 def safa_round_sparse_delta_packed(gbuf, lbuf, cbuf, abuf, *, idx, roles,
@@ -988,49 +709,6 @@ def safa_round_sparse_delta_packed(gbuf, lbuf, cbuf, abuf, *, idx, roles,
         new_c = kops.scatter_rows(cbuf, idx, c2_rows.astype(cbuf.dtype))
         new_l = kops.scatter_rows(lbuf, idx, local_rows.astype(lbuf.dtype))
     return ng.astype(gbuf.dtype), new_l, new_c, na
-
-
-def _safa_sparse_delta_packed_scan(gbuf, lbuf, cbuf, abuf, schedule, weights,
-                                   local_train_fn, spec, wire='f32'):
-    def step(carry, sched):
-        out = safa_round_sparse_delta_packed(
-            *carry, idx=sched.idx, roles=sched.roles, weights=weights,
-            local_train_fn=local_train_fn, train_args=(sched.round_idx,),
-            spec=spec, wire=wire)
-        return out, None
-
-    carry, _ = jax.lax.scan(step, (gbuf, lbuf, cbuf, abuf), schedule)
-    return carry
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3),
-                   static_argnames=('local_train_fn', 'spec', 'wire'))
-def safa_run_scan_sparse_delta_packed(gbuf, lbuf, cbuf, abuf,
-                                      schedule: SparseRoundSchedule,
-                                      weights, *, local_train_fn, spec,
-                                      wire='f32'):
-    """Packed-buffer counterpart of ``safa_run_scan_sparse_delta``: the
-    carry is (global [N], local [m+1, N], cache [m+1, N], agg [N]) pack
-    buffers and every round is gather + train + ONE fused rows dispatch +
-    two in-place scatters.  ``spec`` is the (static) pack layout —
-    ``ops.wire_spec`` under ``wire='int8'``, ``ops.pack_spec`` otherwise;
-    callers pack once before and unpack once after the whole run."""
-    return _safa_sparse_delta_packed_scan(gbuf, lbuf, cbuf, abuf, schedule,
-                                          weights, local_train_fn, spec, wire)
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3),
-                   static_argnames=('local_train_fn', 'spec', 'wire'))
-def safa_run_fleet_sparse_delta_packed(gbuf, lbuf, cbuf, abuf,
-                                       schedule: SparseRoundSchedule,
-                                       weights, *, local_train_fn, spec,
-                                       wire='f32'):
-    """Fleet counterpart of ``safa_run_scan_sparse_delta_packed`` (one
-    vmapped scan over [S, ...] pack buffers; the rows kernels batch under
-    vmap into launches over a grid with a leading fleet dimension)."""
-    run = lambda g, l, c, a, s, w: _safa_sparse_delta_packed_scan(
-        g, l, c, a, s, w, local_train_fn, spec, wire)
-    return jax.vmap(run)(gbuf, lbuf, cbuf, abuf, schedule, weights)
 
 
 # -- lag-tier engine: version ring + active slab instead of [m, N] stacks ---
@@ -1103,47 +781,6 @@ def safa_round_sparse_tier(global_w, buf, agg, *, idx, roles, base_src,
     return new_global, new_buf, new_agg
 
 
-def _safa_sparse_tier_scan(global_w, buf, agg, schedule, weights,
-                           local_train_fn, wire='f32'):
-    def step(carry, sched):
-        out = safa_round_sparse_tier(
-            *carry, idx=sched.idx, roles=sched.roles,
-            base_src=sched.base_src, cache_src=sched.cache_src,
-            cache_dst=sched.cache_dst, global_dst=sched.global_dst,
-            weights=weights, local_train_fn=local_train_fn,
-            train_args=(sched.round_idx,), wire=wire)
-        return out, None
-
-    carry, _ = jax.lax.scan(step, (global_w, buf, agg), schedule)
-    return carry
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1, 2),
-                   static_argnames=('local_train_fn', 'wire'))
-def safa_run_scan_sparse_tier(global_w, buf, agg,
-                              schedule: TierRoundSchedule, weights, *,
-                              local_train_fn, wire='f32'):
-    """Lag-tier SAFA scan: carries (global, value buffer, agg) with
-    ``buf = broadcast(global)`` over capacity+1 rows and
-    ``agg = global * sum(weights)`` at entry (every cache row starts as
-    the init global).  Peak state is O((tau+quota)·N) — no [m, N] stack
-    exists anywhere in the program."""
-    return _safa_sparse_tier_scan(global_w, buf, agg, schedule, weights,
-                                  local_train_fn, wire)
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1, 2),
-                   static_argnames=('local_train_fn', 'wire'))
-def safa_run_fleet_sparse_tier(global_w, buf, agg,
-                               schedule: TierRoundSchedule, weights, *,
-                               local_train_fn, wire='f32'):
-    """Fleet counterpart of ``safa_run_scan_sparse_tier`` (one vmapped
-    scan; schedule fields [S, k, K], buffer [S, capacity+1, ...])."""
-    run = lambda g, b, a, s, w: _safa_sparse_tier_scan(
-        g, b, a, s, w, local_train_fn, wire)
-    return jax.vmap(run)(global_w, buf, agg, schedule, weights)
-
-
 def safa_round_sparse_tier_packed(gbuf, tbuf, abuf, *, idx, roles, base_src,
                                   cache_src, cache_dst, global_dst, weights,
                                   local_train_fn, train_args=(), spec,
@@ -1189,45 +826,6 @@ def safa_round_sparse_tier_packed(gbuf, tbuf, abuf, *, idx, roles, base_src,
         new_t = new_t.at[global_dst].set(
             ng.reshape(new_t.shape[1:]).astype(new_t.dtype))
     return ng.astype(gbuf.dtype), new_t, na
-
-
-def _safa_sparse_tier_packed_scan(gbuf, tbuf, abuf, schedule, weights,
-                                  local_train_fn, spec, wire='f32'):
-    def step(carry, sched):
-        out = safa_round_sparse_tier_packed(
-            *carry, idx=sched.idx, roles=sched.roles,
-            base_src=sched.base_src, cache_src=sched.cache_src,
-            cache_dst=sched.cache_dst, global_dst=sched.global_dst,
-            weights=weights, local_train_fn=local_train_fn,
-            train_args=(sched.round_idx,), spec=spec, wire=wire)
-        return out, None
-
-    carry, _ = jax.lax.scan(step, (gbuf, tbuf, abuf), schedule)
-    return carry
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1, 2),
-                   static_argnames=('local_train_fn', 'spec', 'wire'))
-def safa_run_scan_sparse_tier_packed(gbuf, tbuf, abuf,
-                                     schedule: TierRoundSchedule, weights,
-                                     *, local_train_fn, spec, wire='f32'):
-    """Packed counterpart of ``safa_run_scan_sparse_tier``: the whole run
-    is one scanned program whose carry is three pack buffers totalling
-    O((tau+quota)·N) bytes."""
-    return _safa_sparse_tier_packed_scan(gbuf, tbuf, abuf, schedule,
-                                         weights, local_train_fn, spec, wire)
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1, 2),
-                   static_argnames=('local_train_fn', 'spec', 'wire'))
-def safa_run_fleet_sparse_tier_packed(gbuf, tbuf, abuf,
-                                      schedule: TierRoundSchedule, weights,
-                                      *, local_train_fn, spec, wire='f32'):
-    """Fleet counterpart of ``safa_run_scan_sparse_tier_packed`` (one
-    vmapped scan; the tier-rows kernels batch under vmap)."""
-    run = lambda g, t, a, s, w: _safa_sparse_tier_packed_scan(
-        g, t, a, s, w, local_train_fn, spec, wire)
-    return jax.vmap(run)(gbuf, tbuf, abuf, schedule, weights)
 
 
 def fedasync_round(global_w, local_w, *, committed, order, alphas,
@@ -1342,54 +940,192 @@ def weighted_round(global_w, local_w, *, committed, wrow, local_train_fn,
     return new_global, new_local
 
 
-def _weighted_scan(global_w, local_w, schedule, local_train_fn, use_kernel,
-                   wire='f32', train_extra=()):
-    def step(carry, sched):
-        g, l = carry
-        return weighted_round(
-            g, l, committed=sched.committed, wrow=sched.wrow,
-            local_train_fn=local_train_fn,
-            train_args=(sched.round_idx,) + tuple(train_extra),
-            use_kernel=use_kernel, wire=wire), None
+# ---------------------------------------------------------------------------
+# Step adapters and their engines: one pair per (protocol, state form)
+# ---------------------------------------------------------------------------
+#
+# A step hands one schedule row to its form's round function; the line
+# after it binds the form's engine and fleet twin.  An engine's name is
+# its program's name (``jit(safa_run_scan)``), by which traces, tests and
+# fault plants find it on this module.  The carries are the runner's
+# ``_RunState`` fields (``api.Form``); the packed forms carry pack
+# buffers, which callers pack once before and unpack once after a run.
 
-    carry, _ = jax.lax.scan(step, (global_w, local_w), schedule)
-    return carry
+def _safa_step(carry, row, weights, extra, *, local_train_fn,
+               use_kernel=False, wire='f32', **_):
+    """Dense SAFA: carry (global, local, cache), ``RoundSchedule`` rows."""
+    return safa_round(
+        *carry, sync_mask=row.sync, completed=row.completed,
+        picked=row.picked, undrafted=row.undrafted,
+        deprecated=row.deprecated, weights=weights,
+        local_train_fn=local_train_fn, train_args=(row.round_idx, *extra),
+        use_kernel=use_kernel, wire=wire)
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1),
-                   static_argnames=('local_train_fn', 'use_kernel', 'wire'))
-def weighted_run_scan(global_w, local_w, schedule: WeightedSchedule,
-                      weights=None, *, local_train_fn, use_kernel=False,
-                      wire='f32'):
-    """Weighted-merge counterpart of ``safa_run_scan``: k rounds in one
-    dispatch with the (global, local) carry donated.  The whole
-    aggregation scheme lives in the schedule's [k, m] weight rows, so
-    every scheme in the staleness-adaptive family compiles to this same
-    program.  ``weights`` is accepted for signature parity and ignored
-    (the merge weights live in the schedule)."""
+safa_run_scan, safa_run_fleet = _engines('safa_run_scan', _safa_step, 3)
+
+
+def _safa_sparse_step(carry, row, weights, extra, *, local_train_fn,
+                      use_kernel=False, wire='f32', **_):
+    """Sparse SAFA: the dense carry, ``SparseRoundSchedule`` rows; only
+    the K active rows train (``local_train_fn`` is the rows-train
+    contract, ``Task.local_train_rows``)."""
+    return safa_round_sparse(
+        *carry, idx=row.idx, roles=row.roles, weights=weights,
+        local_train_fn=local_train_fn, train_args=(row.round_idx, *extra),
+        use_kernel=use_kernel, wire=wire)
+
+
+safa_run_scan_sparse, safa_run_fleet_sparse = _engines(
+    'safa_run_scan_sparse', _safa_sparse_step, 3)
+
+
+def _safa_sparse_delta_step(carry, row, weights, extra, *, local_train_fn,
+                            wire='f32', **_):
+    """O(K·N) SAFA: carry (global, local, cache, agg) with
+    ``agg = init_aggregate(cache, weights)`` at entry."""
+    return safa_round_sparse_delta(
+        *carry, idx=row.idx, roles=row.roles, weights=weights,
+        local_train_fn=local_train_fn, train_args=(row.round_idx, *extra),
+        wire=wire)
+
+
+safa_run_scan_sparse_delta, safa_run_fleet_sparse_delta = _engines(
+    'safa_run_scan_sparse_delta', _safa_sparse_delta_step, 4)
+
+
+def _safa_sparse_delta_packed_step(carry, row, weights, extra, *,
+                                   local_train_fn, spec, wire='f32', **_):
+    """O(K·N) SAFA on pack buffers: carry (global [N], local [m+1, N],
+    cache [m+1, N], agg [N]); ``spec`` is the pack layout
+    (``ops.wire_spec`` under ``wire='int8'``, ``ops.pack_spec``
+    otherwise)."""
+    return safa_round_sparse_delta_packed(
+        *carry, idx=row.idx, roles=row.roles, weights=weights,
+        local_train_fn=local_train_fn, train_args=(row.round_idx, *extra),
+        spec=spec, wire=wire)
+
+
+safa_run_scan_sparse_delta_packed, safa_run_fleet_sparse_delta_packed = \
+    _engines('safa_run_scan_sparse_delta_packed',
+             _safa_sparse_delta_packed_step, 4)
+
+
+def _tier_maps(row):
+    """A ``TierRoundSchedule`` row's active set and slot maps."""
+    return dict(idx=row.idx, roles=row.roles, base_src=row.base_src,
+                cache_src=row.cache_src, cache_dst=row.cache_dst,
+                global_dst=row.global_dst)
+
+
+def _safa_sparse_tier_step(carry, row, weights, extra, *, local_train_fn,
+                           wire='f32', **_):
+    """Lag-tier SAFA: carry (global, value buffer, agg) with
+    ``buf = broadcast(global)`` over capacity+1 rows and
+    ``agg = global * sum(weights)`` at entry; no [m, N] stack exists
+    anywhere in the program."""
+    return safa_round_sparse_tier(
+        *carry, **_tier_maps(row), weights=weights,
+        local_train_fn=local_train_fn, train_args=(row.round_idx, *extra),
+        wire=wire)
+
+
+safa_run_scan_sparse_tier, safa_run_fleet_sparse_tier = _engines(
+    'safa_run_scan_sparse_tier', _safa_sparse_tier_step, 3)
+
+
+def _safa_sparse_tier_packed_step(carry, row, weights, extra, *,
+                                  local_train_fn, spec, wire='f32', **_):
+    """Lag-tier SAFA on pack buffers: carry (global [N], row slab
+    [capacity+1, N/128, 128], agg [N]), O((tau+quota)·N) bytes."""
+    return safa_round_sparse_tier_packed(
+        *carry, **_tier_maps(row), weights=weights,
+        local_train_fn=local_train_fn, train_args=(row.round_idx, *extra),
+        spec=spec, wire=wire)
+
+
+safa_run_scan_sparse_tier_packed, safa_run_fleet_sparse_tier_packed = \
+    _engines('safa_run_scan_sparse_tier_packed',
+             _safa_sparse_tier_packed_step, 3)
+
+
+def _fedavg_step(carry, row, weights, extra, *, local_train_fn, wire='f32',
+                 **_):
+    """FedAvg/FedCS: carry (global, local), ``SyncSchedule`` rows."""
+    return fedavg_round(
+        *carry, selected=row.selected, completed=row.completed,
+        weights=weights, local_train_fn=local_train_fn,
+        train_args=(row.round_idx, *extra), wire=wire)
+
+
+fedavg_run_scan, fedavg_run_fleet = _engines('fedavg_run_scan',
+                                             _fedavg_step, 2)
+
+
+def _fedavg_sparse_step(carry, row, weights, extra, *, local_train_fn,
+                        wire='f32', **_):
+    """Sparse FedAvg/FedCS: carry (global, local); trains the selected
+    rows only."""
+    return fedavg_round_sparse(
+        *carry, idx=row.idx, roles=row.roles, weights=weights,
+        local_train_fn=local_train_fn, train_args=(row.round_idx, *extra),
+        wire=wire)
+
+
+fedavg_run_scan_sparse, fedavg_run_fleet_sparse = _engines(
+    'fedavg_run_scan_sparse', _fedavg_sparse_step, 2)
+
+
+def _fedavg_sparse_delta_step(carry, row, weights, extra, *,
+                              local_train_fn, wire='f32', **_):
+    """Stateless FedAvg/FedCS: the global model is the whole carry, so
+    peak device memory is O(N + K·N), independent of m."""
+    return (fedavg_round_sparse_delta(
+        *carry, idx=row.idx, roles=row.roles, weights=weights,
+        local_train_fn=local_train_fn, train_args=(row.round_idx, *extra),
+        wire=wire),)
+
+
+fedavg_run_scan_sparse_delta, fedavg_run_fleet_sparse_delta = _engines(
+    'fedavg_run_scan_sparse_delta', _fedavg_sparse_delta_step, 1)
+
+
+def _local_step(carry, row, weights, extra, *, local_train_fn, **_):
+    """Fully local: the local stack is the whole carry; the caller
+    aggregates at eval points."""
     del weights
-    return _weighted_scan(global_w, local_w, schedule, local_train_fn,
-                          use_kernel, wire)
+    return (local_only_round(
+        *carry, completed=row.completed, local_train_fn=local_train_fn,
+        train_args=(row.round_idx, *extra)),)
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1),
-                   static_argnames=('local_train_fn', 'use_kernel', 'wire'))
-def weighted_run_fleet(global_w, local_w, schedule: WeightedSchedule,
-                       weights=None, *, local_train_fn, use_kernel=False,
-                       wire='f32', train_ctx=None):
-    """S weighted-merge simulations (schedule fields [S, k, m]) in one
-    vmapped scan with the fleet-stacked (global, local) carry donated.
-    Members may replay *different* schemes of the family (SEAFL, CSAFL,
-    folded FedAsync discounts) — the scheme is data, not trace.  Under
-    ``use_kernel='packed'`` the per-round merge kernel vmaps into a
-    batched-grid launch.  ``train_ctx``: per-member train context, as in
-    ``safa_run_fleet``."""
+local_run_scan, local_run_fleet = _engines('local_run_scan', _local_step, 1)
+
+
+def _fedasync_step(carry, row, weights, extra, *, local_train_fn, **_):
+    """FedAsync: carry (global, local); the mixing weights live in the
+    schedule's merge-order/alpha rows, merged by an inner scan."""
     del weights
-    if train_ctx is None:
-        run = lambda g, l, s: _weighted_scan(g, l, s, local_train_fn,
-                                             use_kernel, wire)
-        return jax.vmap(run)(global_w, local_w, schedule)
-    run = lambda g, l, s, ctx: _weighted_scan(g, l, s, local_train_fn,
-                                              use_kernel, wire,
-                                              train_extra=(ctx,))
-    return jax.vmap(run)(global_w, local_w, schedule, train_ctx)
+    return fedasync_round(
+        *carry, committed=row.committed, order=row.order, alphas=row.alphas,
+        local_train_fn=local_train_fn, train_args=(row.round_idx, *extra))
+
+
+fedasync_run_scan, fedasync_run_fleet = _engines('fedasync_run_scan',
+                                                 _fedasync_step, 2)
+
+
+def _weighted_step(carry, row, weights, extra, *, local_train_fn,
+                   use_kernel=False, wire='f32', **_):
+    """The weighted-merge family: carry (global, local); the scheme is
+    the schedule's weight rows, so members of one fleet may replay
+    different schemes."""
+    del weights
+    return weighted_round(
+        *carry, committed=row.committed, wrow=row.wrow,
+        local_train_fn=local_train_fn, train_args=(row.round_idx, *extra),
+        use_kernel=use_kernel, wire=wire)
+
+
+weighted_run_scan, weighted_run_fleet = _engines('weighted_run_scan',
+                                                 _weighted_step, 2)

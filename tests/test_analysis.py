@@ -306,6 +306,20 @@ def safa_cell(**exec_kw):
     return jaxpr_checks.Cell(pdef, api.SafaSpec(), api.ExecSpec(**kw))
 
 
+def _stand_in_cell(monkeypatch, wrap):
+    """``safa_cell()`` whose form names a stand-in engine on ``protocol``:
+    ``wrap`` of the form's own engine, with its step and fleet twin."""
+    cell = safa_cell()
+    form = cell.pdef.form(cell.ex)
+    name = 'stand_in_run_scan'
+    monkeypatch.setattr(protocol, name, wrap(getattr(protocol, form.engine)),
+                        raising=False)
+    monkeypatch.setitem(protocol.ENGINES, name, protocol.ENGINES[form.engine])
+    fake = dataclasses.replace(
+        cell.pdef, form=lambda ex: form._replace(engine=name))
+    return dataclasses.replace(cell, pdef=fake)
+
+
 class TestJaxprMutations:
     def test_jax001_wrong_budget_fires(self):
         cell = safa_cell(wire='int8', use_kernel='packed')
@@ -346,36 +360,32 @@ class TestJaxprMutations:
         f64, _ = jaxpr_checks._check_dtypes_and_callbacks(j.jaxpr)
         assert f64 is not None and 'f64' in f64
 
-    def test_jax005_callback_in_scan_body_fires(self):
-        cell = safa_cell()
-        orig = cell.pdef.scan_segment
+    def test_jax005_callback_in_scan_body_fires(self, monkeypatch):
+        def noisy(engine):
+            def run(*args, local_train_fn, **kw):
+                def tf(*a, **k):
+                    jax.debug.print('round')        # host sync per round
+                    return local_train_fn(*a, **k)
+                return engine(*args, local_train_fn=tf, **kw)
+            return run
 
-        def noisy_segment(st, seg, w, train_fn, ex):
-            def tf(*a, **kw):
-                jax.debug.print('round')        # host sync per round
-                return train_fn(*a, **kw)
-            return orig(st, seg, w, tf, ex)
-
-        fake = dataclasses.replace(cell.pdef, scan_segment=noisy_segment)
         rep = jaxpr_checks.check_cells(
-            cells=[dataclasses.replace(cell, pdef=fake)])
+            cells=[_stand_in_cell(monkeypatch, noisy)])
         assert 'JAX005' in failed_rules(rep)
 
-    def test_jax006_baked_constant_fires(self):
-        cell = safa_cell()
-        orig = cell.pdef.scan_segment
+    def test_jax006_baked_constant_fires(self, monkeypatch):
         counter = itertools.count()
 
-        def drifting_segment(st, seg, w, train_fn, ex):
-            orig(st, seg, w, train_fn, ex)
-            # a fresh python constant per trace: the two consecutive
-            # segment traces bake different literals
-            drift = float(next(counter))
-            st.global_w = jax.tree.map(lambda x: x + drift, st.global_w)
+        def drifting(engine):
+            def run(*args, **kw):
+                # a fresh python constant per trace: the two consecutive
+                # segment traces bake different literals
+                drift = float(next(counter))
+                return jax.tree.map(lambda x: x + drift, engine(*args, **kw))
+            return run
 
-        fake = dataclasses.replace(cell.pdef, scan_segment=drifting_segment)
         rep = jaxpr_checks.check_cells(
-            cells=[dataclasses.replace(cell, pdef=fake)])
+            cells=[_stand_in_cell(monkeypatch, drifting)])
         assert 'JAX006' in failed_rules(rep)
 
 

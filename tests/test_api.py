@@ -420,10 +420,10 @@ class TestRegistry:
 
     def test_register_new_variant_without_touching_federation(self,
                                                               reg_task):
-        """A new spec type registers with the precompute/scan/fleet triple
-        of an existing protocol and immediately runs through Experiment —
-        the extension point a SEAFL-style staleness-discounted variant
-        would use."""
+        """A new spec type registers with the precompute hooks and state
+        forms of an existing protocol and immediately runs through
+        Experiment — the extension point a SEAFL-style
+        staleness-discounted variant would use."""
         @dataclasses.dataclass(frozen=True)
         class TwinSafaSpec(api.ProtocolSpec):
             fraction: float = 0.5
@@ -437,8 +437,7 @@ class TestRegistry:
                                   lag_tolerance=sp.lag_tolerance),
                 rounds=rounds, seed=seed),
             fleet_precompute=base.fleet_precompute,
-            scan_segment=base.scan_segment, loop_round=base.loop_round,
-            fleet_segment=base.fleet_segment,
+            form=base.form,
             uses_cache=True, supports_wire=True, supports_kernel=True)
         api.register(pdef)
         try:

@@ -13,7 +13,7 @@ from repro import api
 
 EXPECTED_ALL = {
     'CompiledRunner', 'CsaflSpec', 'ExecSpec', 'Experiment', 'FedAsyncSpec',
-    'FedAvgSpec', 'FedCSSpec', 'History', 'LocalSpec', 'PROTOCOLS',
+    'FedAvgSpec', 'FedCSSpec', 'Form', 'History', 'LocalSpec', 'PROTOCOLS',
     'ProtocolDef', 'ProtocolSpec', 'RoundRecord', 'STALENESS_FNS',
     'SafaSpec', 'SeaflSpec', 'SweepMember', 'SweepSpec', 'Task',
     'WEIGHTED_SCHEMES', 'check_compat', 'init_fleet_global',
@@ -71,8 +71,7 @@ def test_registry_snapshot():
                                   api.FedAsyncSpec, api.SeaflSpec,
                                   api.CsaflSpec}
     for pdef in api.PROTOCOLS.values():
-        for fn in ('precompute', 'fleet_precompute', 'scan_segment',
-                   'loop_round', 'fleet_segment'):
+        for fn in ('precompute', 'fleet_precompute', 'form'):
             assert callable(getattr(pdef, fn)), (pdef.name, fn)
 
 

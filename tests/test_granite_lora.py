@@ -278,14 +278,14 @@ def test_the_segment_takes_base_and_tokens_as_arguments():
         *[jnp.ones((k, task.m), bool)] * 5, jnp.arange(1, k + 1))
     w = jnp.full((task.m,), 1.0 / task.m)
 
-    def lowered(train, operands):
+    def lowered(train, extra):
         return protocol.safa_run_scan.lower(
             g, stacked, stacked, sched, w, local_train_fn=train,
-            use_kernel='packed', wire='f32', operands=operands).as_text()
+            use_kernel='packed', wire='f32', extra=extra).as_text()
     assert _largest_constant(lowered(task.local_train,
-                                     task.operands())) < 2 ** 20
+                                     (task.operands(),))) < 2 ** 20
     closed = functools.partial(task.local_train, operands=task.operands())
-    assert _largest_constant(lowered(closed, None)) >= 2 ** 20
+    assert _largest_constant(lowered(closed, ())) >= 2 ** 20
 
 
 class _Forwarding:

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import conformance as C
 from repro.core import api, federation, protocol, selection
 from repro.data import make_regression, partition
 from repro.data.tasks import regression_task
@@ -290,13 +291,14 @@ class TestSparseTier:
         _trees_close(hd.final_global, ht.final_global, **self.TOL)
         _trees_close(hs.final_global, ht.final_global, **self.TOL)
 
-    def test_scan_equals_loop_bitwise(self, reg_task):
+    def test_scan_matches_loop(self, reg_task):
+        """Within ``C.ENGINE_TOL``: the scan fuses the delta sums."""
         kw = dict(fraction=0.3, lag_tolerance=2)
         ex = dict(eval_every=4, schedule='sparse_tier')
         hs = _run(reg_task, 'safa', kw, dict(ex, engine='scan'))
         hl = _run(reg_task, 'safa', kw, dict(ex, engine='loop'))
-        _trees_equal(hs.final_global, hl.final_global)
-        assert hs.best_eval == hl.best_eval
+        C.assert_tree_close(hs.final_global, hl.final_global, 'tier')
+        C.assert_evals_close(hs, hl, 'tier')
 
     @pytest.mark.parametrize('wire', ['f32', 'int8'])
     def test_packed_close_to_dense(self, reg_task, wire):
