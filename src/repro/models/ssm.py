@@ -53,16 +53,21 @@ def ssd_chunked(x, dt, A, B, C, chunk=128, initial_state=None,
 
     dA = dtb * A.astype(jnp.float32)           # [b,L,c,h]
     dAc = jnp.cumsum(dA, axis=2)               # within-chunk cumsum
+    # Every contraction takes two operands, with the per-position factors
+    # (dt, the decays) folded into the smaller one first: a multi-operand
+    # einsum picks its own pairwise order, which wrote 5-D f32 products
+    # such as [b,L,h,c,c] * dt to HBM, forward and backward.
+    xd = xb * dtb[..., None]                                 # [b,L,c,h,p]
     # 1. intra-chunk (diagonal blocks)
     Lmat = jnp.exp(segsum(dA.transpose(0, 1, 3, 2)))        # [b,L,h,c,c]
     scores = jnp.einsum('blin,bljn->blij', Cb, Bb,
                         precision=precision)                 # [b,L,c,c]
-    y_diag = jnp.einsum('blij,blhij,bljh,bljhp->blihp', scores, Lmat, dtb, xb,
+    y_diag = jnp.einsum('blhij,bljhp->blihp', scores[:, :, None] * Lmat, xd,
                         precision=precision)
     # 2. chunk-final states
     decay_states = jnp.exp(dAc[:, :, -1:, :] - dAc)          # [b,L,c,h]
-    states = jnp.einsum('blcn,blch,blch,blchp->blhpn', Bb, decay_states, dtb,
-                        xb, precision=precision)
+    states = jnp.einsum('blcn,blchp->blhpn', Bb,
+                        xd * decay_states[..., None], precision=precision)
     # 3. inter-chunk recurrence
     chunk_decay = jnp.exp(dAc[:, :, -1, :])                  # [b,L,h]
 
@@ -82,8 +87,8 @@ def ssd_chunked(x, dt, A, B, C, chunk=128, initial_state=None,
     prev_states = prev_states.transpose(1, 0, 2, 3, 4)       # [b,L,h,p,n]
     # 4. inter-chunk outputs
     state_decay_out = jnp.exp(dAc)                           # [b,L,c,h]
-    y_off = jnp.einsum('blcn,blhpn,blch->blchp', Cb, prev_states,
-                       state_decay_out, precision=precision)
+    y_off = jnp.einsum('blcn,blhpn->blchp', Cb, prev_states,
+                       precision=precision) * state_decay_out[..., None]
     y = (y_diag + y_off).reshape(b, -1, h, p)[:, :s]
     return y.astype(x.dtype), final
 

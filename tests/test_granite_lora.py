@@ -120,6 +120,76 @@ def test_the_ssd_contracts_at_highest_precision():
     assert ssd == {'HIGHEST'} and other == {'DEFAULT'}
 
 
+def _ssd_multi_operand(x, dt, A, B, C, chunk, initial_state, precision):
+    """The chunked SSD as multi-operand einsums, one per block of the dual
+    form: the same algebra as ``ssm.ssd_chunked`` contracted in another
+    order."""
+    b, s, h, p = x.shape
+    n = B.shape[-1]
+    pad = (-s) % chunk
+    x, dt, B, C = (jnp.pad(v, [(0, 0), (0, pad)] + [(0, 0)] * (v.ndim - 2))
+                   for v in (x, dt, B, C))
+    L = x.shape[1] // chunk
+    xb = x.reshape(b, L, chunk, h, p)
+    dtb = dt.reshape(b, L, chunk, h)
+    Bb, Cb = B.reshape(b, L, chunk, n), C.reshape(b, L, chunk, n)
+    dA = dtb * A
+    dAc = jnp.cumsum(dA, axis=2)
+    Lmat = jnp.exp(ssm.segsum(dA.transpose(0, 1, 3, 2)))
+    scores = jnp.einsum('blin,bljn->blij', Cb, Bb, precision=precision)
+    y_diag = jnp.einsum('blij,blhij,bljh,bljhp->blihp', scores, Lmat, dtb, xb,
+                        precision=precision)
+    decay_states = jnp.exp(dAc[:, :, -1:, :] - dAc)
+    states = jnp.einsum('blcn,blch,blch,blchp->blhpn', Bb, decay_states, dtb,
+                        xb, precision=precision)
+
+    def step(carry, inp):
+        st, dec = inp
+        return carry * dec[:, :, None, None] + st, carry
+    final, prev = jax.lax.scan(step, initial_state,
+                               (states.transpose(1, 0, 2, 3, 4),
+                                jnp.exp(dAc[:, :, -1, :]).transpose(1, 0, 2)))
+    y_off = jnp.einsum('blcn,blhpn,blch->blchp', Cb,
+                       prev.transpose(1, 0, 2, 3, 4), jnp.exp(dAc),
+                       precision=precision)
+    return (y_diag + y_off).reshape(b, -1, h, p)[:, :s], final
+
+
+@pytest.mark.parametrize('s', [64, 53], ids=['whole-chunks', 'ragged-tail'])
+def test_ssd_chunked_gradients_match_the_multi_operand_form(s):
+    """Values, final state and the gradients with respect to every input
+    of ``ssm.ssd_chunked`` (chunks of 16, a carried state) against the
+    multi-operand contraction order, float32 at HIGHEST: the same sums
+    grouped otherwise, so they agree to float32 round-off."""
+    b, h, p, n, chunk = 2, 4, 8, 16, 16
+    k = jax.random.split(jax.random.PRNGKey(7), 8)
+    args = (jax.random.normal(k[0], (b, s, h, p)),
+            jax.nn.softplus(jax.random.normal(k[1], (b, s, h)) - 1.0),
+            -jax.random.uniform(k[2], (h,), minval=1.0, maxval=4.0),
+            jax.random.normal(k[3], (b, s, n)),
+            jax.random.normal(k[4], (b, s, n)),
+            jax.random.normal(k[5], (b, h, p, n)))
+    cot = (jax.random.normal(k[6], (b, s, h, p)),
+           jax.random.normal(k[7], (b, h, p, n)))
+
+    def program(x, dt, A, B, C, s0):
+        return ssm.ssd_chunked(x, dt, A, B, C, chunk=chunk, initial_state=s0,
+                               precision=ref.HIGHEST)
+
+    def multi(*a):
+        return _ssd_multi_operand(*a[:5], chunk, a[5], ref.HIGHEST)
+
+    got, got_vjp = jax.vjp(program, *args)
+    want, want_vjp = jax.vjp(multi, *args)
+    names = ['y', 'final_state'] + [f'd{v}' for v in
+                                    ('x', 'dt', 'A', 'B', 'C', 'state')]
+    for name, g, w in zip(names, (*got, *got_vjp(cot)),
+                          (*want, *want_vjp(cot)), strict=True):
+        scale = float(jnp.max(jnp.abs(w)))
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-5,
+                                   atol=1e-6 * scale, err_msg=name)
+
+
 @pytest.mark.parametrize('dtype,rtol', [
     # float32 base: every op float32; the program's chunked SSD and flash
     # attention against the reference's quadratic forms: round-off only

@@ -16,6 +16,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import obs
 from repro.kernels import comm_quant, ops, safa_aggregate
+from repro.models import ssm
 
 #: PAPER_TASKS['task2_cnn']: clients, and the paper CNN's packed width
 M, N = 100, 342_016
@@ -27,8 +28,12 @@ K, R = 40, 64
 #: xdevice_1m's lag-tier round: K slots over a 1.4M-wide pack, and a
 #: value buffer of the cell's capacity (410) + 1 rows, padded to 8
 K_1M, N_1M, R_1M = 251, 1_400_832, 416
+#: granite_h_micro_lora's SSD: one 2048-token row, 64 heads of 64, state
+#: 128, chunks of 256
+T_SSD, H_SSD, P_SSD, N_SSD, CHUNK_SSD = 2048, 64, 64, 128, 256
 
-F32, I8, I32, BOOL = jnp.float32, jnp.int8, jnp.int32, jnp.bool_
+F32, I8, I32, BOOL, BF16 = (jnp.float32, jnp.int8, jnp.int32, jnp.bool_,
+                            jnp.bfloat16)
 
 
 @pytest.fixture(scope='module')
@@ -47,11 +52,11 @@ def one_chip(topo):
 
 
 @pytest.fixture
-def compile_for_tpu(one_chip, monkeypatch):
+def compiled_for_tpu(one_chip, monkeypatch):
     """Compile ``fn`` for the described chip from (shape, dtype) pairs and
-    return the compiled text.  Kernels are traced for the TPU, and JAX's
-    persistent compilation cache is off: a compile for a described chip
-    is written to it but cannot be read back without the chip."""
+    return the compiled program.  Kernels are traced for the TPU, and
+    JAX's persistent compilation cache is off: a compile for a described
+    chip is written to it but cannot be read back without the chip."""
     from jax.experimental.compilation_cache import compilation_cache
 
     from repro.kernels import backend
@@ -64,11 +69,17 @@ def compile_for_tpu(one_chip, monkeypatch):
     def compile_(fn, *shapes):
         args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
                 for s, d in shapes]
-        return jax.jit(fn).lower(*args).compile().as_text()
+        return jax.jit(fn).lower(*args).compile()
 
     yield compile_
     jax.clear_caches()      # no TPU trace left for later tests
     jax.config.update('jax_enable_compilation_cache', cache_on)
+
+
+@pytest.fixture
+def compile_for_tpu(compiled_for_tpu):
+    """As ``compiled_for_tpu``, returning the compiled text."""
+    return lambda fn, *shapes: compiled_for_tpu(fn, *shapes).as_text()
 
 
 def _dense_operands(m, n):
@@ -164,3 +175,23 @@ def test_gather_rows_slab_xdevice_width(compile_for_tpu):
     assert 'tpu_custom_call' in text
     tile = N_1M * K_1M // obs.counters()['gather_rows']['dmas']
     assert tile > safa_aggregate.DEFAULT_TILE and N_1M % tile == 0
+
+
+def test_ssd_granite_width_bytes(compiled_for_tpu):
+    """One SSD forward and backward at Granite's width, float32 at
+    HIGHEST, reads and writes under 1.5 GB: its contractions keep the
+    [b, L, h, c, c] products out of HBM (multi-operand einsums that
+    materialise them read 4.0 GB)."""
+    def fwd_bwd(x, dt, A, B, C):
+        def loss(*a):
+            y, _ = ssm.ssd_chunked(*a, chunk=CHUNK_SSD,
+                                   precision=jax.lax.Precision.HIGHEST)
+            return jnp.sum(y.astype(F32) ** 2)
+        return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(x, dt, A, B, C)
+
+    compiled = compiled_for_tpu(
+        fwd_bwd, ((1, T_SSD, H_SSD, P_SSD), BF16), ((1, T_SSD, H_SSD), F32),
+        ((H_SSD,), F32), ((1, T_SSD, N_SSD), BF16), ((1, T_SSD, N_SSD), BF16))
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost['bytes accessed'] < 1.5e9
